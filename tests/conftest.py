@@ -1,7 +1,6 @@
 """Test configuration: force an 8-device virtual CPU platform BEFORE any jax
 usage so multi-device SPMD paths are exercised without TPU hardware
-(SURVEY.md §4 item 2).  See unicore_tpu.platform_utils for why the env var
-alone is not enough in this environment."""
+(SURVEY.md §4 item 2)."""
 
 import os
 import sys

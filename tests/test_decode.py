@@ -410,6 +410,31 @@ def _drive(eng, reqs, iters=400):
     raise AssertionError("engine did not finish all requests")
 
 
+def test_engine_serves_a_bf16_trained_checkpoint(tiny):
+    """A ``--bf16`` training run saves bf16 parameters, so the decode step
+    computes bf16 K/V rows against the fp32 cache view — the in-module
+    cache write must take the cache's dtype (it raised a dtype mismatch at
+    warm-up: every earlier decode test served fp32 weights)."""
+    model, variables = tiny
+    bf16 = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a,
+        variables,
+    )
+    eng = DecodeEngine(
+        model, bf16, bucket_edges=(16, 32), decode_batch=2,
+        prefill_batch=2, page_size=8, num_pages=12,
+        pad_idx=model.padding_idx, eos_idx=2, vocab_size=model.vocab_size,
+        max_new_tokens=4,
+    )
+    eng.warmup()
+    req = eng.submit([5, 6, 7, 8], 60.0, request_id="bf16")
+    _drive(eng, [req])
+    assert req.response.status == rq.STATUS_OK, req.response
+    assert 1 <= len(req.response.output) <= 4
+    assert np.isfinite(req.response.score)
+
+
 def test_engine_generates_greedy_rollout(tiny):
     model, variables = tiny
     eng = DecodeEngine(

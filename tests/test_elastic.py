@@ -907,6 +907,49 @@ def _cli_args(data_dir, save_dir, max_update, extra=()):
     return argv + list(extra)
 
 
+SUPERVISOR_PROBE = r"""
+import os, sys
+sys.path.insert(0, {repo!r})
+sys.argv = ["train.py"] + {argv!r}
+from unicore_tpu.distributed import elastic
+
+class FakeChild:
+    # stands in for the trainer child: the probe is about the PARENT
+    def __init__(self, cmd, env=None):
+        assert cmd[1:3] == ["-m", "unicore_tpu_cli.train"], cmd
+    def wait(self):
+        return 0
+    def poll(self):
+        return 0
+
+elastic.subprocess.Popen = FakeChild
+from unicore_tpu_cli.train import cli_main
+try:
+    cli_main()
+except SystemExit as e:
+    assert e.code == 0, e.code
+from jax._src import xla_bridge
+print("SUPERVISOR_BACKENDS_INITIALIZED=%s" % xla_bridge.backends_are_initialized())
+"""
+
+
+def test_elastic_supervisor_never_initializes_a_backend(data_dir, tmp_path):
+    """One process per chip: the --elastic parent parses options (which
+    imports jax through the model registry) and supervises, but must
+    never initialize a backend — on a TPU host that would take the chip
+    from the trainer child it is about to start."""
+    argv = _cli_args(data_dir, str(tmp_path), 4, extra=["--elastic"])
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         SUPERVISOR_PROBE.format(repo=REPO, argv=argv)],
+        capture_output=True, text=True, timeout=CLI_TIMEOUT, cwd=REPO,
+        env=_cli_env(),
+    )
+    out = proc.stdout + proc.stderr
+    assert proc.returncode == 0, out[-4000:]
+    assert "SUPERVISOR_BACKENDS_INITIALIZED=False" in out, out[-4000:]
+
+
 def _load_model(path):
     from unicore_tpu import checkpoint_utils
 

@@ -19,12 +19,13 @@ import jax.numpy as jnp
 
 from unicore_tpu.ops import flash_attention as fa
 from unicore_tpu.ops._pallas import interpret_enabled
+from unicore_tpu.platform_utils import on_tpu
 
 
 @pytest.fixture()
 def interpret_kernels():
     prev = interpret_enabled()
-    fa.set_interpret(jax.default_backend() != "tpu")
+    fa.set_interpret(not on_tpu())
     yield
     fa.set_interpret(prev)
 

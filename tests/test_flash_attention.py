@@ -13,8 +13,9 @@ import jax
 import jax.numpy as jnp
 
 from unicore_tpu.ops import flash_attention as fa
+from unicore_tpu.platform_utils import on_tpu
 
-fa.set_interpret(jax.default_backend() != "tpu")
+fa.set_interpret(not on_tpu())
 
 
 def make_inputs(B, H, L, D, dtype, bias_shape=None, with_mask=False, seed=0):
@@ -97,7 +98,7 @@ def test_fully_masked_rows_produce_zeros():
 
 
 @pytest.mark.skipif(
-    jax.default_backend() != "tpu", reason="in-kernel dropout uses TPU PRNG"
+    not on_tpu(), reason="in-kernel dropout uses TPU PRNG"
 )
 def test_dropout_deterministic_and_consistent():
     B, H, L, D = 2, 2, 256, 64

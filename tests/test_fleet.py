@@ -82,13 +82,19 @@ def fake_infer(service_s=0.0):
 
 
 def publish_lease(client, name, address, *, seq, ready=True, est=0.0,
-                  digest="d0", step=0):
+                  digest="d0", step=0, wall=None):
+    """``wall`` is the lease's incarnation stamp: tests that compare
+    incarnations pass strictly increasing stamps instead of resting on
+    two clock reads differing after encoding."""
     client.key_value_set(
         fleet_registry.lease_key(name),
         ReplicaLease(
             name=name, address=address, ready=ready, digest=digest,
             est_delay_s=est,
-            hb=elastic.Lease(epoch=0, seq=seq, step=step, wall=time.time()),
+            hb=elastic.Lease(
+                epoch=0, seq=seq, step=step,
+                wall=time.time() if wall is None else wall,
+            ),
         ).encode(),
     )
 
@@ -337,14 +343,14 @@ def test_membership_restarted_replica_rejoins_despite_fresh_seq(tmp_path):
     invisible until it out-counted the dead incarnation's whole life."""
     client, view, now = _stepped_view(tmp_path)
     # long-lived incarnation: seq climbed high before the death
-    publish_lease(client, "r0", "http://h:1", seq=1800)
+    publish_lease(client, "r0", "http://h:1", seq=1800, wall=1000.0)
     view.poll_once(0.0)
     for t in (3.0, 6.5):
         now[0] = t
         view.poll_once(t)
     assert "r0" in view.stats()["lost"]
-    # restart: fresh registrar, seq 1, but a NEW wall stamp
-    publish_lease(client, "r0", "http://h:1", seq=1)
+    # restart: fresh registrar, seq 1, but a NEW (strictly later) wall stamp
+    publish_lease(client, "r0", "http://h:1", seq=1, wall=1007.0)
     now[0] = 7.0
     view.poll_once(7.0)
     assert [r.name for r in view.balance_set()] == ["r0"]

@@ -145,6 +145,74 @@ def test_comm_section_async_start_and_multi_operand():
     assert multi["tier"] == "dcn"
 
 
+@pytest.mark.parametrize("attr,expected", [
+    # the explicit list
+    ("replica_groups={{0,1},{2,3}}", [[0, 1], [2, 3]]),
+    # iota: 8 devices, 2 groups of 4 — the flat all-reduce of a pod=2 mesh
+    ("replica_groups=[2,4]<=[8]", [[0, 1, 2, 3], [4, 5, 6, 7]]),
+    ("replica_groups=[1,8]<=[8]", [[0, 1, 2, 3, 4, 5, 6, 7]]),
+    # transposed iota: iota(8).reshape(2,4).T.reshape(4,2) — every group
+    # pairs the same slot of both pods, i.e. crosses them
+    ("replica_groups=[4,2]<=[2,4]T(1,0)",
+     [[0, 4], [1, 5], [2, 6], [3, 7]]),
+    ("replica_groups=[2,4]<=[2,2,2]T(1,0,2)",
+     [[0, 1, 4, 5], [2, 3, 6, 7]]),
+    ("source_target_pairs={{0,1},{1,0}}", [[0, 1], [1, 0]]),
+    ("dimensions={0}", []),
+])
+def test_parse_groups_explicit_and_iota_forms(attr, expected):
+    line = (
+        "  %ar = f32[4096]{0} all-reduce(f32[4096]{0} %x), channel_id=1, "
+        + attr + ", use_global_device_ids=true, to_apply=%r"
+    )
+    assert fa._parse_groups(line) == expected
+
+
+def test_comm_section_classifies_iota_groups_by_tier():
+    """The installed XLA prints regular groups in the iota form; a flat
+    all-reduce over both pods must land on the dcn tier (it read as
+    'unknown' -> 0 dcn bytes before), the in-pod one on ici."""
+    hlo = (
+        "HloModule jit_iota\n\n"
+        "ENTRY %main (x: f32[1024]) -> f32[1024] {\n"
+        "  %x = f32[1024]{0} parameter(0)\n"
+        "  %a = f32[1024]{0} all-reduce(f32[1024]{0} %x), channel_id=1, "
+        "replica_groups=[1,8]<=[8], use_global_device_ids=true, "
+        "to_apply=%r\n"
+        "  %b = f32[1024]{0} all-reduce(f32[1024]{0} %a), channel_id=2, "
+        "replica_groups=[2,4]<=[8], use_global_device_ids=true, "
+        "to_apply=%r\n"
+        "  ROOT %c = f32[1024]{0} all-reduce(f32[1024]{0} %b), "
+        "channel_id=3, replica_groups=[4,2]<=[2,4]T(1,0), "
+        "use_global_device_ids=true, to_apply=%r\n"
+        "}\n"
+    )
+    tiers = fa.audit_hlo(hlo, devices_per_pod=4)["comm"]["tiers"]
+    assert tiers["dcn"]["ops"] == 2 and tiers["ici"]["ops"] == 1
+    assert tiers["dcn"]["operand_bytes"] == 2 * 4096
+    assert "unknown" not in tiers
+
+
+def test_comm_section_untyped_operands_resolve_through_definitions():
+    """The installed XLA prints operands as bare %names (no shape
+    literal): operand bytes come from the defining instruction —
+    parameters included — not 0."""
+    hlo = (
+        "HloModule jit_untyped\n\n"
+        "ENTRY %main (x: f32[4096]) -> f32[4096] {\n"
+        "  %x = f32[4,1024]{1,0} parameter(0)\n"
+        "  %bitcast = f32[4096]{0} bitcast(%x)\n"
+        "  ROOT %psum.7 = f32[4096]{0} all-reduce(%bitcast), channel_id=1, "
+        "replica_groups={{0,1,2,3}}, use_global_device_ids=true, "
+        "to_apply=%region_0.0\n"
+        "}\n"
+    )
+    comm = fa.audit_hlo(hlo, devices_per_pod=2)["comm"]
+    assert comm["tiers"]["dcn"] == {
+        "ops": 1, "operand_bytes": 16384, "result_bytes": 16384,
+    }
+
+
 def test_comm_section_unknown_without_pod_info():
     """No devices_per_pod -> no tier claims: everything rolls up under
     'unknown' instead of guessing."""

@@ -1832,7 +1832,7 @@ def test_sharding_legality_shard_map_arity(tmp_path):
     (tmp_path / "code.py").write_text(
         textwrap.dedent(
             """
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             def local(x, y):
@@ -1934,7 +1934,7 @@ def test_sharding_legality_negatives(tmp_path):
             return spec, dynamic
 
         def starred(mesh, *xs):
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def local(*args):
                 return args[0]
@@ -2316,7 +2316,7 @@ def test_stale_escape_select_subset_cannot_judge(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# unsafe-shard-map: the 0.4.x experimental spelling
+# unsafe-shard-map: the deprecated experimental spelling (check_rep)
 # ---------------------------------------------------------------------------
 
 
@@ -2326,11 +2326,11 @@ def test_unsafe_shard_map_check_rep_false(tmp_path):
     vs = run_lint(
         tmp_path,
         """
-        from jax.experimental.shard_map import shard_map
+        from jax.experimental import shard_map as legacy
 
         def run(mesh, f, x):
-            return shard_map(f, mesh=mesh, in_specs=(None,),
-                             out_specs=None, check_rep=False)(x)
+            return legacy.shard_map(f, mesh=mesh, in_specs=(None,),
+                                    out_specs=None, check_rep=False)(x)
         """,
         select=["unsafe-shard-map"],
     )

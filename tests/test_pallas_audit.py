@@ -575,6 +575,30 @@ def test_quant_matmul_serving_shape_fits_budget():
     assert _pallas.vmem_footprint(io) <= _pallas.VMEM_BUDGET
 
 
+@pytest.mark.parametrize("shape", [(512, 4096, 4096), (512, 3072, 768),
+                                   (8192, 768, 3072)])
+def test_quant_matmul_plan_stays_on_the_int8_tiling(shape):
+    """Every planned block divides its dim and stays on the (32, 128) int8
+    tiling: the halving loop used to take 768 to 192, off the lane grid.
+    The plan does not depend on the activation — what an epilogue keeps
+    beside the io blocks (exact GELU: 21 MiB at the first shape, by
+    Mosaic's account) is covered by the declared ``VMEM_LIMIT``, which
+    tests/test_tpu_compile.py holds Mosaic to."""
+    import jax.numpy as jnp
+
+    from unicore_tpu.ops import quant_matmul as qm
+
+    M, N, K = shape
+    BM, BN, BK = qm._plan_blocks(M, N, K, has_bias=True)
+    assert BM % 32 == 0 and BN % 128 == 0 and BK % 128 == 0
+    assert M % BM == 0 and N % BN == 0 and K % BK == 0
+    io = [((BM, BK), jnp.int8), ((BK, BN), jnp.int8),
+          ((1, BN), jnp.float32), ((BM, BN), jnp.float32),
+          ((1, BN), jnp.float32)]
+    assert _pallas.vmem_footprint(io) <= _pallas.VMEM_BUDGET
+    assert _pallas.VMEM_LIMIT >= 2 * _pallas.VMEM_BUDGET
+
+
 def test_flash_attention_bias_errors_are_named():
     import jax.numpy as jnp
 

@@ -132,6 +132,25 @@ def test_quant_matmul_pallas_matches_reference(pallas_on, shape, use_bias,
     assert err < 0.05 * max(np.abs(oracle).max(), 1.0), err
 
 
+def test_quant_matmul_gelu_epilogue_error_bound():
+    """The in-kernel exact-GELU epilogue is an Abramowitz-Stegun erf
+    (Mosaic lowers neither erf nor erfc); the jnp oracle keeps lax.erf.
+    They agree to the stated bound over the whole useful range, and the
+    kernel's other activations ARE the oracle's functions."""
+    from unicore_tpu.ops import quant_matmul as qm
+
+    x = jnp.arange(-10.0, 10.0, 1e-3, dtype=jnp.float32)
+    got = jax.jit(lambda v: qm._apply_activation_kernel(v, "gelu"))(x)
+    ref = qm._apply_activation(x, "gelu")
+    err = float(jnp.max(jnp.abs(got - ref)))
+    assert err <= qm.GELU_EPILOGUE_MAX_ABS_ERR, err
+    for act in ("", "relu", "gelu_fast", "silu", "tanh"):
+        assert np.array_equal(
+            np.asarray(qm._apply_activation_kernel(x, act)),
+            np.asarray(qm._apply_activation(x, act)),
+        ), act
+
+
 def test_quant_matmul_dispatch_gates(pallas_on):
     """Geometry the Pallas kernel can't tile falls back to the jnp
     composition (and mode off always does), with identical results."""

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from unicore_tpu.parallel import make_mesh
 from unicore_tpu.parallel.ring_attention import ring_self_attention
 from unicore_tpu.ops.flash_attention import mha_reference
+from unicore_tpu.platform_utils import on_tpu
 
 
 @pytest.mark.parametrize("with_mask", [False, True])
@@ -134,7 +135,7 @@ def test_pallas_ring_matches_reference(with_bias):
     from unicore_tpu.parallel.ring_attention import pallas_ring_supported
 
     prev_interpret = interpret_enabled()
-    fa.set_interpret(jax.default_backend() != "tpu")
+    fa.set_interpret(not on_tpu())
     try:
         mesh = make_mesh(data=1, seq=4, devices=jax.devices()[:4])
         B, H, L, D = 1, 2, 512, 16  # Lc = 128: the pallas gate opens

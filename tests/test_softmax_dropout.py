@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from unicore_tpu.ops.softmax_dropout import softmax_dropout
+from unicore_tpu.platform_utils import on_tpu
 
 
 def ref_softmax(x, mask=None, bias=None):
@@ -127,7 +128,7 @@ def pallas_mode():
     from unicore_tpu.ops import _pallas
 
     prev = _pallas.interpret_enabled()
-    _pallas.set_interpret(jax.default_backend() != "tpu")
+    _pallas.set_interpret(not on_tpu())
     _sd_mod.set_softmax_dropout_mode("on")
     try:
         yield
@@ -293,6 +294,6 @@ def test_dispatch_fallback_and_gating(pallas_mode):
          == _sd_ref(x3, 0.0, is_training=False)).all()
     )
     _sd_mod.set_softmax_dropout_mode(None)
-    if jax.default_backend() != "tpu":
+    if not on_tpu():
         # auto on a non-TPU backend = jnp (CPU numerics unchanged)
         assert _sd_mod._pallas_eligible(x3, None, None) is None

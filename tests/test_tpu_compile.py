@@ -1,0 +1,188 @@
+"""Mosaic compile rehearsal: every Pallas kernel family of the main paths,
+compiled for a DESCRIBED TPU v5e (no chip attached) at BERT-base /
+``transformer_lm`` widths.
+
+Interpret-mode parity tests prove a kernel's arithmetic; they cannot show
+what the chip's compiler refuses (block shapes off the (8, 128) tiling,
+primitives with no TPU lowering, a kernel's real VMEM appetite).  These
+compiles can, at ~2 s each and no chip time.  A compile that passes is not
+a chip run — ``chip_smoke.py``'s ``kernels`` phase executes the same table
+(``unicore_tpu/ops/kernel_cases.py``) on the chip against the jnp oracles.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load libtpu, and every xdist worker imports every
+test file), the kernel entries are called directly (the ``auto`` gates see
+the CPU backend here), and everything compiles in the test's own process
+with the persistent compile cache off (a described-device executable
+cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from unicore_tpu.ops import _pallas
+from unicore_tpu.ops.kernel_cases import kernel_cases
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # other test files set the interpret override as they are imported;
+    # put back exactly what was there, whichever file ran first
+    interpret_was = _pallas._override
+    _pallas.set_interpret(False)
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        _pallas.set_interpret(interpret_was)
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+
+
+CASES = {case.name: case for case in kernel_cases()}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip):
+    case = CASES[name]
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype, _fill in case.specs
+    ]
+    compiled = jax.jit(case.kernel).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), name
+
+
+B, L, E, V = 16, 512, 768, 30522
+
+
+def _encoder_on_mesh(mesh, layers, monkeypatch):
+    """A BERT-base-wide encoder + tied LM head as abstract values laid over
+    ``mesh`` (params and embedding replicated, the token batch over the dp
+    tier), and its mean-NLL loss.  The gates ask on_tpu() and see the CPU
+    here, so the test steers them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import unicore_tpu.modules.multihead_attention as mha
+    from unicore_tpu.modules import TransformerEncoder
+    from unicore_tpu.parallel import mesh as mesh_mod
+
+    jnp = jax.numpy
+    monkeypatch.setattr(mha, "on_tpu", lambda: True)
+    monkeypatch.setattr(mesh_mod, "_global_mesh", mesh)
+    rows = NamedSharding(mesh, mesh_mod.batch_spec(mesh))
+    everywhere = NamedSharding(mesh, P())
+
+    enc = TransformerEncoder(
+        encoder_layers=layers, embed_dim=E, ffn_embed_dim=3072,
+        attention_heads=12, max_seq_len=L, rel_pos=True, post_ln=True,
+    )
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(
+        lambda: enc.init({"params": key, "dropout": key},
+                         jnp.zeros((B, L, E), jnp.bfloat16))
+    )
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=everywhere),
+        params,
+    )
+    emb = jax.ShapeDtypeStruct((V, E), jnp.bfloat16, sharding=everywhere)
+    tok = jax.ShapeDtypeStruct((B, L), jnp.int32, sharding=rows)
+
+    def loss(params, emb, tok, key):
+        out = enc.apply(params, emb[tok], padding_mask=(tok == 0),
+                        train=True, rngs={"dropout": key})
+        logits = (out @ emb.T).astype(jnp.float32)
+        picked = jnp.take_along_axis(logits, tok[..., None], -1)[..., 0]
+        return jnp.mean(jax.nn.logsumexp(logits, -1) - picked)
+
+    return params, emb, tok, key, loss
+
+
+def _assert_kernel_sees_a_quarter(compiled):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel sees this device's quarter of the batch, not all 16 rows
+    assert f"bf16[{B // 4},12,{L},64]" in text
+    assert f"bf16[{B},12,{L},64]" not in text
+
+
+def test_data_parallel_bert_base_compiles_for_four_chips(topo, one_chip,
+                                                         monkeypatch):
+    """The four-chip rehearsal of ``chip_smoke.py --four-chips``: BERT-base
+    at full width AND depth (12 layers, rel-pos bias, tied 30,522-way LM
+    head), forward+backward, batch 16 laid over the ``data`` axis of the
+    four described chips, dropout on.  About a minute of compile, and it
+    stands for two refusals that only a real multi-chip program showed:
+
+    * XLA's SPMD pass cannot partition a Mosaic kernel ("wrap the call in a
+      shard_map") — interpret mode on virtual CPU devices never showed it.
+      The module router runs the kernel inside a data-parallel shard_map;
+      each chip's kernel sees a quarter of the batch, not all of it.
+    * at this depth the full-row attention backward needed 16.71 MiB of
+      scoped VMEM against the 16 MiB default (it compiles alone, and in a
+      6-layer program): every kernel now declares ``_pallas.VMEM_LIMIT``.
+    """
+    from unicore_tpu.parallel import mesh as mesh_mod
+
+    mesh = mesh_mod.make_mesh(data=4, devices=topo.devices)
+    params, emb, tok, key, loss = _encoder_on_mesh(mesh, 12, monkeypatch)
+
+    def step(params, emb, tok):
+        return jax.grad(loss, argnums=(0, 1))(params, emb, tok, key)
+
+    compiled = jax.jit(step).lower(params, emb, tok).compile()
+    _assert_kernel_sees_a_quarter(compiled)
+
+
+def test_two_level_reduction_with_flash_compiles_for_pod_x_data(
+        topo, one_chip, monkeypatch):
+    """``--num-pods 2`` over the four described chips (pod 2 x data 2): the
+    trainer's forward/backward runs inside ``parallel/hierarchy.py``'s
+    full-manual shard_map over the dp tier.  The attention router must NOT
+    open its own data-parallel shard_map there (JAX refuses a second one
+    over axes that are already Manual); it calls the kernel on the local
+    rows.  One layer: the refusal is at lowering, depth adds nothing."""
+    from unicore_tpu.parallel import hierarchy, mesh as mesh_mod
+    from unicore_tpu.parallel.plan import ParallelPlan
+
+    mesh = mesh_mod.make_mesh(pods=2, data=2, devices=topo.devices)
+    params, emb, tok, key, loss = _encoder_on_mesh(mesh, 1, monkeypatch)
+
+    def fb(params, sample, rng, loss_scale, weight):
+        value, grads = jax.value_and_grad(loss)(
+            params, sample["emb"], sample["tok"], rng)
+        return grads, jax.numpy.float32(sample["tok"].shape[0]), {
+            "loss": value}
+
+    wrapped = hierarchy.wrap_forward_backward(
+        lambda p, s, *rest: fb(p["enc"], dict(s, emb=p["emb"]), *rest),
+        mesh, ParallelPlan(pods=2, data=2))
+
+    def step(params, emb, tok):
+        return wrapped({"enc": params, "emb": emb}, {"tok": tok}, key,
+                       1.0, 1.0)
+
+    compiled = jax.jit(step).lower(params, emb, tok).compile()
+    _assert_kernel_sees_a_quarter(compiled)

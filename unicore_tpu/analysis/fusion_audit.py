@@ -60,6 +60,8 @@ import json
 import re
 from typing import Dict, List, Optional
 
+import numpy as np
+
 #: dtype prefix -> bytes per element (unknown prefixes parse as 0)
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -104,13 +106,32 @@ _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _REPLICA_GROUPS_RE = re.compile(r"replica_groups=\{((?:\{[\d,]*\},?)+)\}")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{[\d,]*\},?)+)\}")
 _GROUP_RE = re.compile(r"\{([\d,]*)\}")
+#: the iota encoding: ``replica_groups=[G,S]<=[d0,d1,..]`` with an optional
+#: ``T(p0,p1,..)`` — iota(prod(d)) reshaped to ``d``, transposed by ``p``,
+#: reshaped to G groups of S devices
+_IOTA_GROUPS_RE = re.compile(
+    r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?"
+)
+
+
+def _iota_groups(m) -> List[List[int]]:
+    n_groups, group_size = int(m.group(1)), int(m.group(2))
+    dims = [int(t) for t in m.group(3).split(",")]
+    ids = np.arange(int(np.prod(dims))).reshape(dims)
+    if m.group(4):
+        ids = ids.transpose([int(t) for t in m.group(4).split(",")])
+    return ids.reshape(n_groups, group_size).tolist()
 
 
 def _parse_groups(line: str) -> List[List[int]]:
-    """Device-id groups out of ``replica_groups={{..},..}`` (or
-    ``source_target_pairs`` for collective-permute) — empty when the
-    line carries neither or uses a form we don't parse (e.g. the iota
-    ``[g,s]<=[..]`` encoding), in which case the tier stays unknown."""
+    """Device-id groups out of ``replica_groups`` — the explicit
+    ``{{..},..}`` list or the iota ``[g,s]<=[..]T(..)`` encoding the
+    installed XLA prints for regular groups — or ``source_target_pairs``
+    for collective-permute.  Empty when the line carries none of them, in
+    which case the tier stays unknown."""
+    m = _IOTA_GROUPS_RE.search(line)
+    if m:
+        return _iota_groups(m)
     m = _REPLICA_GROUPS_RE.search(line) or _PAIRS_RE.search(line)
     if not m:
         return []
@@ -193,11 +214,13 @@ def audit_hlo(
         if comp["name"] in called:
             continue
         instrs = []  # (name, opcode, line)
+        result_shapes: Dict[str, str] = {}  # name -> result shape text
         for line in comp["lines"]:
             m = _INSTR_RE.match(line)
             if not m:
                 continue
-            name, _shape, opcode = m.groups()
+            name, shape, opcode = m.groups()
+            result_shapes[name] = shape
             instrs.append((name, opcode, line))
             instructions += 1
             if opcode not in _NON_KERNEL_OPS:
@@ -218,7 +241,9 @@ def audit_hlo(
             )
             if base_op in _COLLECTIVE_OPS:
                 collectives.append(
-                    _collective_entry(name, base_op, line, devices_per_pod)
+                    _collective_entry(
+                        name, base_op, line, devices_per_pod, result_shapes
+                    )
                 )
         chains.extend(_elementwise_chains(instrs))
         cv, ch = _dequant_chains(instrs)
@@ -247,10 +272,14 @@ def audit_hlo(
 
 
 def _collective_entry(
-    name: str, op: str, line: str, devices_per_pod: Optional[int]
+    name: str, op: str, line: str, devices_per_pod: Optional[int],
+    result_shapes: Dict[str, str],
 ) -> Dict:
     """One comm-section row: operand/result bytes + tier for one
-    collective instruction line."""
+    collective instruction line.  ``result_shapes`` maps the
+    computation's earlier instructions to their result shapes: the
+    installed XLA prints operands as bare ``%name`` references, so their
+    bytes come from the defining instruction."""
     m = _INSTR_RE.match(line)
     result_bytes = _shape_bytes(m.group(2)) if m else 0
     # operand shapes sit between the OPCODE's '(' — which is exactly
@@ -264,7 +293,11 @@ def _collective_entry(
     if m:
         close = line.find(")", m.end())
         if close > m.end():
-            operand_bytes = _shape_bytes(line[m.end():close])
+            operands = line[m.end():close]
+            operand_bytes = _shape_bytes(operands) or sum(
+                _shape_bytes(result_shapes.get(ref, ""))
+                for ref in _OPERAND_RE.findall(operands)
+            )
     groups = _parse_groups(line)
     tier = _comm_tier(groups, devices_per_pod)
     return {

@@ -86,12 +86,7 @@ def distributed_init(args) -> int:
             # cross-process computations outright.  Checked via the env var:
             # probing jax.default_backend() here would initialize the
             # backend before jax.distributed.initialize.
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo"
-                )
-            except Exception:
-                pass  # older/newer jax without the option: keep defaults
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         init_kwargs = {}
         try:
             # elastic restarts bound the rendezvous: a re-formed membership

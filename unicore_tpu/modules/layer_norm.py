@@ -78,11 +78,10 @@ def _use_pallas(use_pallas: Optional[bool], kind: str, dim: int) -> bool:
 
 
 def _pallas_runnable() -> bool:
-    import jax
-
     from unicore_tpu.ops._pallas import interpret_enabled
+    from unicore_tpu.platform_utils import on_tpu
 
-    return jax.default_backend() == "tpu" or interpret_enabled()
+    return on_tpu() or interpret_enabled()
 
 
 def _journal_choice(kind: str, dim: int, pallas: bool, source: str) -> None:

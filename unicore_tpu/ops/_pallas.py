@@ -24,6 +24,7 @@ import os
 from typing import Callable, Dict, Optional
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: TPU vector lane count — every block's last dim is tiled in 128s.
 LANE = 128
@@ -33,11 +34,21 @@ LANE = 128
 #: PR-12-round-5 bug class was exactly an int8 block on the 8-row grid.
 SUBLANE_BY_ITEMSIZE = {8: 8, 4: 8, 2: 16, 1: 32}
 
-#: Per-core VMEM we budget for one grid step's resident blocks, double-
-#: buffering included (~16 MiB physical; headroom left for Mosaic spills).
-#: Moved here from attention_fullrow.py so every kernel prices against
-#: the same number.
+#: Per-core VMEM we budget for one grid step's resident IO BLOCKS, double-
+#: buffering included.  Every kernel prices its blocks against this number
+#: (moved here from attention_fullrow.py so they share it).  It does not
+#: model what Mosaic keeps beside the blocks; ``VMEM_LIMIT`` covers that.
 VMEM_BUDGET = 12 * 1024 * 1024
+
+#: The scoped-VMEM limit every kernel DECLARES to Mosaic and XLA
+#: (``vmem_limit_bytes``; a v5e core has 128 MiB of VMEM, the default
+#: scoped limit is 16 MiB).  It is the one mechanism for what the budget
+#: above does not model — kernel-body temporaries and XLA's own scoped
+#: use around the call: the exact-GELU int8 matmul took a modeled 10 MiB
+#: plan to 21.06 MiB, and the full-row attention backward — 12 MiB
+#: modeled, compiles alone — was refused at 16.70 MiB inside the whole
+#: four-chip BERT train program.  2.7x the budget.
+VMEM_LIMIT = 32 * 1024 * 1024
 
 #: Longest row the full-row attention family will take resident
 #: (attention_fullrow.py refuses beyond it; flash tiles instead).
@@ -167,6 +178,9 @@ def interpret_enabled() -> bool:
 
 
 def pallas_call(*args, **kwargs):
+    kwargs.setdefault(
+        "compiler_params", pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
+    )
     return pl.pallas_call(*args, interpret=interpret_enabled(), **kwargs)
 
 

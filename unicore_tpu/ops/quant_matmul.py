@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 
 from ._pallas import (
     KernelGeometryError,
+    LANE,
     ModeGate,
     VMEM_BUDGET,
     audit_case,
@@ -51,6 +52,7 @@ from ._pallas import (
     pick_block_pow2,
     vmem_footprint,
 )
+from unicore_tpu.platform_utils import on_tpu
 
 _gate = ModeGate("quant_matmul", "UNICORE_TPU_PALLAS_QUANT_MATMUL")
 
@@ -144,6 +146,41 @@ def quant_matmul_reference(x_q, w_q, scale, bias=None, activation: str = "",
 # Pallas kernel: blocked int8 matmul, epilogue on the resident acc block
 # ---------------------------------------------------------------------------
 
+#: Abramowitz & Stegun 7.1.26: erf(x) = 1 - (a1 t + ... + a5 t^5) e^{-x^2},
+#: t = 1 / (1 + p x), x >= 0; |error| <= 1.5e-7 over the whole real line.
+_AS_P = 0.3275911
+_AS_A = (0.254829592, -0.284496736, 1.421413741, -1.453152027, 1.061405429)
+
+#: max |gelu_kernel - exact gelu| in fp32, measured over [-10, 10] at 1e-3
+#: spacing by tests/test_quant.py (the A&S bound times |x|/2 plus fp32
+#: rounding of the polynomial); the parity tolerance and docs/serving.md's
+#: int8 error-bound contract quote it.
+GELU_EPILOGUE_MAX_ABS_ERR = 1e-6
+
+
+def _erf_kernel(x):
+    """erf from primitives Mosaic lowers (exp / mul / add / div / select):
+    the TPU Pallas lowering implements neither ``erf`` nor ``erfc``, so the
+    exact-GELU epilogue cannot call ``jax.nn.gelu`` in-kernel."""
+    ax = jnp.abs(x)
+    t = 1.0 / (1.0 + _AS_P * ax)
+    poly = _AS_A[4]
+    for a in _AS_A[3::-1]:
+        poly = poly * t + a
+    e = 1.0 - poly * t * jnp.exp(-ax * ax)
+    return jnp.where(x < 0, -e, e)
+
+
+def _apply_activation_kernel(y, activation: str):
+    """The in-kernel epilogue table: identical to :func:`_apply_activation`
+    except exact GELU, which goes through :func:`_erf_kernel` (the jnp
+    oracle keeps ``lax.erf``; they agree to
+    :data:`GELU_EPILOGUE_MAX_ABS_ERR`)."""
+    if activation == "gelu":
+        return 0.5 * y * (1.0 + _erf_kernel(y * 0.7071067811865476))
+    return _apply_activation(y, activation)
+
+
 def _qmm_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, *, activation, n_k):
     """One (BM, BN) output block: accumulate int32 over the K grid axis,
     dequantize + bias + activation on the LAST k step only — the epilogue
@@ -166,7 +203,7 @@ def _qmm_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, *, activation, n_k):
         y = o_ref[...] * s_ref[...].astype(jnp.float32)
         if b_ref is not None:
             y = y + b_ref[...].astype(jnp.float32)
-        o_ref[...] = _apply_activation(y, activation)
+        o_ref[...] = _apply_activation_kernel(y, activation)
 
 
 def _pick_block(n, limit):
@@ -183,6 +220,12 @@ def _plan_blocks(M, N, K, *, has_bias):
     a ~16 MiB step at serving lm-head shapes (M=512, K=N=4096, BK=4096
     double-buffered): shrink K first (cheapest — more grid steps over the
     same resident accumulator), then N, then M.
+
+    The budget models the io blocks.  What the epilogue keeps beside them
+    (the exact-GELU erf polynomial took the (512, 1024, 2048) plan to
+    21.06 MiB by Mosaic's own account) is covered by the scoped-VMEM
+    limit every kernel declares (``_pallas.VMEM_LIMIT``), not by a
+    per-activation constant here.
     """
     BM = pick_block_pow2(M, _MAX_BLOCK_M)
     BN = pick_block_pow2(N, _MAX_BLOCK_N)
@@ -196,13 +239,14 @@ def _plan_blocks(M, N, K, *, has_bias):
         return vmem_footprint(io) <= VMEM_BUDGET
 
     while not fits(BM, BN, BK):
-        # halving an even divisor keeps divisibility; floors keep the
-        # last dims on the 128 lane grid and BM on the int8 sublane grid
-        if BK >= 256:
+        # a halving is legal only while the half stays on the 128 lane
+        # grid (BK, BN) or the int8 sublane grid (BM): 768 halves to 384
+        # and stops there, never to 192
+        if BK % (2 * LANE) == 0:
             BK //= 2
-        elif BN >= 256:
+        elif BN % (2 * LANE) == 0:
             BN //= 2
-        elif BM >= 64:
+        elif BM % 64 == 0:
             BM //= 2
         else:
             raise KernelGeometryError(
@@ -288,7 +332,7 @@ def quant_matmul(x_q, w_q, scale, bias=None, activation: str = "",
     # interpret mode is a correctness tool (mode 'on'), not a fast path
     use_pallas = (
         mode != "off"
-        and not (mode == "auto" and jax.default_backend() != "tpu")
+        and not (mode == "auto" and not on_tpu())
         and pallas_eligible(x2.shape[0], K, N, x2.dtype)
     )
     if use_pallas:

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ._pallas import ModeGate
+from unicore_tpu.platform_utils import on_tpu
 
 _gate = ModeGate("quant_norm", "UNICORE_TPU_PALLAS_QUANT_NORM")
 
@@ -51,7 +52,7 @@ def _pallas_eligible(x_q) -> bool:
     mode = _resolved_mode()
     if mode == "off":
         return False
-    if mode == "auto" and jax.default_backend() != "tpu":
+    if mode == "auto" and not on_tpu():
         return False
     if x_q.dtype != jnp.int8 or x_q.ndim < 2:
         return False

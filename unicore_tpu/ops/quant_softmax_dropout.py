@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from .softmax_dropout import softmax_dropout_reference
 
 from ._pallas import ModeGate
+from unicore_tpu.platform_utils import on_tpu
 
 _gate = ModeGate("quant_softmax_dropout", "UNICORE_TPU_PALLAS_QUANT_SOFTMAX")
 
@@ -66,7 +67,7 @@ def _pallas_eligible(input_q, mask, bias) -> Optional[tuple]:
     mode = _resolved_mode()
     if mode == "off":
         return None
-    if mode == "auto" and jax.default_backend() != "tpu":
+    if mode == "auto" and not on_tpu():
         return None
     if input_q.dtype not in (jnp.int8, jnp.int32):
         return None

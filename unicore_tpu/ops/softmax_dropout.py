@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ._pallas import ModeGate
+from unicore_tpu.platform_utils import on_tpu
 
 _gate = ModeGate("softmax_dropout", "UNICORE_TPU_PALLAS_SOFTMAX_DROPOUT")
 
@@ -119,7 +120,7 @@ def _pallas_eligible(input, mask, bias) -> Optional[tuple]:
     mode = _resolved_mode()
     if mode == "off":
         return None
-    if mode == "auto" and jax.default_backend() != "tpu":
+    if mode == "auto" and not on_tpu():
         return None
     from .softmax_dropout_pallas import pallas_plan
 

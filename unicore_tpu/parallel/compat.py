@@ -1,31 +1,16 @@
-"""One version-dispatch point for ``shard_map`` across jax generations.
+"""The one ``shard_map`` entry point of the tree.
 
-The repo spans two shard_map API generations:
+Every call site goes through :func:`shard_map` here so the manual-axis
+set is always named explicitly (``axis_names=`` — never leaning on
+empty-set-means-all) and ``check_vma`` is always an explicit literal the
+``unsafe-shard-map`` lint rule can see.  Partial-manual regions (some
+axes left AUTO, e.g. the pp x sp composition in ``parallel/pipeline.py``)
+derive their manual set from the plan-built mesh via
+:func:`manual_axes_except`.
 
-* the **vma-typed** generation (``jax.shard_map``: ``axis_names=`` +
-  ``check_vma=``, varying-across-mesh types, ``jax.lax.pcast``) — the
-  only one whose *partial-manual* mode (some axes left AUTO) can run
-  collectives over the manual axes;
-* the **0.4.x experimental** generation
-  (``jax.experimental.shard_map.shard_map``: ``check_rep=``, no vma
-  types) — full-manual only: a nonempty ``auto=`` set hard-crashes XLA's
-  SPMD partitioner on the first ``ppermute``.
-
-ONE capability probe decides everything: ``jax.shard_map`` and
-``jax.lax.pcast`` shipped together, and partial-manual correctness needs
-both (the dispatch entry point AND the carry cast), so probing them
-jointly can never send a mid-generation jax down the vma path without
-the cast.  ``parallel/sharding.py`` (``seq_pipeline_plan``) and
-``parallel/pipeline.py`` (``gpipe``) both key on
-:data:`PARTIAL_MANUAL_OK`, so the plan layer and the execution layer can
-never disagree about when the pp×sp composition is supported.
-
-Call sites pass ``check_vma=`` in the new API's vocabulary; this module
-translates it to ``check_rep=`` for the old one.  The literal
-``check_vma=False`` pins at the four pallas call sites stay visible to
-the ``unsafe-shard-map`` lint rule (and keep their
-``# lint: jax-version-pinned`` escapes live) because the call sites are
-still named ``shard_map``.
+The literal ``check_vma=False`` pins at the four pallas call sites stay
+visible to the lint rule (and keep their ``# lint: jax-version-pinned``
+escapes live) because the call sites are still named ``shard_map``.
 """
 
 from typing import Optional
@@ -41,55 +26,35 @@ def manual_axes_except(mesh, *auto_axes: str) -> frozenset:
     exactly this) then flows through automatically."""
     return frozenset(mesh.shape) - frozenset(auto_axes)
 
-#: the vma-typed generation is present (and with it, working
-#: partial-manual mode)
-HAS_VMA_SHARD_MAP = hasattr(jax, "shard_map") and hasattr(jax.lax, "pcast")
 
-#: alias consumed by seq_pipeline_plan and gpipe — one probe, two layers
-PARTIAL_MANUAL_OK = HAS_VMA_SHARD_MAP
+def inside_manual_region(axes) -> bool:
+    """True while tracing inside a ``shard_map`` that already holds any of
+    ``axes`` manually (e.g. ``parallel/hierarchy.py``'s full-manual region
+    over the dp tier).  JAX refuses a second ``shard_map`` over an axis
+    that is already Manual, so a router that would open one asks first."""
+    held = jax.sharding.get_abstract_mesh().manual_axes
+    return bool(frozenset(axes) & frozenset(held))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma,
               manual_axes: Optional[frozenset] = None):
-    """``shard_map`` on whichever API generation this jax provides.
+    """``jax.shard_map`` with the manual axes always spelled out.
 
-    ``manual_axes=None`` means full-manual over every mesh axis (named
-    explicitly on the vma API rather than leaning on
-    empty-set-means-all); a set leaves the remaining axes AUTO —
-    supported only on the vma generation (a named refusal elsewhere,
-    never the XLA partitioner crash).  ``check_vma`` maps to
-    ``check_rep`` on the experimental API and is REQUIRED: defaulting it
-    off would let a future call site disable checking silently, where
-    the ``unsafe-shard-map`` lint can only see (and demand a pin
-    justification for) an explicit literal ``False``.
+    ``manual_axes=None`` means full-manual over every mesh axis; a set
+    leaves the remaining axes AUTO (partial-manual).  ``check_vma`` is
+    REQUIRED: defaulting it off would let a future call site disable
+    checking silently, where the ``unsafe-shard-map`` lint can only see
+    (and demand a pin justification for) an explicit literal ``False``.
     """
-    if HAS_VMA_SHARD_MAP:
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=(
-                frozenset(mesh.shape)
-                if manual_axes is None
-                else frozenset(manual_axes)
-            ),
-            check_vma=check_vma,
-        )
-    if manual_axes is not None:
-        raise NotImplementedError(
-            "partial-manual shard_map (manual_axes=...) needs the "
-            "vma-typed API (jax.shard_map + jax.lax.pcast): this jax "
-            "version's experimental API cannot run collectives with auto "
-            "axes — drop manual_axes (replicated over the auto axes) or "
-            "upgrade jax"
-        )
-    from jax.experimental.shard_map import shard_map as _experimental
-
-    return _experimental(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
+        axis_names=(
+            frozenset(mesh.shape)
+            if manual_axes is None
+            else frozenset(manual_axes)
+        ),
+        check_vma=check_vma,
     )

@@ -124,15 +124,10 @@ def gpipe(
             lambda a: jnp.zeros_like(a), mb0
         )
         outs0 = jax.tree_util.tree_map(lambda a: jnp.zeros_like(a), mbs)
-        from unicore_tpu.parallel.compat import HAS_VMA_SHARD_MAP
-
-        if manual_axes is not None and HAS_VMA_SHARD_MAP:
-            # partial-manual under the vma-typed generation (the ONLY one
-            # that can run it — same probe as the dispatch in compat.py):
-            # the scan carries BECOME pipe-varying after one tick (r is
-            # pipe-varying), so the initial values must be cast to match
-            # the carry type.  The experimental API has no varying-type
-            # system and partial-manual is refused there outright.
+        if manual_axes is not None:
+            # partial-manual: the scan carries BECOME pipe-varying after
+            # one tick (r is pipe-varying), so the initial values must be
+            # cast to match the carry type
             mark = lambda a: jax.lax.pcast(a, (pipe_axis,), to="varying")
             zeros_mb = jax.tree_util.tree_map(mark, zeros_mb)
             outs0 = jax.tree_util.tree_map(mark, outs0)
@@ -206,14 +201,11 @@ def gpipe(
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=jax.tree_util.tree_map(lambda _: mb_spec, microbatches),
-        # partial-manual needs the vma-typed generation (compat.py is the
-        # one dispatch point; seq_pipeline_plan keys on the SAME probe,
-        # and a direct caller on older jax gets a named refusal, never
-        # the XLA partitioner crash).  Partial-manual REQUIRES vma
-        # checking — the eager path's unmatch step otherwise builds an
-        # all-axes spec that mentions the auto axes and is rejected;
-        # full-manual keeps it off (the stage body may contain
-        # pallas_call, whose out_shapes carry no vma annotation).
+        # partial-manual REQUIRES vma checking — the eager path's unmatch
+        # step otherwise builds an all-axes spec that mentions the auto
+        # axes and is rejected; full-manual keeps it off (the stage body
+        # may contain pallas_call, whose out_shapes carry no vma
+        # annotation).
         manual_axes=manual_axes,
         check_vma=manual_axes is not None,
     )

@@ -298,29 +298,8 @@ def seq_pipeline_plan(seq_len: int, enabled: bool, what: str = "stream"):
 
     mesh = get_global_mesh()
     n_seq = 1 if mesh is None else mesh.shape.get(SEQ_AXIS, 1)
-    # partial-manual shard_map (collectives over manual axes while 'seq'
-    # stays AUTO) needs the vma-typed shard_map generation — the SAME
-    # probe compat.py's dispatch and gpipe's carry cast key on, so the
-    # plan layer and the execution layer can never disagree; the 0.4.x
-    # experimental API hard-crashes XLA's SPMD partitioner on a ppermute
-    # under a nonempty `auto` set, so older jax degrades to the
-    # replicated-over-seq fallback below instead of composing pp x sp
-    from unicore_tpu.parallel.compat import (
-        PARTIAL_MANUAL_OK as partial_manual_ok,
-    )
-
-    if not (
-        enabled and n_seq > 1 and seq_len % n_seq == 0 and partial_manual_ok
-    ):
-        if enabled and n_seq > 1 and not partial_manual_ok:
-            warn_once(
-                logging.getLogger(__name__),
-                f"{what} seq sharding: this jax version's shard_map cannot "
-                "run pipeline collectives with 'seq' left AUTO "
-                "(partial-manual); running the pipeline replicated over "
-                "seq (jax >= 0.7 re-enables the dp x pp x sp composition)",
-            )
-        elif enabled and n_seq > 1:
+    if not (enabled and n_seq > 1 and seq_len % n_seq == 0):
+        if enabled and n_seq > 1:
             warn_once(
                 logging.getLogger(__name__),
                 f"{what} seq sharding: seq axis {n_seq} does not divide "

@@ -40,6 +40,7 @@ import numpy as np
 
 from unicore_tpu.checkpoint.emergency import Deadline
 from unicore_tpu.distributed import chaos
+from unicore_tpu.platform_utils import on_tpu
 from unicore_tpu.serve import request as rq
 from unicore_tpu.serve.admission import AdmissionQueue
 from unicore_tpu.serve.engine import (
@@ -193,7 +194,7 @@ class DecodeEngine(ServeEngine):
         # donation keeps the pool update in-place on TPU; CPU ignores
         # donation with a per-call warning, so only request it where it
         # works
-        donate = jax.default_backend() == "tpu"
+        donate = on_tpu()
 
         @functools.partial(
             jax.jit, donate_argnums=(3, 4) if donate else ()

@@ -35,6 +35,7 @@ clean exit, and exposes ``POST /v1/reload`` for the router's rolling
 reload.  See docs/serving.md.
 """
 
+import json
 import logging
 import os
 import signal
@@ -495,19 +496,22 @@ def main(args) -> int:
 
     from unicore_tpu.checkpoint.emergency import Deadline, deadline_scope
     from unicore_tpu.distributed import chaos
+    from unicore_tpu.platform_utils import (
+        configure_compilation_cache,
+        describe_devices,
+    )
     from unicore_tpu.serve.http import bind_server
 
-    if getattr(args, "jax_compilation_cache_dir", None):
-        jax.config.update(
-            "jax_compilation_cache_dir", args.jax_compilation_cache_dir
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    configure_compilation_cache(
+        getattr(args, "jax_compilation_cache_dir", None)
+    )
 
     chaos.configure(args)
     # which fleet replica this process is — the @IDX target of the
     # replica-loss / replica-stall chaos kinds
     chaos.set_replica_index(getattr(args, "replica_index", 0) or 0)
     logger.info(args)
+    logger.info("DEVICES " + json.dumps(describe_devices()))
 
     # serve-plane event journal (docs/observability.md): sheds, reload
     # outcomes, drains — default location is beside the served

@@ -9,6 +9,7 @@ validation metric, and the --max-epoch / --max-update / --stop-time-hours /
 SPMD step instead of a torch DDP loop.
 """
 
+import json
 import logging
 import math
 import os
@@ -264,23 +265,27 @@ def main(args) -> None:
     import jax
     import numpy as np
 
+    from unicore_tpu.platform_utils import (
+        configure_compilation_cache,
+        describe_devices,
+    )
+
     np.random.seed(args.seed)
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
-    if getattr(args, "jax_compilation_cache_dir", None):
-        # persistent XLA compile cache: restarts and repeated runs of the
-        # same config reload their train-step programs instead of
-        # recompiling (docs/performance.md)
-        jax.config.update(
-            "jax_compilation_cache_dir", args.jax_compilation_cache_dir
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # persistent XLA compile cache: restarts and repeated runs of the same
+    # config reload their train-step programs instead of recompiling
+    # (docs/performance.md, "Where the compile cache lives")
+    configure_compilation_cache(
+        getattr(args, "jax_compilation_cache_dir", None)
+    )
 
     if distributed_utils.is_master(args):
         for d in (args.save_dir, args.tmp_save_dir):
             checkpoint_utils.verify_checkpoint_directory(d)
 
     logger.info(args)
+    logger.info("DEVICES " + json.dumps(describe_devices()))
 
     task = tasks.setup_task(args)
     model = task.build_model(args)
@@ -609,8 +614,8 @@ def _finalize_valid_stats(args, trainer, stats: Dict[str, Any]) -> Dict[str, Any
 def cli_main(modify_parser: Optional[Callable] = None) -> None:
     # UNICORE_TPU_PLATFORM=cpu forces the virtual-CPU mesh BEFORE any jax
     # backend init (UNICORE_TPU_CPU_DEVICES sets its size, default 8) —
-    # lets the example scripts and smoke runs proceed when no accelerator
-    # is reachable; see platform_utils for why JAX_PLATFORMS alone fails.
+    # the one explicit CPU switch for example scripts, tests and CI
+    # (platform_utils); nothing selects the CPU unasked.
     from unicore_tpu.platform_utils import force_host_cpu_from_env
 
     force_host_cpu_from_env(default_devices=8)
@@ -630,7 +635,14 @@ def cli_main(modify_parser: Optional[Callable] = None) -> None:
     if getattr(args, "elastic", False) and not elastic.is_child():
         # --elastic: this process becomes the per-host supervisor; training
         # runs in a child it restarts on retryable failures (the child
-        # re-parses this same argv with the child env marker set)
+        # re-parses this same argv with the child env marker set).
+        # One process per chip: a TPU belongs to the first process that
+        # initializes a backend on it, so the supervisor must never do so.
+        # Up to here it has only IMPORTED jax (option parsing pulls in the
+        # model registry) and elastic.supervise keeps it that way — it
+        # spawns, waits and classifies exit codes; no jax.devices(), no
+        # default_backend()/on_tpu(), no array (tests/test_elastic.py
+        # asserts the backend table stays empty).
         sys.exit(elastic.supervise(args, sys.argv[1:]))
 
     try:
