@@ -9,7 +9,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from unicore_tpu.platform_utils import force_host_cpu
+from unicore_tpu.platform_utils import force_host_cpu, on_tpu
 
 force_host_cpu(8)
 
@@ -27,6 +27,26 @@ if _cache != "0":
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     except Exception:
         pass
+
+
+@pytest.fixture(autouse=True)
+def pallas_interpret_mode(request):
+    """Every test starts with the kernels in interpret mode off the chip
+    and compiled on it, and leaves the override as it found it: a test
+    that switches the mode (the gate's own tests, an XLA-path reference)
+    cannot change what a later test in the same worker runs.  A test that
+    compiles for a described chip (it asks for ``one_chip``) has turned
+    interpret mode off in that fixture and keeps it so."""
+    from unicore_tpu.ops import _pallas
+
+    if "one_chip" in request.fixturenames:
+        yield
+        return
+    prev = _pallas._override
+    _pallas.set_interpret(not on_tpu())
+    yield
+    _pallas.set_interpret(prev)
+
 
 # ---------------------------------------------------------------------------
 # `-m fast` smoke subset: finishes in ~1 minute on one CPU core, touching

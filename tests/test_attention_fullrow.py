@@ -11,9 +11,6 @@ import jax.numpy as jnp
 
 from unicore_tpu.ops import flash_attention as fa
 from unicore_tpu.ops import attention_fullrow as fr
-from unicore_tpu.platform_utils import on_tpu
-
-fa.set_interpret(not on_tpu())
 
 
 def make_inputs(B, H, L, D, dtype, bias_shape=None, with_mask=False, seed=0):

@@ -22,6 +22,8 @@ from unicore_tpu.parallel.plan import (
     CACHE_HEAD_AXIS,
     ParallelPlan,
     PlanLegalityError,
+    get_global_plan,
+    set_global_plan,
 )
 from unicore_tpu.serve import request as rq
 from unicore_tpu.serve.decode import DecodeEngine, DecodeSequence
@@ -35,6 +37,18 @@ from unicore_tpu.serve.kv_cache import (
     scatter_prefill,
     scatter_rows,
 )
+
+@pytest.fixture(autouse=True)
+def no_inherited_plan():
+    """Every ``Trainer`` sets the process-global parallel plan and leaves
+    it; an engine built after one in the same worker would shard its KV
+    pools by that plan (and warm a fifth program).  These tests are about
+    an engine with no plan."""
+    plan_was = get_global_plan()
+    set_global_plan(None)
+    yield
+    set_global_plan(plan_was)
+
 
 # ---------------------------------------------------------------------------
 # shared tiny model

@@ -8,11 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from unicore_tpu.ops import flash_attention as fa_mod
 from unicore_tpu.ops.fused_norm import fused_layer_norm, fused_rms_norm
-from unicore_tpu.platform_utils import on_tpu
-
-fa_mod.set_interpret(not on_tpu())
 
 
 def ln_ref(x, w, b, eps=1e-5):

@@ -66,6 +66,7 @@ def test_bounds_flags_grid_overrun(tmp_path):
             x = jnp.zeros((128, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(4,),
                 in_specs=[pl.BlockSpec((64, 256), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((64, 256), lambda i: (i, 0)),
@@ -86,6 +87,7 @@ def test_bounds_flags_shifted_index_map(tmp_path):
             x = jnp.zeros((256, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(2,),
                 in_specs=[pl.BlockSpec((128, 256), lambda i: (i + 1, 0))],
                 out_specs=pl.BlockSpec((128, 256), lambda i: (i, 0)),
@@ -106,6 +108,7 @@ def test_bounds_clean_kernel_passes(tmp_path):
             x = jnp.zeros((256, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(2,),
                 in_specs=[pl.BlockSpec((128, 256), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((128, 256), lambda i: (i, 0)),
@@ -130,6 +133,7 @@ def test_tiling_flags_int8_sublane(tmp_path):
             x = jnp.zeros((64, 256), jnp.int8)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(8,),
                 in_specs=[pl.BlockSpec((8, 256), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((8, 256), lambda i: (i, 0)),
@@ -150,6 +154,7 @@ def test_tiling_flags_lane_violation(tmp_path):
             x = jnp.zeros((8, 192), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(2,),
                 in_specs=[pl.BlockSpec((8, 96), lambda i: (0, i))],
                 out_specs=pl.BlockSpec((8, 96), lambda i: (0, i)),
@@ -172,6 +177,7 @@ def test_tiling_clean_full_dim_and_stat_blocks_pass(tmp_path):
             x = jnp.zeros((32, 64), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(2,),
                 in_specs=[pl.BlockSpec((16, 64), lambda i: (i, 0))],
                 out_specs=[
@@ -201,6 +207,7 @@ def test_vmem_flags_oversized_io_block(tmp_path):
             x = jnp.zeros((2048, 2048), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(1,),
                 in_specs=[pl.BlockSpec((2048, 2048), lambda i: (0, 0))],
                 out_specs=pl.BlockSpec((2048, 2048), lambda i: (0, 0)),
@@ -221,6 +228,7 @@ def test_vmem_flags_oversized_scratch(tmp_path):
             x = jnp.zeros((8, 128), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=0,
                     grid=(2,),
@@ -248,6 +256,7 @@ def test_vmem_clean_modest_blocks_pass(tmp_path):
             x = jnp.zeros((512, 512), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(2,),
                 in_specs=[pl.BlockSpec((256, 512), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((256, 512), lambda i: (i, 0)),
@@ -271,6 +280,7 @@ def test_revisit_flags_unguarded_constant_output(tmp_path):
             x = jnp.zeros((512, 128), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(4,),
                 in_specs=[pl.BlockSpec((128, 128), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((128, 128), lambda i: (0, 0)),
@@ -291,6 +301,7 @@ def test_revisit_flags_ignored_second_axis(tmp_path):
             x = jnp.zeros((256, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(2, 2),
                 in_specs=[pl.BlockSpec((128, 128), lambda i, j: (i, j))],
                 out_specs=pl.BlockSpec((128, 128), lambda i, j: (i, 0)),
@@ -315,6 +326,7 @@ def test_revisit_clean_when_guarded(tmp_path):
             x = jnp.zeros((512, 128), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(4,),
                 in_specs=[pl.BlockSpec((128, 128), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((128, 128), lambda i: (0, 0)),
@@ -341,6 +353,7 @@ def test_revisit_clean_when_accumulating(tmp_path):
             x = jnp.zeros((512, 128), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(4,),
                 in_specs=[pl.BlockSpec((128, 128), lambda i: (i, 0))],
                 out_specs=pl.BlockSpec((128, 128), lambda i: (0, 0)),
@@ -370,6 +383,7 @@ def test_seed_flags_ring_seed_regression(tmp_path):
             x = jnp.zeros((256, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
                     grid=(2, 2),
@@ -396,6 +410,7 @@ def test_seed_flags_partially_mixed_seed(tmp_path):
             x = jnp.zeros((256, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
                     grid=(2, 2),
@@ -426,6 +441,7 @@ def test_seed_clean_when_every_axis_mixed(tmp_path):
             x = jnp.zeros((256, 256), jnp.float32)
             _pallas_call(
                 _kernel,
+                name="fx",
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
                     grid=(2, 2),
@@ -451,6 +467,7 @@ def test_coverage_flags_kernel_module_without_audit_case(tmp_path):
         def run(x):
             return _pallas_call(
                 _kernel,
+                name="fx",
                 grid=(1,),
                 in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
                 out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),

@@ -67,7 +67,6 @@ def pallas_on():
     set_quant_softmax_dropout_mode("on")
     set_quant_norm_mode("on")
     yield
-    _pallas.set_interpret(None)
     set_quant_matmul_mode(None)
     set_quant_softmax_dropout_mode(None)
     set_quant_norm_mode(None)

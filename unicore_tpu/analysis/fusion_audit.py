@@ -100,7 +100,8 @@ _SHAPE_RE = re.compile(r"\b([a-z]+\d*(?:e\d+m\d+(?:fn)?)?)\[([\d,]*)\]")
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\([^=]*?\)|\S+)\s+([\w\-]+)\("
 )
-_COMP_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s+\([^)]*\)\s*->.*{\s*$")
+# the parameter list may nest brackets: a loop body takes one tuple
+_COMP_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*{\s*$")
 _CALLED_RE = re.compile(r"(?:calls|to_apply)=%([\w.\-]+)")
 _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _REPLICA_GROUPS_RE = re.compile(r"replica_groups=\{((?:\{[\d,]*\},?)+)\}")

@@ -78,6 +78,8 @@ class CapturedKernel:
     line: int  # first line of the call expression
     grid: Tuple[int, ...]
     uses: Tuple[BlockUse, ...]
+    #: the kernel's ``name=``: what a profiler trace calls it
+    name: str = ""
 
     def inputs(self) -> List[BlockUse]:
         return [u for u in self.uses if u.kind == "in"]

@@ -450,6 +450,7 @@ def run_audit_cases(kernel_paths: Set[str]):
                 captures.append(CapturedKernel(
                     case=case_name, path=site_path, line=site_line,
                     grid=tuple(int(g) for g in grid), uses=tuple(uses),
+                    name=kw.get("name") or "",
                 ))
             return jax.tree_util.tree_map(
                 lambda sd: jnp.zeros(sd.shape, sd.dtype), out_shape

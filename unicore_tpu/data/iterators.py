@@ -29,6 +29,8 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from unicore_tpu.telemetry import spans
+
 from . import data_utils
 
 logger = logging.getLogger(__name__)
@@ -398,7 +400,10 @@ class _MapLoaderIterator(object):
     def _load(self, batch):
         if len(batch) == 0:
             return {}
-        return self.collate_fn([self.dataset[int(i)] for i in batch])
+        # on the thread that builds the batch: a worker, else the buffer's
+        # pump thread, else the consumer
+        with spans.annotation("data_produce"):
+            return self.collate_fn([self.dataset[int(i)] for i in batch])
 
     def __iter__(self):
         if self.num_workers <= 0:
@@ -597,13 +602,16 @@ class BufferedIterator(object):
         if self._producer is None:
             self._start_producer()
         self._maybe_warn_starved()
-        if self._stall_timeout > 0:
-            budget = self._stall_timeout * (
-                _SKIP_STALL_BUDGET_MULTIPLIER if _stall_relaxed else 1.0
-            )
-            item = self._get_with_stall_watchdog(budget)
-        else:
-            item = self._queue.get(True)
+        # ``depth``: the ready batches found waiting, i.e. the room the
+        # data layer has left (0 = the consumer is about to block)
+        with spans.annotation("data_next", depth=self._queue.qsize()):
+            if self._stall_timeout > 0:
+                budget = self._stall_timeout * (
+                    _SKIP_STALL_BUDGET_MULTIPLIER if _stall_relaxed else 1.0
+                )
+                item = self._get_with_stall_watchdog(budget)
+            else:
+                item = self._queue.get(True)
         if isinstance(item, Exception):
             raise item
         if item is _DONE:

@@ -45,12 +45,23 @@ class MaskedLMLoss(UnicoreLoss):
         )
         if isinstance(logits, tuple):
             logits = logits[0]
-        lprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        safe_target = jnp.where(masked_tokens, target, 0)
-        nll = -jnp.take_along_axis(lprobs, safe_target[..., None], axis=-1)[..., 0]
-        loss = jnp.sum(jnp.where(masked_tokens, nll, 0.0))
+        loss = self._masked_nll(logits, target, masked_tokens)
         loss = loss + aux * sample_size
         return loss, sample_size, self._logging(loss, target, sample_size)
+
+    @staticmethod
+    def _masked_nll(logits, target, valid):
+        """Summed NLL of ``target`` over the positions ``valid``.  Under a
+        ``loss`` scope: no module's name stack covers the loss, and a
+        device profile by scope (telemetry/hlo_scopes.py) should say whose
+        the log-softmax over the vocabulary is."""
+        with jax.named_scope("loss"):
+            lprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            safe_target = jnp.where(valid, target, 0)
+            nll = -jnp.take_along_axis(
+                lprobs, safe_target[..., None], axis=-1
+            )[..., 0]
+            return jnp.sum(jnp.where(valid, nll, 0.0))
 
     # hook: the MoE variant collects sown auxiliary losses here
     def _apply_model(self, model, params, **kwargs):
@@ -80,10 +91,7 @@ class MaskedLMLoss(UnicoreLoss):
         if isinstance(logits, tuple):
             logits = logits[0]
         gathered_target = jnp.take_along_axis(target, positions, axis=1)
-        lprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        safe_target = jnp.where(valid, gathered_target, 0)
-        nll = -jnp.take_along_axis(lprobs, safe_target[..., None], axis=-1)[..., 0]
-        loss = jnp.sum(jnp.where(valid, nll, 0.0))
+        loss = self._masked_nll(logits, gathered_target, valid)
         loss = loss + aux * sample_size
         return loss, sample_size, self._logging(loss, target, sample_size)
 

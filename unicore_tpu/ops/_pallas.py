@@ -177,11 +177,20 @@ def interpret_enabled() -> bool:
     return os.environ.get("UNICORE_TPU_PALLAS_INTERPRET", "0") == "1"
 
 
-def pallas_call(*args, **kwargs):
+def pallas_call(kernel, *, name: str, **kwargs):
+    """``pl.pallas_call`` with this tree's interpret switch and VMEM
+    limit.  ``name`` is required, stable and distinct per call site
+    (``flash_fwd``, ``flash_bwd_dq``, ...): it names the Mosaic custom
+    call in the compiled program, so a profiler trace says which kernel
+    an event is, and a refactor of the kernel body does not rename it."""
+    if not name:
+        raise ValueError("pallas_call needs a non-empty name=")
     kwargs.setdefault(
         "compiler_params", pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
     )
-    return pl.pallas_call(*args, interpret=interpret_enabled(), **kwargs)
+    return pl.pallas_call(
+        kernel, name=name, interpret=interpret_enabled(), **kwargs
+    )
 
 
 class ModeGate:

@@ -188,6 +188,7 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, group):
 
     return _pallas_call(
         wrapped,
+        name="fullrow_attn_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(H, B // G),
@@ -348,6 +349,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, group, do):
     scratch = [pltpu.VMEM((Lq, Lk), jnp.float32)] if has_bias else []
     res = _pallas_call(
         wrapped,
+        name="fullrow_attn_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(H, nbg),

@@ -165,6 +165,7 @@ def _decode_pallas(q, k_cache, v_cache, positions, bias, k_scale, v_scale):
 
     out = _pallas_call(
         wrapped,
+        name="decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H),

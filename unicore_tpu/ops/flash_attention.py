@@ -240,6 +240,7 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q, block_k)
 
     out, lse = _pallas_call(
         wrapped,
+        name="flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nq, nk),
@@ -540,6 +541,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
 
     dq = _pallas_call(
         dq_wrapped,
+        name="flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nq, nk),
@@ -571,6 +573,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
     # lint: shared-prng-stream
     dk, dv = _pallas_call(
         dkv_wrapped,
+        name="flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nk, nq),
@@ -649,6 +652,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
         # lint: shared-prng-stream
         dbias_full = _pallas_call(
             db_wrapped,
+            name="flash_bwd_dbias",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(Bb, H, nq, nk, R),

@@ -123,6 +123,7 @@ def _ln_fwd(x2, w, b, eps, rms, want_stats=True, scale=None, out_dtype=None):
 
     outs = _pallas_call(
         wrapped,
+        name="fused_norm_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -180,6 +181,7 @@ def _ln_bwd(x2, w, b, eps, rms, mean, rstd, dy2):
 
     dx = _pallas_call(
         functools.partial(_ln_dx_kernel, rms=rms),
+        name="fused_norm_bwd_dx",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BN, D), lambda i: (i, 0)),
@@ -208,6 +210,7 @@ def _ln_bwd(x2, w, b, eps, rms, mean, rstd, dy2):
 
     outs = _pallas_call(
         dwdb_wrapped,
+        name="fused_norm_bwd_dwdb",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BN, D), lambda i: (i, 0)),

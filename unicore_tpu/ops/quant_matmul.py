@@ -289,6 +289,7 @@ def quant_matmul_pallas(x_q, w_q, scale, bias=None, activation: str = "",
 
     out = _pallas_call(
         wrapped,
+        name="quant_matmul",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),

@@ -304,6 +304,9 @@ def _run(kernel, ishape, x3, plans, extras, seed, out_dtype, rate, use_hw,
 
     out = _pallas_call(
         wrapped,
+        # one site, two programs: the backward brings a cotangent
+        name="softmax_dropout_bwd" if extra_in is not None
+        else "softmax_dropout_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(R, nm),

@@ -1,6 +1,6 @@
 """Unified telemetry plane (docs/observability.md).
 
-Four pieces, one package:
+One package:
 
 * :mod:`~unicore_tpu.telemetry.journal` — the per-host JSONL **event
   journal** every verdict-class event lands in (``emit(kind, **fields)``;
@@ -15,6 +15,9 @@ Four pieces, one package:
   ``--metrics-port``;
 * :mod:`~unicore_tpu.telemetry.profiler` — ``--profile-steps START:END``
   programmatic **XLA profiling** windows;
+* :mod:`~unicore_tpu.telemetry.hlo_scopes` — the **scope table** of each
+  program launched inside a profiler capture (device operation -> the
+  module that owns it), left beside the capture;
 * :mod:`~unicore_tpu.telemetry.trace` — the ``unicore-tpu-trace`` CLI
   that merges per-host journals into one causally-ordered timeline,
   Perfetto JSON, and a post-mortem summary.
@@ -25,7 +28,7 @@ until configured), so subsystems never need a configured-or-not branch.
 """
 
 from unicore_tpu.telemetry import journal as _journal_mod
-from unicore_tpu.telemetry import profiler, spans
+from unicore_tpu.telemetry import hlo_scopes, profiler, spans
 from unicore_tpu.telemetry.journal import (
     ENV_RUN_ID,
     Journal,
@@ -48,6 +51,7 @@ __all__ = [
     "configure_supervisor",
     "emit",
     "ensure_run_id",
+    "hlo_scopes",
     "journal_dir",
     "journal_file",
     "journal_path",
@@ -102,4 +106,5 @@ def reset() -> None:
     _journal_mod.reset()
     spans.reset()
     profiler.reset()
+    hlo_scopes.reset()
     prometheus.reset()

@@ -7,7 +7,11 @@ the update counter first reaches START and stops at END (or at run end,
 whichever comes first), writing per-host TensorBoard-loadable traces to
 ``<telemetry-dir>/profile_rank<r>/`` and journaling ``profile-start`` /
 ``profile-stop`` events so merged timelines show exactly which updates
-the capture covers.
+the capture covers.  The program's own ``unicore:`` spans
+(telemetry/spans.py) land in the capture by themselves, and when it stops
+the scope table of each captured step program is written beside it as
+``hlo_scopes_<module>.json`` (telemetry/hlo_scopes.py; read both with
+``python3 -m benchmark.trace_scopes <file.xplane.pb>``).
 
 The tick is two integer compares per update when armed (and zero when
 not constructed); the capture itself costs whatever XLA's profiler
@@ -94,7 +98,7 @@ class ProfileWindow:
     def _finish(self, update: int) -> None:
         import jax
 
-        from unicore_tpu.telemetry import journal
+        from unicore_tpu.telemetry import hlo_scopes, journal
 
         try:
             jax.profiler.stop_trace()
@@ -102,11 +106,16 @@ class ProfileWindow:
             logger.warning(f"--profile-steps capture failed to stop: {err}")
         self.active = False
         self.done = True
+        # which module owns each device operation of the captured
+        # programs: hlo_scopes_<module>.json beside the trace
+        scopes = hlo_scopes.write_tables(self.out_dir)
         logger.info(
             f"PROFILE capture stopped at update {update}; trace in "
-            f"{self.out_dir} (load with TensorBoard or xprof)"
+            f"{self.out_dir} (load with TensorBoard or xprof; "
+            f"{len(scopes)} scope table(s) beside it)"
         )
-        journal.emit("profile-stop", update=int(update), dir=self.out_dir)
+        journal.emit("profile-stop", update=int(update), dir=self.out_dir,
+                     scope_tables=[os.path.basename(p) for p in scopes])
 
 
 _window: Optional[ProfileWindow] = None

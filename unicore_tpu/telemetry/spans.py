@@ -34,6 +34,15 @@ inside the gap and the measurement is only an upper bound: the journal
 record carries ``upper_bound: true`` so an input-bound run can never
 masquerade as device-bound.
 
+Every site timed here also opens a ``jax.profiler.TraceAnnotation``
+named ``unicore:<span>`` (:func:`annotation`), whether or not the recorder
+is enabled: with no profiler capture running it costs about half a
+microsecond, and in a capture (``--profile-steps``, the benchmark's
+``--trace 1``) the program's own spans stand on the device events' clock,
+nested by thread, so an idle gap of the device can be given to the host
+phase that covers it (``benchmark/trace_scopes.py`` reads them; the names
+are listed in docs/observability.md).
+
 Sampled updates also land a ``kind="span"`` record per phase in the
 event journal — the raw material ``unicore-tpu-trace`` turns into
 Chrome-trace (Perfetto) slices — and feed the cross-host straggler
@@ -55,6 +64,26 @@ DEVICE_SPAN = "device_busy"
 
 #: EMA horizon for the per-update step wall published via heartbeats
 _STEP_WALL_EMA = 0.2
+
+
+#: every annotation the program writes into a profiler capture starts so
+ANNOTATION_PREFIX = "unicore:"
+
+
+def annotation(name: str, **stats):
+    """``jax.profiler.TraceAnnotation("unicore:<name>", **stats)``: a host
+    span in the profiler's own trace (a no-op costing ~0.5 us while no
+    capture runs).  ``stats`` land as the event's stats (``update=<n>``
+    is the identifier the spans of one update share)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(ANNOTATION_PREFIX + name, **stats)
+
+
+def mark(name: str, **stats) -> None:
+    """An instant annotation: something that happened, not a phase."""
+    with annotation(name, **stats):
+        pass
 
 
 def _device_sync(handle) -> None:
@@ -118,14 +147,15 @@ class SpanRecorder:
         """Accumulate one host phase of the OPEN update (no-op when
         disabled or when no update is open — a plan exchange or transfer
         issued by validation must not count as hot-loop blockage)."""
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+        with annotation(name):
+            if not self.enabled:
+                yield
+                return
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
     @contextlib.contextmanager
     def between_span(self, name: str):
@@ -137,12 +167,14 @@ class SpanRecorder:
         here costs nothing and reads the device-busy gap at the earliest
         possible host point."""
         if not self.enabled:
-            yield
+            with annotation(name):
+                yield
             return
         self.collect_probe()
         t0 = time.perf_counter()
         try:
-            yield
+            with annotation(name):
+                yield
         finally:
             dt = time.perf_counter() - t0
             if dt > 0:

@@ -220,7 +220,7 @@ class Trainer(object):
         self._wall_lock = threading.Lock()
         self._transfer_wall = 0.0
         self._prefetch_wall = 0.0
-        self._compiled_seen = 0
+        self._compiled_seen: Dict[str, int] = {}  # program -> executables
         self._recompile_count = 0
         self._device_shares_logged = False
         # warmup is counted in updates run by THIS process: compiles are
@@ -1048,99 +1048,116 @@ class Trainer(object):
         # is this update (a 0:N window must capture update 0 — usually
         # the compile step, the most common profiling target)
         telemetry.profiler.tick(self.get_num_updates())
-        _hot_t0 = time.perf_counter()
+        # the update's host side in a profiler capture: one
+        # ``unicore:train_step`` annotation per update (opened after the
+        # tick above, which may start the capture, and closed before the
+        # tick below, which may stop it), its phases nested inside
+        update = self.get_num_updates()
+        with telemetry.spans.annotation("train_step", update=update):
+            _hot_t0 = time.perf_counter()
 
-        state = self._state
-        n = len(samples)
-        audit_args = None  # (kind, payload) for the one-shot --fusion-audit
+            state = self._state
+            n = len(samples)
+            audit_args = None  # (kind, payload), the one-shot --fusion-audit
 
-        with self._oom_guard(samples[0]):
-            if prepared is not None:
-                self._note_plan_consumed(plan[1], plan[0], plan[2])
-                self._prefetch_wall += prepared.prefetch_wall
-                # hot-thread prep guard: any _prepare_*/_plan_slots call on
-                # this thread before the dispatches finish is a prefetch
-                # contract violation (counted, asserted by the tests)
-                self._prepared_dispatch_thread = threading.get_ident()
-                try:
-                    new_state, self._macc = self._dispatch_prepared(
-                        state, prepared
-                    )
-                finally:
-                    self._prepared_dispatch_thread = None
-            elif n == 1:
-                mode = None
-                if plan is not None and plan[0] is not None:
+            with self._oom_guard(samples[0]):
+                if prepared is not None:
                     self._note_plan_consumed(plan[1], plan[0], plan[2])
-                    mode = plan[0][0]
-                sample, weight = self._prepare_sample_or_dummy(
-                    samples[0], mode=mode
-                )
-                new_state, self._macc = self._get_jit("train_step")(
-                    state, sample, self._step_scalars(0, weight), self._macc
-                )
-                audit_args = ("single", (sample, weight))
-                if not self._device_shares_logged:
-                    self._log_device_shares(sample)
-            else:
-                if plan is not None and plan[0] is not None:
-                    modes, sigs, stop_flags = plan
-                    self._note_plan_consumed(sigs, modes, stop_flags)
-                elif jax.process_count() > 1:
-                    modes, sigs, stop_flags = self._plan_slots(samples)
-                    self._note_plan_consumed(sigs, modes, stop_flags)
-                else:
-                    modes = None
-                    sigs = plan[1] if plan is not None else None
-                stacked = self._try_stack_microbatches(samples, modes,
-                                                       sigs=sigs)
-                if stacked is not None:
-                    # all micro-batches share shapes: ONE compiled program scans
-                    # the whole accumulation (no per-micro-batch dispatch)
-                    new_state, self._macc = self._get_jit(
-                        self._scan_jit_name()
-                    )(state, stacked, self._step_scalars(0), self._macc)
-                    audit_args = ("scan", stacked)
-                else:
-                    if self.grad_accum_mode == "adama":
-                        from unicore_tpu.parallel.mesh import warn_once
-
-                        warn_once(
-                            logger,
-                            "--grad-accum adama engages only on the "
-                            "stacked-scan accumulation path; this update's "
-                            "micro-batches have mixed geometry, so it falls "
-                            "back to buffer-mode sequential micro-steps "
-                            "(bound the shape set with --length-bucket to "
-                            "keep adama engaged)",
+                    self._prefetch_wall += prepared.prefetch_wall
+                    # hot-thread prep guard: any _prepare_*/_plan_slots call
+                    # on this thread before the dispatches finish is a
+                    # prefetch contract violation (counted, asserted by the
+                    # tests)
+                    self._prepared_dispatch_thread = threading.get_ident()
+                    try:
+                        new_state, self._macc = self._dispatch_prepared(
+                            state, prepared
                         )
-                    acc = None
-                    micro = self._get_jit("micro_step")
-                    for i, s in enumerate(samples):
+                    finally:
+                        self._prepared_dispatch_thread = None
+                elif n == 1:
+                    mode = None
+                    if plan is not None and plan[0] is not None:
+                        self._note_plan_consumed(plan[1], plan[0], plan[2])
+                        mode = plan[0][0]
+                    with telemetry.spans.annotation("prepare", update=update):
                         sample, weight = self._prepare_sample_or_dummy(
-                            s, mode=modes[i] if modes else None
+                            samples[0], mode=mode
                         )
-                        acc = micro(
-                            state["params"], state["loss_scale"], sample, acc,
-                            self._step_scalars(i, weight),
-                        )
-                    new_state, self._macc = self._get_jit("apply_step")(
-                        state, acc, self._step_scalars(0), self._macc
+                    new_state, self._macc = self._launch(
+                        "train_step", state, sample,
+                        self._step_scalars(0, weight), self._macc,
                     )
+                    audit_args = ("single", (sample, weight))
+                    if not self._device_shares_logged:
+                        self._log_device_shares(sample)
+                else:
+                    if plan is not None and plan[0] is not None:
+                        modes, sigs, stop_flags = plan
+                        self._note_plan_consumed(sigs, modes, stop_flags)
+                    elif jax.process_count() > 1:
+                        modes, sigs, stop_flags = self._plan_slots(samples)
+                        self._note_plan_consumed(sigs, modes, stop_flags)
+                    else:
+                        modes = None
+                        sigs = plan[1] if plan is not None else None
+                    with telemetry.spans.annotation("prepare", update=update):
+                        stacked = self._try_stack_microbatches(
+                            samples, modes, sigs=sigs
+                        )
+                    if stacked is not None:
+                        # all micro-batches share shapes: ONE compiled program
+                        # scans the whole accumulation (no per-micro-batch
+                        # dispatch)
+                        new_state, self._macc = self._launch(
+                            self._scan_jit_name(), state, stacked,
+                            self._step_scalars(0), self._macc,
+                        )
+                        audit_args = ("scan", stacked)
+                    else:
+                        if self.grad_accum_mode == "adama":
+                            from unicore_tpu.parallel.mesh import warn_once
 
-        finished_update = self.get_num_updates()
-        # dispatch span = hot-block wall minus the separately-recorded
-        # plan_exchange/h2d pieces; note_dispatched retains one tiny
-        # replicated output leaf for the lag-1 device_busy probe (sampled
-        # updates only — unsampled updates retain nothing, so they can
-        # never sync)
-        _spans.add_dispatch_residual(time.perf_counter() - _hot_t0)
-        _spans.note_dispatched(finished_update, new_state["loss_scale"])
-        self._state = new_state
-        self._cached_eval_params = None
-        self.set_num_updates(finished_update + 1)
-        _spans.end_update(finished_update)
-        telemetry.spans.journal_straggler(finished_update)
+                            warn_once(
+                                logger,
+                                "--grad-accum adama engages only on the "
+                                "stacked-scan accumulation path; this "
+                                "update's micro-batches have mixed geometry, "
+                                "so it falls back to buffer-mode sequential "
+                                "micro-steps (bound the shape set with "
+                                "--length-bucket to keep adama engaged)",
+                            )
+                        acc = None
+                        for i, s in enumerate(samples):
+                            with telemetry.spans.annotation(
+                                "prepare", update=update
+                            ):
+                                sample, weight = self._prepare_sample_or_dummy(
+                                    s, mode=modes[i] if modes else None
+                                )
+                            acc = self._launch(
+                                "micro_step", state["params"],
+                                state["loss_scale"], sample, acc,
+                                self._step_scalars(i, weight),
+                            )
+                        new_state, self._macc = self._launch(
+                            "apply_step", state, acc, self._step_scalars(0),
+                            self._macc,
+                        )
+
+            finished_update = update
+            # dispatch span = hot-block wall minus the separately-recorded
+            # plan_exchange/h2d pieces; note_dispatched retains one tiny
+            # replicated output leaf for the lag-1 device_busy probe (sampled
+            # updates only — unsampled updates retain nothing, so they can
+            # never sync)
+            _spans.add_dispatch_residual(time.perf_counter() - _hot_t0)
+            _spans.note_dispatched(finished_update, new_state["loss_scale"])
+            self._state = new_state
+            self._cached_eval_params = None
+            self.set_num_updates(finished_update + 1)
+            _spans.end_update(finished_update)
+            telemetry.spans.journal_straggler(finished_update)
         # --profile-steps: the POST-update tick closes the window at END
         # promptly instead of one update late (two int compares when
         # armed, nothing when not)
@@ -1206,25 +1223,38 @@ class Trainer(object):
         in their final layout, so the only per-update work here is the
         jitted call(s) themselves."""
         if item.kind == "single":
-            return self._get_jit("train_step")(
-                state, item.data, self._step_scalars(0, item.weight),
-                self._macc,
+            return self._launch(
+                "train_step", state, item.data,
+                self._step_scalars(0, item.weight), self._macc,
             )
         if item.kind == "scan":
-            return self._get_jit(self._scan_jit_name())(
-                state, item.data, self._step_scalars(0), self._macc
+            return self._launch(
+                self._scan_jit_name(), state, item.data,
+                self._step_scalars(0), self._macc,
             )
         assert item.kind == "micro", item.kind
         acc = None
-        micro = self._get_jit("micro_step")
         for i, sample in enumerate(item.data):
-            acc = micro(
-                state["params"], state["loss_scale"], sample, acc,
-                self._step_scalars(i, item.weight),
+            acc = self._launch(
+                "micro_step", state["params"], state["loss_scale"], sample,
+                acc, self._step_scalars(i, item.weight),
             )
-        return self._get_jit("apply_step")(
-            state, acc, self._step_scalars(0), self._macc
+        return self._launch(
+            "apply_step", state, acc, self._step_scalars(0), self._macc
         )
+
+    def _launch(self, name, *args):
+        """Call the jitted train program ``name``: the host side of the
+        call is the ``unicore:launch`` span of a profiler capture, and in
+        a capture the program's scope table (which module each of its
+        device operations belongs to) is left behind first, once per
+        program (telemetry/hlo_scopes.py) — before the call, so that the
+        first traced launch starts late and the device never waits for
+        it inside the traced window."""
+        fn = self._get_jit(name)
+        telemetry.hlo_scopes.note_launch(name, fn, args)
+        with telemetry.spans.annotation("launch", program=name):
+            return fn(*args)
 
     def prepare_prefetched(self, samples, modes, sigs):
         """Producer-thread batch prep for the device prefetcher: narrow,
@@ -1371,13 +1401,17 @@ class Trainer(object):
         """Total compiled-executable count across the train-step jit
         caches — the denominator of the one-XLA-program-per-update
         promise."""
-        total = 0
+        return sum(self._compiled_programs().values())
+
+    def _compiled_programs(self) -> Dict[str, int]:
+        """Compiled-executable count of each train-step program."""
+        counts = {}
         for key in self._TRAIN_PROGRAM_KEYS:
             fn = self._jit_cache.get(key)
             if fn is None:
                 continue
             try:
-                total += int(fn._cache_size())
+                counts[key] = int(fn._cache_size())
             except Exception:
                 # private jit API: a jax upgrade renaming it would silently
                 # zero the recompiles gauge AND mute the after-warmup
@@ -1389,7 +1423,7 @@ class Trainer(object):
                         "change?): the 'recompiles' metric and the "
                         "recompile-after-warmup warning are disabled"
                     )
-        return total
+        return counts
 
     def _log_device_shares(self, sample):
         """One ``DEVICE-SHARES {json}`` line per process, after the first
@@ -1416,15 +1450,26 @@ class Trainer(object):
         when one fires past ``--compile-warmup-updates`` — by then every
         batch geometry should have been seen (use --length-bucket to bound
         the geometry set if this keeps firing)."""
-        n = self._count_compiled_programs()
-        if n <= self._compiled_seen:
+        counts = self._compiled_programs()
+        n, seen = sum(counts.values()), sum(self._compiled_seen.values())
+        if n <= seen:
             return
-        grew = n - self._compiled_seen
-        first = self._compiled_seen == 0
-        self._compiled_seen = n
+        grew = n - seen
+        first = seen == 0
         self._recompile_count += grew
-        warmup = int(getattr(self.args, "compile_warmup_updates", 0) or 0)
+        # in a profiler capture the compile stands beside the gap it
+        # caused (a span around the compile itself cannot be opened after
+        # the fact)
         step = self.get_num_updates()
+        telemetry.spans.mark(
+            "recompiled", update=step - 1,
+            program=",".join(
+                k for k, c in counts.items()
+                if c > self._compiled_seen.get(k, 0)
+            ),
+        )
+        self._compiled_seen = counts
+        warmup = int(getattr(self.args, "compile_warmup_updates", 0) or 0)
         # warmup is process-relative: a resumed run re-compiles its working
         # set even though the global update counter is long past warmup
         if not first and warmup > 0 and self._updates_this_process > warmup:
@@ -1814,7 +1859,9 @@ class Trainer(object):
         metric (producer thread and training thread both report here)."""
         t0 = time.perf_counter()
         try:
-            yield
+            # both threads annotate: a capture tells them apart by thread
+            with telemetry.spans.annotation("h2d"):
+                yield
         finally:
             dt = time.perf_counter() - t0
             with self._wall_lock:
