@@ -17,7 +17,9 @@ with the persistent compile cache off (a described-device executable
 cannot be read back without a chip).
 """
 
+import math
 import os
+import re
 
 import jax
 import pytest
@@ -186,3 +188,96 @@ def test_two_level_reduction_with_flash_compiles_for_pod_x_data(
 
     compiled = jax.jit(step).lower(params, emb, tok).compile()
     _assert_kernel_sees_a_quarter(compiled)
+
+
+def _encoder_layer_grad_hlo(one_chip, monkeypatch, batch, length, embed,
+                            heads, ffn, bias_shape, post_ln, return_attn):
+    """Optimized HLO of one ``TransformerEncoderLayer`` forward + backward
+    in bf16 on one described chip, dropout off as the benchmark's cells run
+    it, the attention gate steered as ``_encoder_on_mesh`` steers it."""
+    import unicore_tpu.modules.multihead_attention as mha
+    from unicore_tpu.modules import TransformerEncoderLayer
+
+    jnp = jax.numpy
+    monkeypatch.setattr(mha, "on_tpu", lambda: True)
+    layer = TransformerEncoderLayer(
+        embed_dim=embed, ffn_embed_dim=ffn, attention_heads=heads,
+        dropout=0.0, attention_dropout=0.0, post_ln=post_ln,
+    )
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0),
+            jnp.zeros((batch, length, embed), jnp.bfloat16))),
+    )
+
+    def loss(params, x, bias, mask):
+        out = layer.apply(params, x, attn_bias=bias, padding_mask=mask,
+                          return_attn=return_attn, train=True)
+        return sum(jnp.sum(a.astype(jnp.float32))
+                   for a in jax.tree_util.tree_leaves(out))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        params, spec((batch, length, embed)), spec(bias_shape),
+        spec((batch, length), jnp.bool_),
+    ).compile().as_text()
+
+
+def _copies(hlo_text):
+    """(name, element count) of every ``copy`` instruction."""
+    return [
+        (m.group(1), math.prod(int(d) for d in m.group(2).split(",") if d))
+        for m in re.finditer(
+            r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* copy\(", hlo_text,
+            re.M)
+    ]
+
+
+def test_attention_projections_leave_no_activation_copy(one_chip,
+                                                        monkeypatch):
+    """``bert_base.train_mlm512``'s attention block: the projections hand
+    the full-row kernels their ``(B, H, L, D)`` operands and take the
+    result back with no standalone layout ``copy`` of an activation between
+    them (the flat projections left 14 per layer, 11.5% of the step's
+    device time on the chip: PERF.md, PR 25 / PR 26).  What may remain is
+    weight-sized.  A compile is not a chip run: it says the copies are
+    gone, not what the step costs."""
+    b, l, e, h = 32, 512, 768, 12
+    text = _encoder_layer_grad_hlo(
+        one_chip, monkeypatch, b, l, e, h, 3072, (1, h, l, l),
+        post_ln=True, return_attn=False)
+    big = [(n, size) for n, size in _copies(text) if size >= b * l * e]
+    assert not big, f"activation-sized copies: {big}"
+    assert "ble,ehd->bhld" in text and "bhld,hde->ble" in text
+    for kernel in ("fullrow_attn_fwd", "fullrow_attn_bwd"):
+        call = re.search(
+            rf"%{kernel}\S* = .*? custom-call\((.*?)\), "
+            r'custom_call_target="tpu_custom_call"', text)
+        assert call, f"no Mosaic call named {kernel}"
+        # (scalar-prefetch seed,) q, k, v: still (B, H, L, D), row-major
+        for name in call.group(1).split(", ")[1:4]:
+            name = re.escape(name.split("*/")[-1])
+            assert re.search(
+                rf"{name} = bf16\[{b},{h},{l},{e // h}\]\{{3,2,1,0[:}}]",
+                text), f"{kernel} operand {name}"
+
+
+def test_unimol_shaped_layer_compiles(one_chip, monkeypatch,
+                                      record_property):
+    """Uni-Mol's shape (64 heads of width 8, per-batch pair bias, scores
+    handed on): no kernel pins a layout here, so the projections stay flat
+    and XLA places the copies as it likes (the products that make the heads
+    themselves cost this encoder 15% at L = 128 on the chip: PERF.md,
+    PR 26); the count is recorded, not bounded."""
+    b, l, e, h = 16, 256, 512, 64
+    text = _encoder_layer_grad_hlo(
+        one_chip, monkeypatch, b, l, e, h, 2048, (b, h, l, l),
+        post_ln=False, return_attn=True)
+    big = [n for n, size in _copies(text) if size >= b * l * e]
+    record_property("activation_sized_copies", len(big))
+    assert "ble,ehd->bhld" not in text, (
+        f"head-major products; {len(big)} activation-sized copies: {big}")

@@ -2,7 +2,15 @@
 (reference /root/reference/unicore/modules/multihead_attention.py).
 
 TPU-native design: attention stays in (B, H, L, D) layout (one batched
-einsum -> MXU).  Two execution paths behind the same API:
+einsum -> MXU), and the projections produce and consume that layout
+(``QuantDense.heads_out`` / ``heads_in``).  Where a Mosaic kernel takes the
+operands (``_kernel_pins_layout``) they do so inside their own products:
+``in_proj`` (``q_proj`` / ``k_proj`` / ``v_proj``) contracts ``x`` with its
+``(E, T*E)`` kernel viewed as ``(E, T, H, D)``, ``out_proj`` contracts
+``(B, H, L, D)`` with its kernel viewed as ``(H, D, E)``, so no standalone
+layout copy of an activation sits between a projection and the kernel.
+Elsewhere the flat product and a transpose do, and XLA places them.  Two
+execution paths behind the same API:
 
 - **flash path** (default when shapes allow and ``return_attn`` is False):
   the Pallas blockwise kernel in ops/flash_attention.py — softmax + bias +
@@ -42,16 +50,6 @@ def _warn_flash_fallback(reason):
         f"flash attention unavailable ({reason}); using the fused-softmax "
         "path, which materializes the full attention matrix"
     )
-
-
-def _split_heads(x, num_heads):
-    b, l, d = x.shape
-    return x.reshape(b, l, num_heads, d // num_heads).transpose(0, 2, 1, 3)
-
-
-def _merge_heads(x):
-    b, h, l, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b, l, h * d)
 
 
 def _bias_to_bhll(bias, bsz, num_heads, tgt_len, src_len):
@@ -266,6 +264,63 @@ def _flash_ok(tgt_len, src_len, head_dim, dtype):
     return True, None
 
 
+def _quant_scores(quantize, train, return_attn):
+    """Whether the int8 serving program's quantized-score path
+    (``_quant_attend``) takes this call."""
+    return quantize == "int8" and not train and not return_attn
+
+
+def _flash_route(use_flash, return_attn, eff_dropout, attn_bias, bsz,
+                 num_heads, tgt_len, src_len, head_dim, dtype):
+    """The one decision whether the Mosaic attention kernel
+    (``_flash_data_parallel``) takes this call's q, k, v: ``(True,
+    bias_min, None)``, or ``(False, None, reason)`` with the reason to warn
+    about (None where flash was not asked for).  ``_attend`` routes by it;
+    the projections read it before q, k, v exist (``_kernel_pins_layout``),
+    from the same shapes."""
+    if not use_flash or return_attn:
+        return False, None, None
+    if eff_dropout > 0.0 and not on_tpu():
+        # in-kernel dropout uses TPU-only PRNG primitives
+        return False, None, "in-kernel dropout needs a TPU backend"
+    ok, reason = _flash_ok(tgt_len, src_len, head_dim, dtype)
+    if not ok:
+        return False, None, reason
+    bias_min = _bias_min_broadcast(attn_bias, bsz, num_heads, tgt_len, src_len)
+    if attn_bias is not None and bias_min is None:
+        return False, None, (
+            f"attn bias shape {attn_bias.shape} needs materialization"
+        )
+    return True, bias_min, None
+
+
+def _kernel_pins_layout(module, train, return_attn, attn_bias, bsz, tgt_len,
+                        src_len, head_dim, dtype, other_route=False):
+    """Whether this call's q, k, v go straight to the Mosaic attention
+    kernel, decided before the projections as ``_attend`` decides it after
+    them (same predicates, same arguments).  The kernel's custom call fixes
+    row-major ``(B, H, L, D)`` operands, so there the projections write and
+    read that layout inside their own products (``QuantDense.heads_fused``)
+    and no layout copy of an activation stands between them and the kernel:
+    11.5% of BERT-base's step on a v5e.  Everywhere else the flat product
+    and its transposes stay, the parent's program: where XLA's own
+    attention runs (``return_attn`` consumers such as Uni-Mol's pair
+    stream, a bias that needs materializing, dropout off the TPU, a shape
+    or backend the gate refuses) the fused form was measured to lose (15%
+    of Uni-Mol's encoder at L = 128: PERF.md, PR 26); on the routes
+    ``_attend`` tries first or that bypass it (``other_route``: decode,
+    ``seq_inside``; the ring / Ulysses request, the int8 scores) it was
+    never measured."""
+    if other_route or getattr(module, "use_ring", False):
+        return False
+    if _quant_scores(getattr(module, "quantize", ""), train, return_attn):
+        return False
+    return _flash_route(
+        module.use_flash, return_attn, module.dropout if train else 0.0,
+        attn_bias, bsz, module.num_heads, tgt_len, src_len, head_dim, dtype,
+    )[0]
+
+
 def _ring_ok(use_ring, return_attn, tgt_len, src_len, attn_bias,
              bsz, num_heads):
     """Gate for the sequence-parallel ring path: needs a live mesh with a
@@ -376,7 +431,7 @@ def _attend(
 
     eff_dropout = dropout_rate if train else 0.0
 
-    if quantize == "int8" and not train and not return_attn:
+    if _quant_scores(quantize, train, return_attn):
         # the quantized serving program takes the SAME path on every
         # backend so the fusion audit checks the program that serves
         # (fp8 quantizes the dense weights only — scores stay fp32)
@@ -452,41 +507,28 @@ def _attend(
         )
         return o, None, None
 
-    dropout_backend_ok = (
-        eff_dropout == 0.0 or on_tpu()
-    )  # in-kernel dropout uses TPU-only PRNG primitives
-    if use_flash and not return_attn and dropout_backend_ok:
-        shapes_ok, reason = _flash_ok(tgt_len, src_len, head_dim, q.dtype)
-    else:
-        shapes_ok, reason = False, None
-        if use_flash and not return_attn and not dropout_backend_ok:
-            reason = "in-kernel dropout needs a TPU backend"
-    if use_flash and not return_attn and not shapes_ok and reason is not None:
+    flash, bias_min, reason = _flash_route(
+        use_flash, return_attn, eff_dropout, attn_bias, bsz, num_heads,
+        tgt_len, src_len, head_dim, q.dtype,
+    )
+    if reason is not None:
         _warn_flash_fallback(reason)
-    if shapes_ok:
-        bias_min = _bias_min_broadcast(
-            attn_bias, bsz, num_heads, tgt_len, src_len
+    if flash:
+        seed = 0
+        if eff_dropout > 0.0:
+            seed = jax.random.randint(
+                module.make_rng("dropout"), (), 0, 2 ** 31 - 1,
+                dtype=jnp.int32,
+            )
+        kmask = (
+            None if key_padding_mask is None
+            else key_padding_mask.astype(jnp.int32)
         )
-        if attn_bias is not None and bias_min is None:
-            _warn_flash_fallback(
-                f"attn bias shape {attn_bias.shape} needs materialization"
-            )
-        if attn_bias is None or bias_min is not None:
-            seed = 0
-            if eff_dropout > 0.0:
-                seed = jax.random.randint(
-                    module.make_rng("dropout"), (), 0, 2 ** 31 - 1,
-                    dtype=jnp.int32,
-                )
-            kmask = (
-                None if key_padding_mask is None
-                else key_padding_mask.astype(jnp.int32)
-            )
-            o = _flash_data_parallel(
-                q, k, v, bias_min, kmask, tgt_len, src_len,
-                dropout_rate=eff_dropout, dropout_seed=seed,
-            )
-            return o, None, None
+        o = _flash_data_parallel(
+            q, k, v, bias_min, kmask, tgt_len, src_len,
+            dropout_rate=eff_dropout, dropout_seed=seed,
+        )
+        return o, None, None
 
     # fused-softmax path (materializes the attention matrix)
     attn_weights = jnp.einsum("bhqd,bhkd->bhqk", q, k)
@@ -580,8 +622,13 @@ class SelfMultiheadAttention(nn.Module):
         head_dim = embed_dim // self.num_heads
         assert head_dim * self.num_heads == embed_dim
         scaling = (head_dim * self.scaling_factor) ** -0.5
+        fused = _kernel_pins_layout(
+            self, train, return_attn, attn_bias, bsz, tgt_len, tgt_len,
+            head_dim, query.dtype,
+            other_route=cache_kv is not None or self.seq_inside,
+        )
 
-        qkv = QuantDense(
+        q, k, v = QuantDense(
             3 * embed_dim,
             use_bias=self.bias,
             name="in_proj",
@@ -589,11 +636,10 @@ class SelfMultiheadAttention(nn.Module):
             dtype=query.dtype,
             param_dtype=jnp.float32,
             quantize=self.quantize,
-        )(query)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = _split_heads(q, self.num_heads) * scaling
-        k = _split_heads(k, self.num_heads)
-        v = _split_heads(v, self.num_heads)
+            heads_out=(3, self.num_heads),
+            heads_fused=fused,
+        )(query)  # (B, H, L, D) each
+        q = q * scaling
 
         new_rows = None
         if cache_kv is not None:
@@ -618,7 +664,6 @@ class SelfMultiheadAttention(nn.Module):
                 quantize=self.quantize,
             )
 
-        o = _merge_heads(o)
         o = QuantDense(
             embed_dim,
             use_bias=self.bias,
@@ -627,6 +672,8 @@ class SelfMultiheadAttention(nn.Module):
             dtype=query.dtype,
             param_dtype=jnp.float32,
             quantize=self.quantize,
+            heads_in=self.num_heads,
+            heads_fused=fused,
         )(o)
         if cache_kv is not None:
             return o, new_rows
@@ -736,23 +783,28 @@ class CrossMultiheadAttention(nn.Module):
         assert embed_dim == self.embed_dim
         head_dim = embed_dim // self.num_heads
         scaling = (head_dim * self.scaling_factor) ** -0.5
+        fused = _kernel_pins_layout(
+            self, train, False, attn_bias, bsz, tgt_len, key.shape[1],
+            head_dim, query.dtype,
+        )
 
-        mk_dense = lambda name: nn.Dense(
+        mk_dense = lambda name, **heads: QuantDense(
             embed_dim,
             use_bias=self.bias,
             name=name,
             kernel_init=nn.initializers.normal(0.02),
             dtype=query.dtype,
             param_dtype=jnp.float32,
+            heads_fused=fused,
+            **heads,
         )
-        q = _split_heads(mk_dense("q_proj")(query), self.num_heads) * scaling
-        k = _split_heads(mk_dense("k_proj")(key), self.num_heads)
-        v = _split_heads(mk_dense("v_proj")(value), self.num_heads)
+        one = (1, self.num_heads)
+        (q,) = mk_dense("q_proj", heads_out=one)(query)
+        (k,) = mk_dense("k_proj", heads_out=one)(key)
+        (v,) = mk_dense("v_proj", heads_out=one)(value)
 
         o, _, _ = _attend(
-            self, q, k, v, key_padding_mask, attn_bias,
+            self, q * scaling, k, v, key_padding_mask, attn_bias,
             self.dropout, train, False, self.use_flash,
         )
-        o = _merge_heads(o)
-        o = mk_dense("out_proj")(o)
-        return o
+        return mk_dense("out_proj", heads_in=self.num_heads)(o)

@@ -6,8 +6,9 @@ The pieces (docs/serving.md, "Quantized inference"):
   boundary between a ``QuantDense(quantize_output=True)`` site and the
   quantized-input op that consumes it (``ops/quant_norm.py``);
 - :func:`calibration_scope` — a trace-time flag that makes every
-  :class:`~unicore_tpu.quant.dense.QuantDense` site run the fp32 path and
-  sow per-site activation absmax into the ``quant_calib`` collection;
+  :class:`~unicore_tpu.quant.dense.QuantDense` site run the fp32 path and,
+  where it was built with a mode, sow per-site activation absmax into the
+  ``quant_calib`` collection;
 - :mod:`~unicore_tpu.quant.calibrate` — the startup calibration pass:
   deterministic held-out batches through the warmed per-bucket programs,
   per-channel weight scales + per-site activation scales, persisted

@@ -7,7 +7,7 @@ The flow (docs/serving.md, "Quantized inference"):
    batches (one per warmed bucket edge, token ids from a fixed-seed
    stream) through the model's fp32 path inside
    :func:`~unicore_tpu.quant.calibration_scope`; every ``QuantDense``
-   site sows its input absmax (and output absmax for ``quantize_output``
+   site built with a mode sows its input absmax (and output absmax for ``quantize_output``
    sites) into the ``quant_calib`` collection with a running-max reducer.
    Same batches => bit-identical scales (the determinism test proves it).
 2. **prepare** — :func:`prepare` transforms the fp32 checkpoint tree:
