@@ -1,0 +1,421 @@
+"""The hybrid decoder's mechanisms at sizes a CPU holds: the chunked
+state-space scan against the token-by-token recurrence, the Mamba-2 mixer,
+grouped-KV attention, the LatentMoE that is told which experts it holds
+(its shares add up to the uncut layer; no pair is lost at the worst
+skew), the packing dataset, the chunked loss, and the whole model through
+the trainer.  The whole model against the plain reference over three
+updates is ``tests/benchmark/test_nemotron3.py``."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from unicore_tpu.modules.hybrid_decoder import split_pattern
+from unicore_tpu.modules.latent_moe import STATS, LatentMoE, relu2
+from unicore_tpu.modules.mamba2 import Mamba2Mixer
+from unicore_tpu.modules.multihead_attention import GroupedQueryAttention
+from unicore_tpu.ops.ssd_scan import ssd_recurrence, ssd_scan
+
+
+def scan_inputs(L, b=2, H=4, P=8, G=2, N=16, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    return (
+        jax.random.normal(ks[0], (b, L, H, P)),
+        jax.nn.softplus(jax.random.normal(ks[1], (b, L, H))),
+        -jnp.exp(0.3 * jax.random.normal(ks[2], (H,))),
+        jax.random.normal(ks[3], (b, L, G, N)),
+        jax.random.normal(ks[4], (b, L, G, N)),
+        jax.random.normal(ks[5], (H,)),
+    )
+
+
+# lengths that are, and are not, multiples of the chunk (16); one shorter
+# than a chunk
+@pytest.mark.parametrize("L", [32, 64, 37, 5])
+def test_chunked_scan_is_the_recurrence_forward_and_gradients(L):
+    args = scan_inputs(L)
+    got, want = ssd_scan(*args, chunk=16), ssd_recurrence(*args)
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
+    every = tuple(range(len(args)))
+    g_got = jax.grad(lambda *a: jnp.sum(jnp.sin(ssd_scan(*a, chunk=16))), every)(*args)
+    g_want = jax.grad(lambda *a: jnp.sum(jnp.sin(ssd_recurrence(*a))), every)(*args)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.abs(b).max()))
+
+
+def test_chunked_scan_without_the_skip_term_differs():
+    """``D x_t`` is part of the result: leaving it out is seen."""
+    args = scan_inputs(32)
+    assert float(jnp.abs(
+        ssd_scan(*args, chunk=16) - ssd_scan(*args[:5], None, chunk=16)
+    ).max()) > 0.1
+
+
+@pytest.mark.parametrize("pattern,want", [
+    ("*EMEMEMEMEM", ("*", "EM", 5)),
+    ("*EMEM", ("*", "EM", 2)),
+    ("MMMM", ("", "M", 4)),
+    ("M*E", ("M*E", "", 0)),
+    ("MEMEM*EMEM", ("MEMEM*", "EM", 2)),
+])
+def test_split_pattern(pattern, want):
+    head, unit, repeats = split_pattern(pattern)
+    assert (head, unit, repeats) == want
+    assert head + unit * repeats == pattern
+
+
+# -- shares of the mixers --------------------------------------------------------
+
+def mamba_share(params, j, shares, H, P, G, N):
+    """The parameters of share ``j`` of a mixer whose heads and groups are
+    divided evenly over ``shares``."""
+    inner, bc = H * P, G * N
+    hs = slice(j * inner // shares, (j + 1) * inner // shares)
+    gs = slice(j * bc // shares, (j + 1) * bc // shares)
+    heads = slice(j * H // shares, (j + 1) * H // shares)
+    cols = lambda a, parts: jnp.concatenate(
+        [a[..., lo:lo + w][..., s] for lo, w, s in parts], axis=-1
+    )
+    xbc = [(0, inner, hs), (inner, bc, gs), (inner + bc, bc, gs)]
+    p = params["params"]
+    return {"params": {
+        "in_proj": {"kernel": cols(
+            p["in_proj"]["kernel"],
+            [(0, inner, hs)] + [(inner + lo, w, s) for lo, w, s in xbc]
+            + [(2 * inner + 2 * bc, H, heads)],
+        )},
+        "conv_kernel": cols(p["conv_kernel"], xbc),
+        "conv_bias": cols(p["conv_bias"], xbc),
+        "dt_bias": p["dt_bias"][heads], "A_log": p["A_log"][heads],
+        "D_skip": p["D_skip"][heads],
+        "norm": {"weight": p["norm"]["weight"][hs]},
+        "out_proj": {"kernel": p["out_proj"]["kernel"][hs]},
+    }}
+
+
+def test_mamba_head_shares_add_up_to_the_uncut_mixer():
+    """8 heads in 2 groups over 2 shares: a share holds 4 heads and their
+    group, and the gated norm is per group, so the shares' ``out_proj``
+    outputs add up to the whole mixer's."""
+    H, P, G, N, d = 8, 8, 2, 16, 32
+    sizes = dict(head_dim=P, state_size=N, chunk_size=16)
+    whole = Mamba2Mixer(d, num_heads=H, n_groups=G, **sizes)
+    u = jax.random.normal(jax.random.key(1), (2, 40, d))
+    params = whole.init(jax.random.key(2), u)
+    params = jax.tree_util.tree_map(  # the conv bias starts at zero
+        lambda a: a + 0.1 * jax.random.normal(jax.random.key(3), a.shape), params
+    )
+    part = Mamba2Mixer(d, num_heads=H // 2, n_groups=G // 2, **sizes)
+    total = sum(
+        part.apply(mamba_share(params, j, 2, H, P, G, N), u) for j in range(2)
+    )
+    np.testing.assert_allclose(total, whole.apply(params, u), atol=2e-5)
+
+
+def test_attention_head_shares_add_up_and_grouped_kv_is_repeated_kv():
+    H, KV, D, d, L = 4, 2, 16, 32, 24
+    whole = GroupedQueryAttention(d, num_heads=H, num_kv_heads=KV, head_dim=D)
+    x = jax.random.normal(jax.random.key(1), (2, L, d))
+    params = whole.init(jax.random.key(2), x)
+    p = params["params"]
+    want = whole.apply(params, x)
+
+    # against plain attention with the KV heads repeated to the query heads
+    heads = lambda t, n: t.reshape(2, L, n, D).transpose(0, 2, 1, 3)
+    q = heads(x @ p["q_proj"]["kernel"], H) * D ** -0.5
+    k = jnp.repeat(heads(x @ p["k_proj"]["kernel"], KV), H // KV, axis=1)
+    v = jnp.repeat(heads(x @ p["v_proj"]["kernel"], KV), H // KV, axis=1)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    scores = jnp.where(jnp.arange(L)[None] > jnp.arange(L)[:, None], -jnp.inf, scores)
+    o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), v)
+    plain = o.transpose(0, 2, 1, 3).reshape(2, L, H * D) @ p["out_proj"]["kernel"]
+    np.testing.assert_allclose(want, plain, atol=2e-5)
+
+    # 4 query heads over 2 shares, each with its own KV head
+    part = GroupedQueryAttention(d, num_heads=2, num_kv_heads=1, head_dim=D)
+    total = 0.0
+    for j in range(2):
+        qs, ks = slice(j * 2 * D, (j + 1) * 2 * D), slice(j * D, (j + 1) * D)
+        total = total + part.apply({"params": {
+            "q_proj": {"kernel": p["q_proj"]["kernel"][:, qs]},
+            "k_proj": {"kernel": p["k_proj"]["kernel"][:, ks]},
+            "v_proj": {"kernel": p["v_proj"]["kernel"][:, ks]},
+            "out_proj": {"kernel": p["out_proj"]["kernel"][qs]},
+        }}, x)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+MOE = dict(latent_dim=16, expert_dim=24, shared_dim=40, n_routed=16, top_k=4,
+           routed_scale=2.5)
+
+
+def moe_layer_and_params(d=32, n=48):
+    whole = LatentMoE(d, **MOE)
+    h = jax.random.normal(jax.random.key(1), (2, n // 2, d))
+    params = whole.init(jax.random.key(2), h)
+    params = jax.tree_util.tree_map(
+        lambda a: a + 0.3 * jax.random.normal(jax.random.key(3), a.shape), params
+    )
+    return whole, params, h
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """16 experts over 4 shares: the held parts of all shares, with the
+    shared expert counted once, are the uncut layer's output."""
+    d = 32
+    whole, params, h = moe_layer_and_params(d)
+    p = params["params"]
+    want, stats = whole.apply(params, h)
+    shared = relu2(h @ p["shared_fc1"]["kernel"]) @ p["shared_fc2"]["kernel"]
+    total, pairs = shared, 0.0
+    for j in range(4):
+        held = slice(4 * j, 4 * j + 4)
+        share = dict(p, experts_fc1=p["experts_fc1"][held],
+                     experts_fc2=p["experts_fc2"][held])
+        y, st = LatentMoE(d, n_held=4, first_held=4 * j, **MOE).apply(
+            {"params": share}, h
+        )
+        total = total + (y - shared)
+        pairs += float(st[STATS.index("pairs_here")])
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    # every token's top_k choices fall in exactly one share each
+    assert pairs == h.shape[0] * h.shape[1] * MOE["top_k"]
+
+
+@pytest.mark.parametrize("favoured", [(5,), (4, 5, 6, 7)])
+def test_no_pair_is_lost_under_a_routing_skewed_on_purpose(monkeypatch, favoured):
+    """Routing skewed on purpose: the selection bias sends every token to
+    one held expert (its load is every token, the others' are whatever the
+    scores give), or to all four held experts at once: every token on every
+    held expert it can choose, the case the buffer is sized for
+    (``buffer_rows``), which fills it to the last pair.  Either way the
+    layer's routed part is the plain loop over the held experts, pair for
+    pair."""
+    from unicore_tpu.modules import latent_moe
+
+    monkeypatch.setattr(latent_moe, "TILE", 8)
+    d = 32
+    _, params, h = moe_layer_and_params(d)
+    n = h.shape[0] * h.shape[1]
+    p = dict(params["params"])
+    p["correction"] = p["correction"].at[jnp.asarray(favoured)].set(100.0)
+    share = dict(p, experts_fc1=p["experts_fc1"][4:8],
+                 experts_fc2=p["experts_fc2"][4:8])
+    got, st = LatentMoE(d, n_held=4, first_held=4, **MOE).apply(
+        {"params": share}, h
+    )
+
+    # the same, by hand: every chosen (token, held expert) pair, one by one
+    tokens = h.reshape(n, d)
+    s = jax.nn.sigmoid(tokens @ p["router"])
+    _, idx = jax.lax.top_k(s + p["correction"], MOE["top_k"])
+    chosen = jnp.take_along_axis(s, idx, axis=1)
+    w = chosen / chosen.sum(-1, keepdims=True) * MOE["routed_scale"]
+    latent = tokens @ p["latent_down"]["kernel"]
+    routed = jnp.zeros_like(latent)
+    loads = []
+    for e in range(4, 8):
+        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)
+        loads.append(int((idx == e).sum()))
+        routed = routed + w_e[:, None] * (
+            relu2(latent @ p["experts_fc1"][e]) @ p["experts_fc2"][e]
+        )
+    want = routed @ p["latent_up"]["kernel"] + (
+        relu2(tokens @ p["shared_fc1"]["kernel"]) @ p["shared_fc2"]["kernel"]
+    )
+    np.testing.assert_allclose(got.reshape(n, d), want, atol=5e-5)
+    assert float(st[STATS.index("pairs_here")]) == sum(loads)
+    assert float(st[STATS.index("load_max")]) == n
+    if len(favoured) == 4:  # the buffer's worst case, reached
+        assert sum(loads) == n * 4
+        assert latent_moe.buffer_rows(n, MOE["top_k"], 4) == n * 4 + 4 * 8
+
+
+# -- the loss and the data ----------------------------------------------------------
+
+def test_chunked_loss_is_the_whole_loss_with_its_gradients():
+    from unicore_tpu.losses.lm_cross_entropy import chunked_lm_nll
+
+    T, d, V = 50, 16, 37  # 50 tokens in chunks of 16: a padded tail
+    x = jax.random.normal(jax.random.key(1), (T, d))
+    w = jax.random.normal(jax.random.key(2), (d, V))
+    target = jax.random.randint(jax.random.key(3), (T,), 0, V)
+    valid = jnp.arange(T) % 7 != 0
+
+    def whole(x, w):
+        lp = jax.nn.log_softmax(x @ w, axis=-1)
+        nll = -jnp.take_along_axis(lp, target[:, None], axis=-1)[:, 0]
+        return jnp.sum(jnp.where(valid, nll, 0.0))
+
+    chunked = lambda x, w: chunked_lm_nll(x, w, target, valid, 16)
+    np.testing.assert_allclose(chunked(x, w), whole(x, w), rtol=1e-5)
+    for a, b in zip(jax.grad(chunked, (0, 1))(x, w), jax.grad(whole, (0, 1))(x, w)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+class Documents:
+    """Documents of distinct tokens: document ``i`` is ``100 i + 0, 1, ...``."""
+
+    def __init__(self, sizes):
+        self.sizes = sizes
+
+    def __len__(self):
+        return len(self.sizes)
+
+    def __getitem__(self, i):
+        return 100 * i + np.arange(self.sizes[i])
+
+
+def test_packing_fills_every_block_and_loses_or_doubles_no_token():
+    from unicore_tpu.data import TokenBlockDataset
+
+    sizes = [7, 30, 1, 12, 25, 3, 18]  # 96 tokens: 9 blocks of 10, 6 left
+    packed = TokenBlockDataset(Documents(sizes), block_size=10, seed=3)
+    assert len(packed) == 9
+    orders = []
+    for epoch in (1, 2):
+        packed.set_epoch(epoch)
+        blocks = [packed[i] for i in range(len(packed))]
+        assert all(len(b) == 10 for b in blocks)
+        stream = np.concatenate(blocks)
+        assert len(set(stream)) == 90  # nothing doubled
+        # the stream is whole documents in the epoch's order, each in its
+        # own order: only the tail of the last one is missing
+        docs = [d for d, _ in zip(*np.unique(stream // 100, return_index=True))]
+        first_seen = sorted(docs, key=lambda d: list(stream // 100).index(d))
+        want = np.concatenate(
+            [100 * d + np.arange(sizes[d]) for d in first_seen]
+        )[:90]
+        np.testing.assert_array_equal(stream, want)
+        orders.append(first_seen)
+    assert orders[0] != orders[1]  # the order is drawn per epoch ...
+    packed.set_epoch(1)
+    again = np.concatenate([packed[i] for i in range(len(packed))])
+    packed.set_epoch(2)
+    np.testing.assert_array_equal(  # ... from (seed, epoch)
+        np.concatenate([packed[i] for i in range(9)]), stream
+    )
+    assert list(again // 100)[0] == orders[0][0]
+
+
+def test_packing_refuses_a_corpus_that_fills_no_block():
+    from unicore_tpu.data import TokenBlockDataset
+
+    with pytest.raises(ValueError, match="do not fill one block"):
+        TokenBlockDataset(Documents([3, 4]), block_size=10, seed=1)
+
+
+def test_tokenizer_cuts_a_document_only_when_asked(tmp_path):
+    from unicore_tpu.data import BertTokenizeDataset
+
+    words = ["[PAD]", "[UNK]", "[CLS]", "[SEP]"] + [f"w{c}" for c in "abcdefgh"]
+    (tmp_path / "dict.txt").write_text("\n".join(words) + "\n")
+    text = [" ".join(["wa", "wb", "wc"] * 10)]
+    cut = BertTokenizeDataset(text, str(tmp_path / "dict.txt"), max_seq_len=8)
+    whole = BertTokenizeDataset(text, str(tmp_path / "dict.txt"), max_seq_len=None)
+    assert len(cut[0]) == 8 and len(whole[0]) == 32
+
+
+def test_the_loss_states_what_a_capture_is_told_of_an_update():
+    """``trace_marks``: from one update's summed logging output, a
+    ``moe_route`` mark with the pairs of all expert layers and the loads per
+    layer; nothing for a model without routed experts."""
+    from unicore_tpu.losses.lm_cross_entropy import LMCrossEntropyLoss
+
+    sums = {"loss": 9.0, "_n": 1.0, "moe_layers": 5.0, "moe_pairs_here": 2422.0,
+            "moe_load_max": 1702.0, "moe_load_mean": 302.75}
+    assert LMCrossEntropyLoss.trace_marks(sums) == {"moe_route": {
+        "pairs_here": 2422, "load_max": 340.4, "load_mean": 60.55}}
+    assert LMCrossEntropyLoss.trace_marks({"loss": 9.0, "_n": 1.0}) == {}
+
+
+@pytest.mark.parametrize("n,top_k,held,want", [
+    (8192, 22, 8, 8192 * 8 + 8 * 128),    # the benchmark's share: 66,560 rows
+    (100, 2, 8, 256 + 8 * 128),           # 200 pairs at most, in whole tiles
+])
+def test_the_buffer_is_the_worst_case_from_shapes(n, top_k, held, want):
+    from unicore_tpu.modules.latent_moe import TILE, buffer_rows
+
+    rows = buffer_rows(n, top_k, held)
+    assert rows == want and rows % TILE == 0
+    # every token on every held expert it can choose, each expert's last
+    # tile nearly empty: no routing needs more
+    assert rows >= n * min(top_k, held) + held * (TILE - 1)
+
+
+# -- the whole model through the trainer ----------------------------------------------
+
+def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
+    """``--task causal_lm --arch nemotron_h_tiny --loss lm_cross_entropy``
+    on packed text, as ``unicore-tpu-train`` builds them (its parser, the
+    task's own pipeline, ``Trainer.train_step``): a falling loss, every
+    block full, the routing stats in the step's sums."""
+    from unicore_tpu import options, tasks
+    from unicore_tpu.data.indexed_dataset import make_builder
+    from unicore_tpu.losses import LOSS_REGISTRY
+    from unicore_tpu.models import build_model
+    from unicore_tpu.trainer import Trainer
+
+    words = [f"w{a}{b}" for a in "abcdefgh" for b in "abcdefgh"]
+    (tmp_path / "dict.txt").write_text(
+        "\n".join(["[PAD]", "[UNK]", "[CLS]", "[SEP]"] + words) + "\n"
+    )
+    rng = np.random.default_rng(0)
+    builder = make_builder(str(tmp_path / "train"))
+    for n in rng.integers(20, 200, 80):
+        builder.add_item(" ".join(rng.choice(words[:8], n)))
+    builder.finalize()
+
+    parser = options.get_training_parser()
+    args = options.parse_args_and_arch(parser, [
+        str(tmp_path), "--task", "causal_lm", "--loss", "lm_cross_entropy",
+        "--arch", "nemotron_h_tiny", "--tokens-per-sample", "64",
+        "--n-routed-experts-held", "8",
+        "--optimizer", "adam", "--lr-scheduler", "fixed", "--lr", "3e-3",
+        "--batch-size", "1", "--max-update", "20", "--seed", "1",
+    ])
+    task = tasks.setup_task(args)
+    task.load_dataset("train")
+    model = build_model(args, task)
+    trainer = Trainer(args, task, model, LOSS_REGISTRY[args.loss](task))
+    batches = task.get_batch_iterator(
+        task.datasets["train"], batch_size=8, seed=1, epoch=1,
+    ).next_epoch_itr(shuffle=True)
+    sums = []
+    for _, batch in zip(range(6), batches):
+        assert batch["net_input"]["src_tokens"].shape == (8, 64)
+        assert (np.asarray(batch["net_input"]["src_tokens"]) != 0).all()
+        trainer.train_step([batch])
+        sums.append({k: float(v) for k, v in jax.device_get(trainer._macc).items()})
+    per_update = np.diff([0.0] + [s["loss"] / 1.0 for s in sums])
+    assert per_update[-1] < per_update[0]
+    assert sums[-1]["moe_layers"] == 6 * 2
+    assert sums[-1]["moe_pairs_here"] > 0
+
+    # inside a profiler capture the loss's marks reach the trace: one
+    # ``unicore:moe_route`` per update, three updates late, from that
+    # update's own sums (the first update read only sets the base)
+    from benchmark import reduce, trace_scopes
+    from unicore_tpu import telemetry
+
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        for _, batch in zip(range(6), batches):
+            trainer.train_step([batch])
+        jax.block_until_ready(trainer.state["params"])
+    finally:
+        jax.profiler.stop_trace()
+        telemetry.hlo_scopes.reset()  # the scope tables the capture stashed
+    (found,) = [os.path.join(d, f) for d, _s, fs in os.walk(str(tmp_path / "trace"))
+                for f in fs if f.endswith(".xplane.pb")]
+    marks = [s[3] for spans in trace_scopes.host_spans(reduce._load(found)).values()
+             for s in spans if s[2] == "unicore:moe_route"]
+    assert [m["update"] for m in marks] == [7, 8]
+    for m in marks:  # 8 x 64 tokens, 2 expert layers, 8 of 16 experts held
+        assert set(m) == {"update", "pairs_here", "load_max", "load_mean"}
+        assert 0 < m["pairs_here"] <= 2 * 8 * 64 * 4
+        assert m["load_mean"] == pytest.approx(m["pairs_here"] / 2 / 8)
+        assert m["load_mean"] <= m["load_max"] <= 8 * 64

@@ -30,6 +30,7 @@ from .pad_dataset import (
 from .lmdb_dataset import LMDBDataset
 from .indexed_dataset import IndexedPickleDataset, IndexedPickleDatasetBuilder, make_builder
 from .sort_dataset import SortDataset, EpochShuffleDataset
+from .token_block_dataset import TokenBlockDataset
 
 from .iterators import (
     BufferedIterator,
@@ -71,6 +72,7 @@ __all__ = [
     "RightPadDataset2D",
     "ShardedIterator",
     "SortDataset",
+    "TokenBlockDataset",
     "TokenizeDataset",
     "UnicoreDataset",
     "data_utils",

@@ -16,7 +16,9 @@ except ImportError:
 
 
 class BertTokenizeDataset(BaseWrapperDataset):
-    def __init__(self, dataset, dict_path: str, max_seq_len: int = 512):
+    def __init__(self, dataset, dict_path: str, max_seq_len=512):
+        """``max_seq_len``: documents are cut to that many tokens; ``None``
+        keeps them whole (for a consumer that packs them into blocks)."""
         if BertWordPieceTokenizer is None:
             raise ImportError(
                 "BertTokenizeDataset requires the 'tokenizers' package"

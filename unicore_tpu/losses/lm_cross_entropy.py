@@ -4,6 +4,13 @@ The model predicts position t+1 from positions <= t, so the loss pairs
 ``logits[:, :-1]`` with ``target[:, 1:]`` and masks pad targets — the
 causal-LM counterpart of losses/cross_entropy.py, matching the
 tasks/causal_lm.py contract (target == input token stream).
+
+A model that states ``loss_chunk`` (models/nemotron_h.py) is asked for its
+final hidden states instead of its logits, and the output head and the
+loss run over ``loss_chunk`` tokens at a time (:func:`chunked_lm_nll`): at
+8,192 x 16,384 the float32 logits alone would be 512 MB, and as much again
+for their gradient.  What such a model returns beside the hidden states
+(an expert layer's routing stats) goes into the logging output.
 """
 
 import jax
@@ -14,6 +21,39 @@ from . import register_loss
 from .unicore_loss import UnicoreLoss
 
 
+def chunked_lm_nll(x, kernel, target, valid, chunk):
+    """Summed next-token negative log-likelihood with the logits of only
+    ``chunk`` tokens alive at a time.  ``x`` (T, d) hidden states, ``kernel``
+    (d, V), ``target`` (T,) and ``valid`` (T,) already shifted.  Each
+    chunk's logits are float32 (the product's accumulator, not a rounded
+    copy) and are computed again in the backward pass.  The kernel's
+    cotangent is summed over the chunks in float32."""
+    T, d = x.shape
+    pad = (-T) % chunk
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        target = jnp.pad(target, (0, pad))
+        valid = jnp.pad(valid, (0, pad))
+    kernel32 = kernel.astype(jnp.float32)
+
+    @jax.checkpoint
+    def one(args):
+        xc, tc, vc = args
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(xc, kernel32.astype(xc.dtype),
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope("loss"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+            return jnp.sum(jnp.where(vc, lse - picked, 0.0))
+
+    n = (T + pad) // chunk
+    return jnp.sum(jax.lax.map(one, (
+        x.reshape(n, chunk, d), target.reshape(n, chunk),
+        valid.reshape(n, chunk),
+    )))
+
+
 @register_loss("lm_cross_entropy")
 class LMCrossEntropyLoss(UnicoreLoss):
     def __init__(self, task):
@@ -21,6 +61,8 @@ class LMCrossEntropyLoss(UnicoreLoss):
         self.padding_idx = task.dictionary.pad()
 
     def forward(self, model, params, sample, rngs=None, train=True):
+        if getattr(model, "loss_chunk", 0):
+            return self._forward_chunked(model, params, sample, rngs, train)
         logits = model.apply(
             params, **sample["net_input"], train=train, rngs=rngs
         )
@@ -44,6 +86,32 @@ class LMCrossEntropyLoss(UnicoreLoss):
         }
         return loss, sample_size, logging_output
 
+    def _forward_chunked(self, model, params, sample, rngs, train):
+        x, extra = model.apply(
+            params, **sample["net_input"], train=train, rngs=rngs,
+            features_only=True,
+        )
+        B, L, d = x.shape
+        # every position predicts its successor; the last has none
+        target = jnp.concatenate(
+            [sample["target"][:, 1:],
+             jnp.full((B, 1), self.padding_idx, sample["target"].dtype)],
+            axis=1,
+        ).reshape(B * L)
+        valid = target != self.padding_idx
+        loss = chunked_lm_nll(
+            x.reshape(B * L, d), params["params"]["lm_head"],
+            jnp.where(valid, target, 0), valid, int(model.loss_chunk),
+        )
+        sample_size = jnp.sum(valid).astype(jnp.float32)
+        logging_output = {
+            "loss": loss,
+            "sample_size": sample_size,
+            "bsz": jnp.asarray(B, dtype=jnp.float32),
+            **extra,
+        }
+        return loss, sample_size, logging_output
+
     @staticmethod
     def reduce_metrics(logging_outputs, split="train") -> None:
         loss_sum = sum(log.get("loss", 0) for log in logging_outputs)
@@ -51,6 +119,29 @@ class LMCrossEntropyLoss(UnicoreLoss):
         metrics.log_scalar(
             "loss", loss_sum / sample_size / jnp.log(2), sample_size, round=3
         )
+        layers = sum(log.get("moe_layers", 0) for log in logging_outputs)
+        if layers > 0:
+            # an expert layer's routing, per layer and update
+            # (modules/latent_moe.py): how uneven the held experts' loads are
+            for key in ("moe_load_max", "moe_load_mean"):
+                total = sum(log.get(key, 0) for log in logging_outputs)
+                metrics.log_scalar(key, total / layers, 1, round=2)
+
+    @staticmethod
+    def trace_marks(sums):
+        """What a profiler capture is told of one update, from that
+        update's summed logging output (``Trainer._mark_update``): for a
+        model with routed experts one ``unicore:moe_route`` mark with the
+        (token, held expert) pairs of all its expert layers and the most
+        loaded held expert's and the mean load, per layer."""
+        layers = sums.get("moe_layers", 0)
+        if not layers:
+            return {}
+        return {"moe_route": dict(
+            pairs_here=int(sums["moe_pairs_here"]),
+            load_max=sums["moe_load_max"] / layers,
+            load_mean=sums["moe_load_mean"] / layers,
+        )}
 
     @staticmethod
     def logging_outputs_can_be_summed(is_train) -> bool:

@@ -1,0 +1,120 @@
+"""A decoder whose layers follow a pattern string (``nemotron_h``'s
+``hybrid_override_pattern``): ``M`` a Mamba-2 mixer, ``*`` grouped-KV
+causal attention, ``E`` a LatentMoE with a shared expert.  Every layer is
+
+    x = x + mixer(RMSNorm(x))
+
+with one mixer per layer, a final RMSNorm after the last, no biases (the
+convolution's apart) and no dropout.
+
+A pattern whose tail repeats (``*EMEMEMEMEM`` = ``*`` + 5 x ``EM``) runs
+the repeated unit as ONE traced body under ``nn.scan``, its parameters
+stacked on a leading axis (``units/layer_<j>/...`` with shape ``(repeats,
+...)``): the step program then holds one ``E`` and one ``M`` body instead
+of five of each, which is what keeps its compilation inside a benchmark
+run's time limit.  Layers before the repeated tail are ``layers_<i>``.
+Each layer (each unit, under the scan) is rematerialized in the backward
+pass when ``remat`` is set: only the residual stream is kept.
+"""
+
+from typing import Tuple
+
+import flax.linen as nn
+import jax.numpy as jnp
+
+from .latent_moe import STATS, LatentMoE
+from .layer_norm import RMSNorm
+from .mamba2 import Mamba2Mixer
+from .multihead_attention import GroupedQueryAttention
+
+KINDS = "M*E"
+
+
+def split_pattern(pattern: str) -> Tuple[str, str, int]:
+    """``(head, unit, repeats)`` with ``pattern == head + unit * repeats``
+    and ``repeats >= 2``, the split that leaves the fewest distinct layer
+    bodies (``len(head) + len(unit)``; the shorter head on a tie); or
+    ``(pattern, "", 0)`` where nothing repeats."""
+    best = (pattern, "", 0)
+    n = len(pattern)
+    for h in range(n):
+        for u in range(1, (n - h) // 2 + 1):
+            reps, rest = divmod(n - h, u)
+            if rest == 0 and pattern[h:] == pattern[h:h + u] * reps:
+                if h + u < len(best[0]) + len(best[1]):
+                    best = (pattern[:h], pattern[h:h + u], reps)
+                break
+    return best
+
+
+class HybridBlock(nn.Module):
+    kind: str
+    embed_dim: int
+    norm_eps: float
+    mamba: dict
+    attention: dict
+    moe: dict
+
+    @nn.compact
+    def __call__(self, x):
+        h = RMSNorm(self.embed_dim, eps=self.norm_eps, name="norm")(x)
+        stats = jnp.zeros((len(STATS),), jnp.float32)
+        if self.kind == "M":
+            y = Mamba2Mixer(self.embed_dim, name="mamba", **self.mamba)(h)
+        elif self.kind == "*":
+            y = GroupedQueryAttention(
+                self.embed_dim, name="self_attn", **self.attention
+            )(h)
+        elif self.kind == "E":
+            y, stats = LatentMoE(self.embed_dim, name="moe", **self.moe)(h)
+        else:
+            raise ValueError(
+                f"layer kind {self.kind!r} is not one of {KINDS!r}"
+            )
+        return x + y, stats
+
+
+class _Unit(nn.Module):
+    """One repeat of the pattern's repeated tail, as a scan body."""
+
+    pattern: str
+    block: dict
+
+    @nn.compact
+    def __call__(self, carry, _):
+        x, stats = carry
+        for j, kind in enumerate(self.pattern):
+            x, s = HybridBlock(kind=kind, name=f"layer_{j}", **self.block)(x)
+            stats = stats + s
+        return (x, stats), None
+
+
+class HybridDecoder(nn.Module):
+    pattern: str
+    embed_dim: int
+    norm_eps: float
+    mamba: dict       # Mamba2Mixer's sizes
+    attention: dict   # GroupedQueryAttention's sizes
+    moe: dict         # LatentMoE's sizes
+    remat: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        """``x`` (B, L, embed_dim) -> ``(x, stats)``: the final-normed
+        stream and the expert layers' routing stats summed over layers
+        (``latent_moe.STATS``; all zero where no layer is ``E``)."""
+        block = dict(embed_dim=self.embed_dim, norm_eps=self.norm_eps,
+                     mamba=self.mamba, attention=self.attention, moe=self.moe)
+        wrap = nn.remat if self.remat else (lambda cls: cls)
+        head, unit, repeats = split_pattern(self.pattern)
+        stats = jnp.zeros((len(STATS),), jnp.float32)
+        for i, kind in enumerate(head):
+            x, s = wrap(HybridBlock)(kind=kind, name=f"layers_{i}", **block)(x)
+            stats = stats + s
+        if repeats:
+            (x, stats), _ = nn.scan(
+                wrap(_Unit), variable_axes={"params": 0},
+                split_rngs={"params": True}, length=repeats,
+            )(pattern=unit, block=block, name="units")((x, stats), None)
+        x = RMSNorm(self.embed_dim, eps=self.norm_eps, name="final_norm")(x)
+        return x, stats

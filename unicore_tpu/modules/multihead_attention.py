@@ -808,3 +808,61 @@ class CrossMultiheadAttention(nn.Module):
             self.dropout, train, False, self.use_flash,
         )
         return mk_dense("out_proj", heads_in=self.num_heads)(o)
+
+
+def causal_bias(length, dtype):
+    """The additive causal mask ``(L, L)``: 0 at and below the diagonal, a
+    large finite negative above it.  Made from two iotas, so the compiled
+    program computes it (XLA would otherwise try to fold an ``L x L``
+    constant: 256 MB at L = 8192)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (length, length), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (length, length), 1)
+    return jnp.where(col > row, -1e30, 0.0).astype(dtype)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal self-attention with ``num_heads`` query heads on
+    ``num_kv_heads`` key/value heads (query head ``h`` reads KV head
+    ``h // (num_heads / num_kv_heads)``), head size ``head_dim`` stated
+    (not ``embed_dim / num_heads``), no bias, no positional term: the
+    attention layer of ``nemotron_h``.  K and V are repeated to the query
+    heads and go through :func:`_attend` like every other attention here,
+    so the Mosaic kernels take them where ``_flash_route`` says so; the
+    causal mask is the dense additive triangle (no kernel skips blocks
+    yet: ROADMAP S6).  ``num_heads`` / ``num_kv_heads`` are the heads held
+    here: the shares of a tensor-parallel split each own whole KV groups,
+    and their ``out_proj`` outputs add up to the whole layer's."""
+
+    embed_dim: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    dropout: float = 0.0
+    use_flash: bool = True
+
+    @nn.compact
+    def __call__(self, x, key_padding_mask=None, train: bool = False):
+        bsz, seq_len, _ = x.shape
+        H, KV, D = self.num_heads, self.num_kv_heads, self.head_dim
+        if H % KV:
+            raise ValueError(f"{H} query heads do not divide over {KV} KV heads")
+        bias = causal_bias(seq_len, x.dtype)
+        fused = _kernel_pins_layout(
+            self, train, False, bias, bsz, seq_len, seq_len, D, x.dtype,
+        )
+        dense = lambda name, features, **heads: QuantDense(
+            features, use_bias=False, name=name,
+            kernel_init=nn.initializers.normal(0.02), dtype=x.dtype,
+            param_dtype=jnp.float32, heads_fused=fused, **heads,
+        )
+        (q,) = dense("q_proj", H * D, heads_out=(1, H))(x)
+        (k,) = dense("k_proj", KV * D, heads_out=(1, KV))(x)
+        (v,) = dense("v_proj", KV * D, heads_out=(1, KV))(x)
+        if H != KV:
+            k = jnp.repeat(k, H // KV, axis=1)
+            v = jnp.repeat(v, H // KV, axis=1)
+        o, _, _ = _attend(
+            self, q * D ** -0.5, k, v, key_padding_mask, bias,
+            self.dropout, train, False, self.use_flash,
+        )
+        return dense("out_proj", self.embed_dim, heads_in=H)(o)

@@ -7,6 +7,10 @@ incremental-decode serving path (models/transformer_lm.py,
 docs/serving.md "Incremental decode") has a trainable decoder-only
 checkpoint behind it, end-to-end from ``examples/bert/make_example_data.py``
 text.
+
+With ``--tokens-per-sample N`` the documents are not padded one per row but
+packed: joined end to end and cut into blocks of exactly ``N`` tokens
+(data/token_block_dataset.py), one block per row, every row full.
 """
 
 import logging
@@ -16,8 +20,10 @@ from unicore_tpu.data import (
     BertTokenizeDataset,
     Dictionary,
     EpochShuffleDataset,
+    LRUCacheDataset,
     NestedDictionaryDataset,
     RightPadDataset,
+    TokenBlockDataset,
 )
 from unicore_tpu.tasks import register_task
 from unicore_tpu.tasks.bert import open_text_dataset
@@ -43,6 +49,14 @@ class CausalLMTask(UnicoreTask):
                  "batches with the flash-attention kernel's block size",
         )
 
+        parser.add_argument(
+            "--tokens-per-sample", default=0, type=int,
+            help="pack documents into blocks of this many tokens (joined "
+                 "by the end token, cut in order, the epoch's last short "
+                 "block dropped; no mask and no state reset at the joins); "
+                 "0 keeps one document per row, cut at --max-seq-len",
+        )
+
     def __init__(self, args, dictionary):
         super().__init__(args)
         self.dictionary = dictionary
@@ -64,17 +78,25 @@ class CausalLMTask(UnicoreTask):
 
     def load_dataset(self, split, combine=False, **kwargs):
         a = self.args
+        block = getattr(a, "tokens_per_sample", 0)
         tokens = BertTokenizeDataset(
             open_text_dataset(os.path.join(a.data, split)),
             os.path.join(a.data, "dict.txt"),
-            max_seq_len=a.max_seq_len,
+            # a document is cut to the model's positions only where it is
+            # a row of its own
+            max_seq_len=None if block else a.max_seq_len,
         )
+        if block:
+            # input and target read the same block: built once
+            tokens = LRUCacheDataset(
+                TokenBlockDataset(tokens, block, self.seed)
+            )
         batches = NestedDictionaryDataset(
             {
                 "net_input": {"src_tokens": self._padded(tokens)},
                 "target": self._padded(tokens),
             }
         )
-        if split == "train":
+        if split == "train" and not block:  # packing draws its own order
             batches = EpochShuffleDataset(batches, len(batches), self.seed)
         self.datasets[split] = batches

@@ -29,6 +29,7 @@ per update:
   times and collectives stay aligned.
 """
 
+import collections
 import contextlib
 import json
 import logging
@@ -69,6 +70,9 @@ def _narrow_dtype(x):
     if x.dtype == np.float64:
         return x.astype(np.float32)
     return x
+
+
+_copy_tree = jax.jit(lambda tree: jax.tree_util.tree_map(jnp.copy, tree))
 
 
 class Trainer(object):
@@ -202,6 +206,9 @@ class Trainer(object):
         self._nan_rerun_seen = 0.0  # overflow count already diagnosed
         self._cached_eval_params = None
         self._macc = None  # device-side metric sums (see flush_metrics)
+        # updates' sums awaiting the loss's annotations (see _mark_update)
+        self._marks_pending = collections.deque()
+        self._marks_seen = None
         self._vacc = None  # device-side eval sums (see finish_valid_accum)
         self._num_updates = 0
         self._loss_fn = task.loss_fn(model, loss)
@@ -1056,7 +1063,7 @@ class Trainer(object):
         with telemetry.spans.annotation("train_step", update=update):
             _hot_t0 = time.perf_counter()
 
-            state = self._state
+            state = self._undonated_scalars(self._state)
             n = len(samples)
             audit_args = None  # (kind, payload), the one-shot --fusion-audit
 
@@ -1153,6 +1160,7 @@ class Trainer(object):
             # never sync)
             _spans.add_dispatch_residual(time.perf_counter() - _hot_t0)
             _spans.note_dispatched(finished_update, new_state["loss_scale"])
+            self._mark_update(finished_update)
             self._state = new_state
             self._cached_eval_params = None
             self.set_num_updates(finished_update + 1)
@@ -1242,6 +1250,54 @@ class Trainer(object):
         return self._launch(
             "apply_step", state, acc, self._step_scalars(0), self._macc
         )
+
+    def _undonated_scalars(self, state):
+        """Under --donate-train-state (off unless asked for) a step program
+        consumes its input state, every leaf of it.  Whoever still holds a
+        leaf of the previous update's state to wait on (the spans' lag-1
+        device probe, ``note_dispatched``; a caller pacing its dispatches
+        on ``state["loss_scale"]``) would find it deleted, so the step is
+        handed copies of the scalar leaves (one small program an update)
+        and the originals stay valid.  The parameters and the optimizer's
+        state are what donation is for, and they are donated as before."""
+        if not getattr(self.args, "donate_train_state", False):
+            return state
+        scalars = {k: v for k, v in state.items()
+                   if k not in ("params", "opt", "ema")}
+        return {**state, **_copy_tree(scalars)}
+
+    #: updates between a dispatch and the read of its sums for the loss's
+    #: annotations.  The read waits for that update alone; three back, a
+    #: caller that keeps two updates in flight (the benchmark's driver) has
+    #: already seen it finish, so the ``train_step`` span holds no wait
+    _MARK_LAG = 3
+
+    def _mark_update(self, update):
+        """Inside a profiler capture, what the loss wants said of each
+        update: a loss with a ``trace_marks(sums) -> {name: stats}`` is
+        handed that update's own logging sums (the step's running sums
+        ``_MARK_LAG`` updates back, less the sums before them) and each
+        entry becomes one ``unicore:<name>`` mark.  Outside a capture, or
+        for a loss with nothing to say, it reads one attribute and one
+        boolean."""
+        marks = getattr(self.loss, "trace_marks", None)
+        if marks is None or not telemetry.hlo_scopes.capture_running():
+            self._marks_pending.clear()
+            self._marks_seen = None
+            return
+        if self._macc is None:
+            return
+        self._marks_pending.append((update, self._macc))
+        if len(self._marks_pending) <= self._MARK_LAG:
+            return
+        update, sums = self._marks_pending.popleft()
+        sums = {k: float(v) for k, v in jax.device_get(sums).items()}  # lint: explicit-sync
+        seen, self._marks_seen = self._marks_seen, sums
+        if seen is None or sums["_n"] <= seen["_n"]:
+            return  # the capture's first update, or the sums began anew
+        own = {k: v - seen.get(k, 0.0) for k, v in sums.items()}
+        for name, stats in marks(own).items():
+            telemetry.spans.mark(name, update=int(update), **stats)
 
     def _launch(self, name, *args):
         """Call the jitted train program ``name``: the host side of the
