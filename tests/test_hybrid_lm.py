@@ -7,6 +7,7 @@ the trainer.  The whole model against the plain reference over three
 updates is ``tests/benchmark/test_nemotron3.py``."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -229,9 +230,89 @@ def test_no_pair_is_lost_under_a_routing_skewed_on_purpose(monkeypatch, favoured
     np.testing.assert_allclose(got.reshape(n, d), want, atol=5e-5)
     assert float(st[STATS.index("pairs_here")]) == sum(loads)
     assert float(st[STATS.index("load_max")]) == n
+    assert float(st[STATS.index("tiles_used")]) == sum(-(-l // 8) for l in loads)
     if len(favoured) == 4:  # the buffer's worst case, reached
         assert sum(loads) == n * 4
         assert latent_moe.buffer_rows(n, MOE["top_k"], 4) == n * 4 + 4 * 8
+
+
+def _loads(n, loads):
+    """Expert ``e`` chosen by ``loads[e]`` tokens, spread over the ``n``."""
+    return jnp.stack([
+        jnp.zeros((n,), bool).at[(jnp.arange(l) * 7 + 3 * e) % n].set(True)
+        if l < n else jnp.ones((n,), bool)
+        for e, l in enumerate(loads)
+    ], axis=1)
+
+
+@pytest.mark.parametrize("tile,n,loads", [
+    (8, 48, (0, 0, 0, 0)),         # no token on any held expert: no trip
+    (8, 48, (0, 48, 0, 0)),        # every token on one expert
+    (8, 48, (48, 48, 48, 48)),     # the worst case: the layout full to the last pair
+    (8, 48, (13, 0, 1, 30)),       # uneven, the experts' last tiles partly empty
+    (8, 44, (44, 5, 0, 17)),       # n no multiple of the tile
+    (128, 100, (100, 0, 37, 99)),  # the real tile, longer than a column of tokens
+], ids=["none", "one-expert", "every-expert", "uneven", "n-not-whole-tiles",
+        "tile-longer-than-n"])
+def test_routed_experts_are_the_plain_sum_over_pairs(monkeypatch, tile, n, loads):
+    """``routed_experts`` against ``sum_e w[n, e] W2_e relu2(W1_e l_n)``
+    written out with no layout: the output and the gradients of all four
+    arguments, in float32."""
+    from unicore_tpu.modules import latent_moe
+
+    monkeypatch.setattr(latent_moe, "TILE", tile)
+    lat, f, Eh, top_k = 16, 24, len(loads), 4
+    ks = jax.random.split(jax.random.key(5), 5)
+    latent = jax.random.normal(ks[0], (n, lat))
+    w1 = 0.3 * jax.random.normal(ks[1], (Eh, lat, f))
+    w2 = 0.3 * jax.random.normal(ks[2], (Eh, f, lat))
+    pair = _loads(n, loads)
+    assert tuple(int(l) for l in pair.sum(0)) == loads
+    w_held = jnp.where(pair, jax.random.uniform(ks[3], (n, Eh), minval=0.1), 0.0)
+    g = jax.random.normal(ks[4], (n, lat))
+    rows = latent_moe.buffer_rows(n, top_k, Eh)
+
+    def plain(latent, w_held, w1, w2):
+        w = jnp.where(pair, w_held, 0.0)
+        return sum(w[:, e:e + 1] * (relu2(latent @ w1[e]) @ w2[e])
+                   for e in range(Eh))
+
+    routed = lambda *a: latent_moe.routed_experts(*a, rows, pair)
+    args = (latent, w_held, w1, w2)
+    got, want = routed(*args), plain(*args)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    every = (0, 1, 2, 3)
+    g_got = jax.grad(lambda *a: jnp.sum(routed(*a) * g), every)(*args)
+    g_want = jax.grad(lambda *a: jnp.sum(plain(*a) * g), every)(*args)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())))
+    tiles = int(latent_moe.buffer_layout(pair, w_held, rows)["tiles_used"])
+    assert tiles == sum(-(-l // tile) for l in loads) <= rows // tile
+    if not any(loads):
+        assert tiles == 0 and not got.any()
+        assert not any(a.any() for a in g_got)
+
+
+def test_a_row_without_a_pair_reads_nothing_of_its_token(monkeypatch):
+    """A tile's rows beyond its expert's load hold some token's index; what
+    that token's row holds (here: not finite) reaches neither the output
+    nor a gradient."""
+    from unicore_tpu.modules import latent_moe
+
+    monkeypatch.setattr(latent_moe, "TILE", 8)
+    n, lat, f = 16, 8, 12
+    ks = jax.random.split(jax.random.key(6), 3)
+    pair = jnp.zeros((n, 2), bool).at[:3, 0].set(True)
+    latent = jax.random.normal(ks[0], (n, lat)).at[3:].set(jnp.inf)
+    w1 = jax.random.normal(ks[1], (2, lat, f))
+    w2 = jax.random.normal(ks[2], (2, f, lat))
+    w_held = jnp.where(pair, 0.5, 0.0)
+    rows = latent_moe.buffer_rows(n, 2, 2)
+    total = lambda *a: jnp.sum(latent_moe.routed_experts(*a, rows, pair))
+    out, grads = jax.value_and_grad(total, (0, 1, 2, 3))(latent, w_held, w1, w2)
+    assert np.isfinite(out) and all(np.isfinite(a).all() for a in grads)
+    assert not grads[0][3:].any() and grads[0][:3].any()
 
 
 # -- the loss and the data ----------------------------------------------------------
@@ -321,14 +402,17 @@ def test_tokenizer_cuts_a_document_only_when_asked(tmp_path):
 
 def test_the_loss_states_what_a_capture_is_told_of_an_update():
     """``trace_marks``: from one update's summed logging output, a
-    ``moe_route`` mark with the pairs of all expert layers and the loads per
-    layer; nothing for a model without routed experts."""
+    ``moe_route`` mark with the pairs of all expert layers, the tiles of
+    rows they filled and the loads per layer; nothing for a model without
+    routed experts."""
     from unicore_tpu.losses.lm_cross_entropy import LMCrossEntropyLoss
 
     sums = {"loss": 9.0, "_n": 1.0, "moe_layers": 5.0, "moe_pairs_here": 2422.0,
-            "moe_load_max": 1702.0, "moe_load_mean": 302.75}
+            "moe_load_max": 1702.0, "moe_load_mean": 302.75,
+            "moe_tiles_used": 41.0}
     assert LMCrossEntropyLoss.trace_marks(sums) == {"moe_route": {
-        "pairs_here": 2422, "load_max": 340.4, "load_mean": 60.55}}
+        "pairs_here": 2422, "tiles_used": 41, "load_max": 340.4,
+        "load_mean": 60.55}}
     assert LMCrossEntropyLoss.trace_marks({"loss": 9.0, "_n": 1.0}) == {}
 
 
@@ -348,16 +432,14 @@ def test_the_buffer_is_the_worst_case_from_shapes(n, top_k, held, want):
 
 # -- the whole model through the trainer ----------------------------------------------
 
-def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
+def tiny_task(tmp_path, held):
     """``--task causal_lm --arch nemotron_h_tiny --loss lm_cross_entropy``
-    on packed text, as ``unicore-tpu-train`` builds them (its parser, the
-    task's own pipeline, ``Trainer.train_step``): a falling loss, every
-    block full, the routing stats in the step's sums."""
+    on a small corpus, as ``unicore-tpu-train`` builds them (its parser,
+    the task's own pipeline): ``(args, task, model, loss)``."""
     from unicore_tpu import options, tasks
     from unicore_tpu.data.indexed_dataset import make_builder
     from unicore_tpu.losses import LOSS_REGISTRY
     from unicore_tpu.models import build_model
-    from unicore_tpu.trainer import Trainer
 
     words = [f"w{a}{b}" for a in "abcdefgh" for b in "abcdefgh"]
     (tmp_path / "dict.txt").write_text(
@@ -373,14 +455,22 @@ def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
     args = options.parse_args_and_arch(parser, [
         str(tmp_path), "--task", "causal_lm", "--loss", "lm_cross_entropy",
         "--arch", "nemotron_h_tiny", "--tokens-per-sample", "64",
-        "--n-routed-experts-held", "8",
+        "--n-routed-experts-held", str(held),
         "--optimizer", "adam", "--lr-scheduler", "fixed", "--lr", "3e-3",
         "--batch-size", "1", "--max-update", "20", "--seed", "1",
     ])
     task = tasks.setup_task(args)
     task.load_dataset("train")
-    model = build_model(args, task)
-    trainer = Trainer(args, task, model, LOSS_REGISTRY[args.loss](task))
+    return args, task, build_model(args, task), LOSS_REGISTRY[args.loss](task)
+
+
+def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
+    """The tiny model through ``Trainer.train_step``: a falling loss, every
+    block full, the routing stats in the step's sums."""
+    from unicore_tpu.trainer import Trainer
+
+    args, task, model, loss = tiny_task(tmp_path, held=8)
+    trainer = Trainer(args, task, model, loss)
     batches = task.get_batch_iterator(
         task.datasets["train"], batch_size=8, seed=1, epoch=1,
     ).next_epoch_itr(shuffle=True)
@@ -394,6 +484,7 @@ def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
     assert per_update[-1] < per_update[0]
     assert sums[-1]["moe_layers"] == 6 * 2
     assert sums[-1]["moe_pairs_here"] > 0
+    assert sums[-1]["moe_tiles_used"] >= sums[-1]["moe_pairs_here"] / 128
 
     # inside a profiler capture the loss's marks reach the trace: one
     # ``unicore:moe_route`` per update, three updates late, from that
@@ -415,7 +506,38 @@ def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
              for s in spans if s[2] == "unicore:moe_route"]
     assert [m["update"] for m in marks] == [7, 8]
     for m in marks:  # 8 x 64 tokens, 2 expert layers, 8 of 16 experts held
-        assert set(m) == {"update", "pairs_here", "load_max", "load_mean"}
+        assert set(m) == {"update", "pairs_here", "tiles_used", "load_max",
+                          "load_mean"}
         assert 0 < m["pairs_here"] <= 2 * 8 * 64 * 4
+        # a tile holds 128 rows of one expert: 16 (layer, expert) columns
+        assert m["pairs_here"] / 128 <= m["tiles_used"] < m["pairs_here"] / 128 + 16
         assert m["load_mean"] == pytest.approx(m["pairs_here"] / 2 / 8)
         assert m["load_mean"] <= m["load_max"] <= 8 * 64
+
+
+def test_the_step_holds_no_worst_case_buffer_of_rows(tmp_path):
+    """Loss and gradient of the tiny model, compiled: no array in the
+    program has ``rows x latent_dim`` elements (tokens laid out in the
+    worst case's rows) or ``n x n_held x latent_dim`` (every token's row
+    from every held expert).  Dispatch and combine move tiles."""
+    from unicore_tpu.modules.latent_moe import TILE, buffer_rows
+
+    _, task, model, loss = tiny_task(tmp_path, held=5)
+    batch = next(task.get_batch_iterator(
+        task.datasets["train"], batch_size=3, seed=1, epoch=1,
+    ).next_epoch_itr(shuffle=False))
+    n, held, lat = 3 * 64, 5, model.moe_latent_size
+    assert batch["net_input"]["src_tokens"].shape == (3, 64)
+    params = model.init_params(jax.random.key(0), batch)
+    step = jax.jit(jax.value_and_grad(
+        lambda p: loss.forward(model, p, batch)[0]))
+    text = step.lower(params).compile().as_text()
+    sizes = {
+        int(np.prod([int(d) for d in dims.split(",") if d]))
+        for dims in re.findall(r"\b(?:pred|[fsu]\d+|bf16)\[([\d,]*)\]", text)
+    }
+    rows = buffer_rows(n, model.num_experts_per_tok, held)
+    # what the search would find: the tokens' latent rows, the layout's
+    # index arrays and a tile of rows are in the program
+    assert {n * lat, rows, TILE * lat, n * held} <= sizes
+    assert rows * lat not in sizes and n * held * lat not in sizes

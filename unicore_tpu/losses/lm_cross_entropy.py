@@ -122,8 +122,9 @@ class LMCrossEntropyLoss(UnicoreLoss):
         layers = sum(log.get("moe_layers", 0) for log in logging_outputs)
         if layers > 0:
             # an expert layer's routing, per layer and update
-            # (modules/latent_moe.py): how uneven the held experts' loads are
-            for key in ("moe_load_max", "moe_load_mean"):
+            # (modules/latent_moe.py): how uneven the held experts' loads
+            # are, and how many tiles of rows they fill
+            for key in ("moe_load_max", "moe_load_mean", "moe_tiles_used"):
                 total = sum(log.get(key, 0) for log in logging_outputs)
                 metrics.log_scalar(key, total / layers, 1, round=2)
 
@@ -132,13 +133,16 @@ class LMCrossEntropyLoss(UnicoreLoss):
         """What a profiler capture is told of one update, from that
         update's summed logging output (``Trainer._mark_update``): for a
         model with routed experts one ``unicore:moe_route`` mark with the
-        (token, held expert) pairs of all its expert layers and the most
-        loaded held expert's and the mean load, per layer."""
+        (token, held expert) pairs of all its expert layers, the tiles of
+        ``latent_moe.TILE`` rows they filled (what dispatch and combine
+        moved, each way) and the most loaded held expert's and the mean
+        load, per layer."""
         layers = sums.get("moe_layers", 0)
         if not layers:
             return {}
         return {"moe_route": dict(
             pairs_here=int(sums["moe_pairs_here"]),
+            tiles_used=int(sums["moe_tiles_used"]),
             load_max=sums["moe_load_max"] / layers,
             load_mean=sums["moe_load_mean"] / layers,
         )}

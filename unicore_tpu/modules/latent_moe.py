@@ -20,21 +20,23 @@ shares and adding the shared expert once gives the uncut layer
 chips; the exchange across chips is not built (ROADMAP R8).
 
 No capacity factor and no dropped pair.  The (token, held expert) pairs
-are laid out expert by expert in one buffer of rows shared by the held
-experts, each expert's rows rounded up to whole tiles of ``TILE``; the
-experts' products run tile by tile over the tiles IN USE only (a loop
-whose trip count is the routing's, :func:`_grouped_ffn`), so the work
-follows the pairs there are, however unevenly they fall.  The buffer is
-sized from the shapes for the worst case (:func:`buffer_rows`: every token
-on every held expert it can choose), which no routing can exceed, so the
-layer is dropless by construction and has no bound to set; the size costs
-memory and two gathers of that many rows, not products.
+are laid out expert by expert in rows shared by the held experts, each
+expert's rows rounded up to whole tiles of ``TILE``
+(:func:`buffer_layout`).  One loop walks the tiles IN USE only, with a
+trip count that is the routing's (:func:`_grouped_ffn`): a trip gathers
+its tile's ``TILE`` rows of the latent tokens, runs them through the
+tile's expert and adds the weighted result at their tokens; the backward
+(:func:`_grouped_ffn_bwd`, through :func:`routed_experts`, a
+``custom_vjp``) walks the same tiles with the cotangents.  So the products
+AND the rows moved follow the pairs there are, however unevenly they fall.
 
-Dispatch and combine are gathers in both directions
-(:func:`routed_experts`, a ``custom_vjp``): row ``r`` holds pair ``(n, e)``
-and pair ``(n, e)`` knows its row, so the forward gathers tokens into rows
-and rows back into tokens, and the backward does the same with the
-cotangents; nothing scatters, and no (rows x tokens) one-hot matrix exists.
+What is sized by the worst case (:func:`buffer_rows`: every token on every
+held expert it can choose, which no routing can exceed) is the layout's
+index arrays alone: a token, a weight and a flag per row.  That is why the
+layer is dropless by construction and has no bound to set.  No array of
+that many rows of activations, and none of (tokens x held experts x
+latent), is built, forward or backward (``tests/test_hybrid_lm.py``
+searches the compiled step for one).
 """
 
 import functools
@@ -48,7 +50,7 @@ from unicore_tpu.quant.dense import QuantDense
 _init = nn.initializers.normal(0.02)
 
 #: what :meth:`LatentMoE.__call__` returns beside ``y``, in this order
-STATS = ("pairs_here", "load_max", "load_mean", "layers")
+STATS = ("pairs_here", "load_max", "load_mean", "layers", "tiles_used")
 
 
 #: rows per tile of the grouped products (the MXU's 128 rows)
@@ -57,6 +59,11 @@ TILE = 128
 
 def relu2(x):
     return jnp.square(jax.nn.relu(x))
+
+
+def tiles_of(load):
+    """Whole tiles that ``load`` pairs of one expert fill."""
+    return (load + TILE - 1) // TILE
 
 
 def buffer_rows(n, top_k, n_held):
@@ -69,30 +76,27 @@ def buffer_rows(n, top_k, n_held):
 
 def buffer_layout(pair, w_held, rows):
     """Where each pair sits.  ``pair`` (n, Eh) bool; ``w_held`` (n, Eh) the
-    pairs' weights; ``rows`` the buffer's size (:func:`buffer_rows`).
-    Expert ``e``'s pairs take rows ``start_e .. start_e + load_e - 1`` in
-    token order, ``start_e`` the tile-aligned end of expert ``e - 1``'s.
-    Returns
+    pairs' weights; ``rows`` the layout's length (:func:`buffer_rows`, the
+    worst case: these index arrays are all that is sized by it).  Expert
+    ``e``'s pairs take rows ``start_e .. start_e + load_e - 1`` in token
+    order, ``start_e`` the tile-aligned end of expert ``e - 1``'s.  Returns
 
-    * ``row_of_pair`` (n, Eh): the pair's row; ``rows`` (one past the end,
-      a row of zeros) for a token that did not choose the expert;
-    * ``token_of_row`` (rows,), ``weight_of_row`` (rows,), ``valid`` (rows,);
-    * ``tile_expert`` (rows / TILE,), ``tiles_used`` (scalar).
+    * ``token_of_row`` (rows,), ``weight_of_row`` (rows,), ``valid`` (rows,):
+      a row of a tile in use that holds no pair has ``valid`` false, weight
+      zero and some token's index that is in bounds;
+    * ``tile_expert`` (rows / TILE,), ``tiles_used`` (scalar): the rows the
+      loops read are those of the first ``tiles_used`` tiles.
 
     No gather and no search: each expert's tokens come out of one stable
     sort of its column (chosen tokens first, in token order, their weights
     carried along) and are written at the expert's start; what a column
     holds beyond its load is overwritten by the next expert's or cut off
-    (the arrays are ``n`` rows longer than the buffer while they are
+    (the arrays are ``n`` rows longer than ``rows`` while they are
     written, so the last expert's column always fits)."""
     n, Eh = pair.shape
-    count = jnp.cumsum(pair.astype(jnp.int32), axis=0)       # (n, Eh)
-    load = count[-1]                                         # (Eh,)
-    tiles = (load + TILE - 1) // TILE
+    tiles = tiles_of(pair.sum(axis=0, dtype=jnp.int32))
     ends = jnp.cumsum(tiles)                                 # in tiles
     start = (ends - tiles) * TILE                            # in rows
-    row = start[None, :] + count - 1
-    row_of_pair = jnp.where(pair, row, rows)
     n_tiles = rows // TILE
     tile_expert = jnp.minimum(
         jnp.sum(jnp.arange(n_tiles)[:, None] >= ends[None, :], axis=1), Eh - 1
@@ -114,70 +118,82 @@ def buffer_layout(pair, w_held, rows):
             weight_of_row, weights[:, e], at)
         valid = jax.lax.dynamic_update_slice(valid, unchosen[:, e] == 0, at)
     return dict(
-        row_of_pair=row_of_pair, token_of_row=token_of_row[:rows],
-        weight_of_row=weight_of_row[:rows], valid=valid[:rows],
-        tile_expert=tile_expert,
+        token_of_row=token_of_row[:rows], weight_of_row=weight_of_row[:rows],
+        valid=valid[:rows], tile_expert=tile_expert,
         tiles_used=ends[-1].astype(jnp.int32),
     )
 
 
-def _grouped_ffn(x_rows, w1, w2, tile_expert, tiles_used):
-    """``relu2(x W1_e) W2_e`` for each tile of ``TILE`` rows with its
-    expert's weights, over the first ``tiles_used`` tiles; the rest stay
-    zero.  A loop with a trip count from the data: only forward (the
+def _tile_of(lay, t):
+    """Tile ``t``'s ``TILE`` entries of the layout's row arrays."""
+    cut = lambda a: jax.lax.dynamic_slice(a, (t * TILE,), (TILE,))
+    return (cut(lay["token_of_row"]), cut(lay["weight_of_row"]),
+            cut(lay["valid"]))
+
+
+def _tile_rows(table, token, valid):
+    """``table``'s rows at a tile's tokens; zeros where the row holds no
+    pair, whatever the token's row holds (never a ``0 x inf``)."""
+    return jnp.where(valid[:, None], table[token], 0)
+
+
+def _grouped_ffn(latent, w1, w2, lay):
+    """``sum_e weight * relu2(latent W1_e) W2_e`` over the pairs of the
+    first ``tiles_used`` tiles of ``lay`` (:func:`buffer_layout`), (n, lat)
+    float32.  Each trip gathers its tile's ``TILE`` rows of ``latent``,
+    runs them through its expert and adds the weighted result at their
+    tokens.  A loop with a trip count from the data: only forward (the
     backward is :func:`_grouped_ffn_bwd`)."""
-    lat = x_rows.shape[1]
+    f32 = jnp.float32
 
-    def body(t, y):
-        e = tile_expert[t]
-        x_t = jax.lax.dynamic_slice(x_rows, (t * TILE, 0), (TILE, lat))
-        h = relu2(jnp.dot(x_t, w1[e], preferred_element_type=jnp.float32))
-        y_t = jnp.dot(h.astype(x_rows.dtype), w2[e],
-                      preferred_element_type=jnp.float32)
-        return jax.lax.dynamic_update_slice(
-            y, y_t.astype(y.dtype), (t * TILE, 0)
-        )
+    def body(t, out):
+        e = lay["tile_expert"][t]
+        token, weight, valid = _tile_of(lay, t)
+        x_t = _tile_rows(latent, token, valid)
+        h = relu2(jnp.dot(x_t, w1[e], preferred_element_type=f32))
+        y_t = jnp.dot(h.astype(latent.dtype), w2[e],
+                      preferred_element_type=f32)
+        # a row without a pair adds an exact zero (x_t and its weight are)
+        return out.at[token].add(weight[:, None] * y_t)
 
-    return jax.lax.fori_loop(0, tiles_used, body, jnp.zeros_like(x_rows))
+    return jax.lax.fori_loop(
+        0, lay["tiles_used"], body, jnp.zeros(latent.shape, f32)
+    )
 
 
-def _grouped_ffn_bwd(x_rows, dy_rows, w1, w2, tile_expert, tiles_used):
-    """Cotangents of :func:`_grouped_ffn`: the hidden states are computed
-    again tile by tile; the weights' cotangents accumulate in float32."""
-    lat = x_rows.shape[1]
-    dtype = x_rows.dtype
+def _grouped_ffn_bwd(latent, d_out, w1, w2, lay):
+    """Cotangents of :func:`_grouped_ffn` for ``d_out`` (n, lat) float32:
+    ``d_latent`` (n, lat), the pairs' weights' (Eh, n), ``dw1``, ``dw2``,
+    all float32.  The hidden states are computed again tile by tile."""
+    dtype = latent.dtype
     f32 = jnp.float32
 
     def body(t, carry):
-        dx, dw1, dw2 = carry
-        e = tile_expert[t]
-        x_t = jax.lax.dynamic_slice(x_rows, (t * TILE, 0), (TILE, lat))
-        dy_t = jax.lax.dynamic_slice(dy_rows, (t * TILE, 0), (TILE, lat))
+        dx, dweight, dw1, dw2 = carry
+        e = lay["tile_expert"][t]
+        token, weight, valid = _tile_of(lay, t)
+        x_t = _tile_rows(latent, token, valid)
+        d_t = _tile_rows(d_out, token, valid)
         r = jax.nn.relu(jnp.dot(x_t, w1[e], preferred_element_type=f32))
         h = jnp.square(r).astype(dtype)
+        y_t = jnp.dot(h, w2[e], preferred_element_type=f32)
+        dy_t = (d_t * weight[:, None]).astype(dtype)
         dh = jnp.dot(dy_t, w2[e].T, preferred_element_type=f32)
         dpre = (dh * 2.0 * r).astype(dtype)
         dx_t = jnp.dot(dpre, w1[e].T, preferred_element_type=f32)
         dw1 = dw1.at[e].add(jnp.dot(x_t.T, dpre, preferred_element_type=f32))
         dw2 = dw2.at[e].add(jnp.dot(h.T, dy_t, preferred_element_type=f32))
-        dx = jax.lax.dynamic_update_slice(
-            dx, dx_t.astype(dtype), (t * TILE, 0)
-        )
-        return dx, dw1, dw2
+        # a row without a pair adds exact zeros: x_t and d_t are, so
+        # dx_t, y_t and the weight's cotangent are
+        dweight = dweight.at[e, token].add(jnp.sum(y_t * d_t, axis=-1))
+        return dx.at[token].add(dx_t), dweight, dw1, dw2
 
     return jax.lax.fori_loop(
-        0, tiles_used, body,
-        (jnp.zeros_like(x_rows), jnp.zeros(w1.shape, f32),
-         jnp.zeros(w2.shape, f32)),
+        0, lay["tiles_used"], body,
+        (jnp.zeros(latent.shape, f32),
+         jnp.zeros((w1.shape[0], latent.shape[0]), f32),
+         jnp.zeros(w1.shape, f32), jnp.zeros(w2.shape, f32)),
     )
-
-
-def _gather_rows(table, index):
-    """``table`` (m, c) read at ``index``; an index of ``m`` (one past the
-    end) reads zeros."""
-    m = table.shape[0]
-    got = table[jnp.minimum(index, m - 1)]
-    return jnp.where((index < m)[..., None], got, 0).astype(table.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -185,37 +201,21 @@ def routed_experts(latent, w_held, w1, w2, rows, pair):
     """``sum_e w_held[n, e] * W2_e relu2(W1_e latent[n])`` over the pairs
     ``pair`` marks.  ``latent`` (n, lat); ``w_held`` (n, Eh) float32, zero
     off the pairs; ``w1`` (Eh, lat, f), ``w2`` (Eh, f, lat); ``rows``
-    static; ``pair`` (n, Eh) bool, not differentiated.  Returns (n, lat)
-    float32."""
+    static, the layout's length (:func:`buffer_rows`); ``pair`` (n, Eh)
+    bool, not differentiated.  Returns (n, lat) float32."""
     return _routed_fwd(latent, w_held, w1, w2, rows, pair)[0]
 
 
 def _routed_fwd(latent, w_held, w1, w2, rows, pair):
     lay = buffer_layout(pair, w_held, rows)
-    x_rows = jnp.where(
-        lay["valid"][:, None], latent[lay["token_of_row"]], 0
-    ).astype(latent.dtype)
-    y_rows = _grouped_ffn(x_rows, w1, w2, lay["tile_expert"], lay["tiles_used"])
-    back = _gather_rows(y_rows, lay["row_of_pair"])          # (n, Eh, lat)
-    out = jnp.einsum("nel,ne->nl", back.astype(jnp.float32), w_held)
-    return out, (x_rows, back, w1, w2, lay)
+    return _grouped_ffn(latent, w1, w2, lay), (latent, w1, w2, lay)
 
 
 def _routed_bwd(rows, residuals, d_out):
-    x_rows, back, w1, w2, lay = residuals
-    f32 = jnp.float32
-    d_out = d_out.astype(f32)
-    d_w_held = jnp.einsum("nel,nl->ne", back.astype(f32), d_out)
-    # a row that holds no pair holds a token that did not choose the
-    # expert, whose weight is zero: no mask needed
-    dy_rows = (
-        d_out[lay["token_of_row"]] * lay["weight_of_row"][:, None]
-    ).astype(x_rows.dtype)
-    dx_rows, dw1, dw2 = _grouped_ffn_bwd(
-        x_rows, dy_rows, w1, w2, lay["tile_expert"], lay["tiles_used"]
-    )
-    d_latent = _gather_rows(dx_rows, lay["row_of_pair"]).astype(f32).sum(axis=1)
-    return (d_latent.astype(x_rows.dtype), d_w_held, dw1.astype(w1.dtype),
+    latent, w1, w2, lay = residuals
+    d_out = d_out.astype(jnp.float32)
+    d_latent, dweight, dw1, dw2 = _grouped_ffn_bwd(latent, d_out, w1, w2, lay)
+    return (d_latent.astype(latent.dtype), dweight.T, dw1.astype(w1.dtype),
             dw2.astype(w2.dtype), None)
 
 
@@ -294,6 +294,7 @@ class LatentMoE(nn.Module):
             routed = routed_experts(latent, w_held, w1, w2, rows, pair)
             stats = jnp.stack([
                 load.sum(), load.max(), load.astype(f32).mean(), 1,
+                tiles_of(load).sum(),
             ]).astype(f32)
             routed = routed.astype(dtype)
 
