@@ -353,6 +353,24 @@ def test_audit_proves_fused_adam_shrinks_program():
     assert counts[True]["instructions"] < counts[False]["instructions"]
 
 
+def test_audit_reads_the_program_the_update_ran():
+    """The audit's arguments come from the method that dispatches the
+    update: after a flush (no running sums on the host) it still audits
+    the one train-step program, not a second one that starts from None."""
+    tr = _tiny_trainer()
+    tr.train_step([_batch(1)])
+    sample, w = tr._prepare_sample_or_dummy(_batch(1))
+    with_sums = tr.fusion_audit(sample, w)
+    tr.flush_metrics()
+    assert tr._macc is None
+    after_flush = tr.fusion_audit(sample, w)
+    assert after_flush["program"] == with_sums["program"] == "train_step"
+    for key in ("kernels", "fusions", "instructions"):
+        assert after_flush[key] == with_sums[key]
+    tr.train_step([_batch(2)])
+    assert tr._compiled_programs() == {"train_step": 1}
+
+
 # ---------------------------------------------------------------------------
 # CLI e2e (the CI "Kernel parity smoke" greps this test's -s output)
 # ---------------------------------------------------------------------------

@@ -1,10 +1,7 @@
-"""The one TPU predicate, the compile-cache resolver, and bench.py's
-no-fallback contract (a measurement path that finds no chip fails)."""
+"""The one TPU predicate and the compile-cache resolver."""
 
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import pytest
@@ -92,73 +89,48 @@ def test_default_cache_dir_is_gitignored():
 
 
 # ---------------------------------------------------------------------------
-# bench.py: no chip -> non-zero at once; one failed config -> non-zero
+# one benchmark: what the documents send a reader to exists
 # ---------------------------------------------------------------------------
 
-def test_bench_exits_nonzero_at_once_without_a_tpu():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=120, cwd=REPO, env=env,
-    )
-    assert proc.returncode == 3, proc.stderr[-2000:]
-    assert "no TPU" in proc.stderr
-    assert proc.stdout.strip() == ""  # no row: a CPU is never reported
+def _documents():
+    """The repository's own prose and scripts, history apart (the records
+    of past PRs name what those PRs had)."""
+    history = {"CHANGES.md", "PERF.md", "ROADMAP.md", "ISSUE.md", "REVIEW.md"}
+    for top in ("", "docs", "scripts", "examples", "unicore_tpu",
+                "unicore_tpu_cli", "benchmark", os.path.join(".claude", "skills")):
+        for dirpath, dirs, files in os.walk(os.path.join(REPO, top)):
+            if not top:
+                dirs[:] = []
+            for f in files:
+                if f.endswith((".md", ".py", ".sh")) and f not in history:
+                    yield os.path.join(dirpath, f)
 
 
-@pytest.fixture
-def bench(monkeypatch):
-    monkeypatch.syspath_prepend(REPO)
-    import bench as bench_mod
-
-    monkeypatch.setattr(bench_mod, "_require_tpu", lambda: None)
-    monkeypatch.setattr(
-        platform_utils, "configure_compilation_cache", lambda *a: None
-    )
-    return bench_mod
-
-
-def test_bench_one_failed_config_fails_the_run(bench, monkeypatch, capsys):
-    def boom():
-        raise RuntimeError("kernel refused")
-
-    monkeypatch.setenv("BENCH_CONFIG", "all")
-    monkeypatch.setattr(bench, "run_config", lambda c: {"metric": c})
-    monkeypatch.setitem(bench._RUNNERS, "serve", lambda: {"metric": "serve"})
-    monkeypatch.setitem(bench._RUNNERS, "kernels", boom)
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 4
-    out, err = capsys.readouterr()
-    # the configs that worked still printed their rows
-    assert [json.loads(line)["metric"] for line in out.splitlines()] == [
-        "bert", "unimol", "evoformer", "moe", "serve",
-    ]
-    assert "config kernels failed" in err and "['kernels']" in err
+def test_no_document_points_at_the_deleted_benchmark():
+    # spelled in pieces, so that this file is no hit of its own search
+    gone = ("bench" + ".py", "BENCH_" + "PARTIAL", "MULTICHIP_" + "r0",
+            "BENCH_" + "CONFIG", "bench_" + "attention", "copy" + "sweep",
+            "bench_input_" + "pipeline")
+    hits = []
+    for path in _documents():
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        hits += [(os.path.relpath(path, REPO), g) for g in gone if g in text]
+    assert not hits, hits
 
 
-def test_bench_all_configs_passing_exits_zero(bench, monkeypatch):
-    monkeypatch.setenv("BENCH_CONFIG", "bert")
-    monkeypatch.setattr(bench, "run_config", lambda c: {"metric": c})
-    bench.main()  # no SystemExit
+def test_documented_benchmark_commands_name_a_cell():
+    """Every ``benchmark.run --workload <name>`` a document spells out is a
+    cell of the manifest (``<cell>`` stands for any of them)."""
+    import re
 
-
-@pytest.mark.parametrize("kind,peak", [
-    ("TPU v5 lite", 197e12), ("TPU v5", None), ("cpu", None), ("", None),
-])
-def test_bench_peak_flops_unknown_kind_is_an_error(bench, kind, peak):
-    if peak is None:
-        with pytest.raises(ValueError, match="not in the peaks table"):
-            bench._peak_flops(kind)
-    else:
-        assert bench._peak_flops(kind) == peak
-
-
-def test_bench_device_kind_lookup_failure_raises(bench, monkeypatch):
-    def no_devices():
-        raise RuntimeError("no backend")
-
-    monkeypatch.setattr(jax, "devices", no_devices)
-    with pytest.raises(RuntimeError, match="no backend"):
-        bench._device_kind()
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        cells = {w["name"] for w in json.load(f)["workloads"]}
+    named = set()
+    for path in _documents():
+        with open(path, encoding="utf-8") as f:
+            named |= set(re.findall(
+                r"benchmark\.run\s+--workload\s+([\w.]+)", f.read()
+            ))
+    assert "bert_base.train_mlm512" in named  # the README's
+    assert named <= cells, named - cells

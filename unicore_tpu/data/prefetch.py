@@ -75,7 +75,7 @@ class PreparedUpdate:
 
     ``data`` depends on ``kind``: the prepared global batch (``single``),
     the stacked micro-batch tree for the fused scan (``scan``), or the
-    list of per-slot prepared batches (``micro``)."""
+    list of per-slot ``(prepared batch, weight)`` pairs (``micro``)."""
 
     kind: str
     data: Any

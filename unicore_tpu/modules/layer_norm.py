@@ -7,8 +7,8 @@ BOTH paths exist and ONE documented flag picks between them
 :func:`configure_fused_norm`):
 
 - ``auto`` (default): the jnp composition — XLA fuses the norm into the
-  surrounding elementwise/matmul ops, which measures FASTER end-to-end than
-  the standalone Pallas kernel (BERT-base step: 195 vs 186 samples/s);
+  surrounding elementwise/matmul ops (against the standalone Pallas
+  kernel: not measured on a cell);
 - ``on``: the Pallas fused kernels (ops/fused_norm.py) — for parity
   benchmarking and for shapes where XLA's fusion falls over;
 - ``off``: jnp unconditionally.

@@ -257,7 +257,7 @@ class ParallelPlan:
         return f"ParallelPlan({body}{(' ' + ' '.join(extras)) if extras else ''})"
 
     def to_json(self) -> Dict:
-        """The journal/bench-facing form (telemetry kind ``comm-plan``)."""
+        """The journal-facing form (telemetry kind ``comm-plan``)."""
         return {
             "axes": {a: n for a, n in self.axis_sizes().items()},
             "tiers": self.tiers(),

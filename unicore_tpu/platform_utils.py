@@ -62,8 +62,7 @@ def force_host_cpu_from_env(default_devices: int = 8) -> bool:
     """Apply the standard CPU-platform override when the operator set
     ``UNICORE_TPU_PLATFORM=cpu`` (device count from
     ``UNICORE_TPU_CPU_DEVICES``, else ``default_devices``).  One shared
-    implementation for every entry point (CLIs, example scripts, bench
-    scripts) — must run BEFORE any jax backend use.  Returns True when the
+    implementation for every entry point (CLIs, example scripts) — must run BEFORE any jax backend use.  Returns True when the
     override engaged."""
     if os.environ.get("UNICORE_TPU_PLATFORM", "").lower() != "cpu":
         return False

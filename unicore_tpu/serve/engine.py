@@ -260,7 +260,7 @@ class ServeEngine:
                 "bucket_edges vs the transport's validation."
             )
 
-    # -- submission (transports + flood generator + bench) ---------------
+    # -- submission (transports + flood generator) ---------------
 
     def submit(self, tokens, deadline_s: float,
                request_id: Optional[str] = None) -> rq.ServeRequest:

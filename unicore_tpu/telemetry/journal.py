@@ -45,7 +45,7 @@ logger = logging.getLogger(__name__)
 #: run identity env contract: minted once at ``cli_main`` and inherited
 #: by elastic restart children (the supervisor passes its environment
 #: through), so every incarnation of one run shares the run_id and
-#: journals/checkpoints/bench rows stay joinable across restarts
+#: journals and checkpoints stay joinable across restarts
 ENV_RUN_ID = "UNICORE_TPU_RUN_ID"
 
 _JOURNAL_DIRNAME = "telemetry"

@@ -210,6 +210,8 @@ class Trainer(object):
         self._marks_pending = collections.deque()
         self._marks_seen = None
         self._vacc = None  # device-side eval sums (see finish_valid_accum)
+        # per program, the zeros its sums start from (see _sums_in)
+        self._zero_sums: Dict[str, Any] = {}
         self._num_updates = 0
         self._loss_fn = task.loss_fn(model, loss)
         self._jit_cache: Dict[str, Any] = {}
@@ -367,7 +369,7 @@ class Trainer(object):
             master = opt_state["master"] if opt_state["master"] is not None else params
             state["ema"] = init_ema(master)
         # the comm/topology story of this run, journaled once so traces
-        # and bench rows can join against the plan that produced them
+        # can join against the plan that produced them
         # (emitted here, not in __init__: the CLI configures telemetry
         # between Trainer construction and state init)
         telemetry.emit(
@@ -733,7 +735,7 @@ class Trainer(object):
             # log_interval (one fetch), so logging costs nothing per step
             upd = dict(step_metrics)
             upd["_n"] = jnp.ones((), jnp.float32)
-            if macc is None:
+            if macc is None:  # a caller outside the Trainer (_sums_in)
                 return upd
             return {k: macc.get(k, 0.0) + v for k, v in upd.items()}
 
@@ -753,71 +755,37 @@ class Trainer(object):
                 return new_state, accumulate(macc, step_metrics)
 
             fn = train_step
-        elif name == "scan_step":
+        elif name in ("scan_step", "scan_step_adama"):
+            # the two programs of a stacked grad-accumulation update differ
+            # in what the scan carries, how a micro-batch's gradient folds
+            # into it, and the apply path that takes it.  scan_step: fp32
+            # grads (SURVEY.md §7: 'micro-batch scan'), then the shared
+            # apply.  scan_step_adama (--grad-accum adama, arXiv
+            # 2305.19982): the Adam moment ACCUMULATORS — each micro-batch's
+            # gradient folds straight into them and is dead after its fold,
+            # so no full fp32 gradient pytree ever lives across the scan;
+            # under --zero-stage >= 1 they inherit the optimizer slots'
+            # per-leaf dp sharding (the stage-2/3 flat reduce-scatter
+            # machinery applies to buffer mode only).
+            opt = self._optimizer
+            adama = name == "scan_step_adama"
+            if adama:
+                fold, apply = opt.accum_fold, self._apply_update_adama
+            else:
+                fold = partial(jax.tree_util.tree_map, jnp.add)
+                apply = self._apply_update
 
-            @partial(jax.jit, donate_argnums=(0,) if donate else ())
             def scan_step(state, stacked, scalars, macc):
                 """Whole grad-accumulation update in ONE program: micro-
-                batches stacked on a leading axis, lax.scan accumulates fp32
-                grads (SURVEY.md §7: 'micro-batch scan'); then the shared
-                apply path."""
-
-                def body(carry, xs):
-                    acc_grads, acc_ss, acc_log = carry
-                    sample_k, micro_i = xs
-                    rng = make_rng(scalars, micro_i)
-                    grads, ss, log = self._forward_backward(
-                        state["params"], sample_k, rng, state["loss_scale"],
-                        scalars["weight"],
-                    )
-                    acc_grads = jax.tree_util.tree_map(jnp.add, acc_grads, grads)
-                    new_log = {k: acc_log[k] + log[k] for k in acc_log}
-                    return (acc_grads, acc_ss + ss, new_log), None
-
-                zero_grads = jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), state["params"]
-                )
-                with num_updates_context(scalars["step"]):
-                    # trace one body call to learn the logging keys
-                    probe_rng = make_rng(scalars, 0)
-                    _, _, probe_log = jax.eval_shape(
-                        lambda p, s: self._forward_backward(
-                            p, s, probe_rng, state["loss_scale"],
-                            scalars["weight"]
-                        ),
+                batches stacked on a leading axis, lax.scan folds each one's
+                gradient into the carry, then the apply path."""
+                if adama:
+                    acc0 = opt.accum_init(state["opt"]["slots"])
+                else:
+                    acc0 = jax.tree_util.tree_map(
+                        lambda p: jnp.zeros(p.shape, jnp.float32),
                         state["params"],
-                        jax.tree_util.tree_map(lambda x: x[0], stacked),
                     )
-                    zero_log = {
-                        k: jnp.zeros(v.shape, jnp.float32)
-                        for k, v in probe_log.items()
-                    }
-                    n_micro = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-                    (grads, ss, log), _ = jax.lax.scan(
-                        body,
-                        (zero_grads, jnp.zeros((), jnp.float32), zero_log),
-                        (stacked, jnp.arange(n_micro, dtype=jnp.int32)),
-                    )
-                rng = make_rng(scalars, 0)
-                new_state, step_metrics = self._apply_update(
-                    state, grads, ss, log, scalars, rng
-                )
-                return new_state, accumulate(macc, step_metrics)
-
-            fn = scan_step
-        elif name == "scan_step_adama":
-
-            @partial(jax.jit, donate_argnums=(0,) if donate else ())
-            def scan_step_adama(state, stacked, scalars, macc):
-                """--grad-accum adama (arXiv 2305.19982): the scan carries
-                the Adam moment ACCUMULATORS — each micro-batch's gradient
-                folds straight into them and is dead after its fold, so no
-                full fp32 gradient pytree ever lives across the scan.
-                Under --zero-stage >= 1 the accumulators inherit the
-                optimizer slots' per-leaf dp sharding (the stage-2/3 flat
-                reduce-scatter machinery applies to buffer mode only)."""
-                opt = self._optimizer
-                acc0 = opt.accum_init(state["opt"]["slots"])
 
                 def body(carry, xs):
                     acc, acc_ss, acc_log = carry
@@ -827,11 +795,12 @@ class Trainer(object):
                         state["params"], sample_k, rng, state["loss_scale"],
                         scalars["weight"],
                     )
-                    acc = opt.accum_fold(acc, grads)
+                    acc = fold(acc, grads)
                     new_log = {k: acc_log[k] + log[k] for k in acc_log}
                     return (acc, acc_ss + ss, new_log), None
 
                 with num_updates_context(scalars["step"]):
+                    # trace one body call to learn the logging keys
                     probe_rng = make_rng(scalars, 0)
                     _, _, probe_log = jax.eval_shape(
                         lambda p, s: self._forward_backward(
@@ -852,12 +821,15 @@ class Trainer(object):
                         (stacked, jnp.arange(n_micro, dtype=jnp.int32)),
                     )
                 rng = make_rng(scalars, 0)
-                new_state, step_metrics = self._apply_update_adama(
+                new_state, step_metrics = apply(
                     state, acc, ss, log, scalars, rng
                 )
                 return new_state, accumulate(macc, step_metrics)
 
-            fn = scan_step_adama
+            # the compiled module is named after the function
+            # (jit_scan_step / jit_scan_step_adama)
+            scan_step.__name__ = scan_step.__qualname__ = name
+            fn = jax.jit(scan_step, donate_argnums=(0,) if donate else ())
         elif name == "micro_step":
 
             @partial(jax.jit, donate_argnums=(3,) if donate else ())
@@ -1077,8 +1049,9 @@ class Trainer(object):
                     # tests)
                     self._prepared_dispatch_thread = threading.get_ident()
                     try:
-                        new_state, self._macc = self._dispatch_prepared(
-                            state, prepared
+                        new_state = self._dispatch(
+                            state, prepared.kind, prepared.data,
+                            prepared.weight,
                         )
                     finally:
                         self._prepared_dispatch_thread = None
@@ -1091,11 +1064,8 @@ class Trainer(object):
                         sample, weight = self._prepare_sample_or_dummy(
                             samples[0], mode=mode
                         )
-                    new_state, self._macc = self._launch(
-                        "train_step", state, sample,
-                        self._step_scalars(0, weight), self._macc,
-                    )
-                    audit_args = ("single", (sample, weight))
+                    new_state = self._dispatch(state, "single", sample, weight)
+                    audit_args = ("single", sample, weight)
                     if not self._device_shares_logged:
                         self._log_device_shares(sample)
                 else:
@@ -1116,10 +1086,7 @@ class Trainer(object):
                         # all micro-batches share shapes: ONE compiled program
                         # scans the whole accumulation (no per-micro-batch
                         # dispatch)
-                        new_state, self._macc = self._launch(
-                            self._scan_jit_name(), state, stacked,
-                            self._step_scalars(0), self._macc,
-                        )
+                        new_state = self._dispatch(state, "scan", stacked)
                         audit_args = ("scan", stacked)
                     else:
                         if self.grad_accum_mode == "adama":
@@ -1134,22 +1101,20 @@ class Trainer(object):
                                 "micro-steps (bound the shape set with "
                                 "--length-bucket to keep adama engaged)",
                             )
-                        acc = None
-                        for i, s in enumerate(samples):
-                            with telemetry.spans.annotation(
-                                "prepare", update=update
-                            ):
-                                sample, weight = self._prepare_sample_or_dummy(
-                                    s, mode=modes[i] if modes else None
-                                )
-                            acc = self._launch(
-                                "micro_step", state["params"],
-                                state["loss_scale"], sample, acc,
-                                self._step_scalars(i, weight),
-                            )
-                        new_state, self._macc = self._launch(
-                            "apply_step", state, acc, self._step_scalars(0),
-                            self._macc,
+
+                        def prepared_slots():
+                            # pulled by _dispatch one at a time: slot i+1 is
+                            # prepared while the device runs slot i
+                            for i, s in enumerate(samples):
+                                with telemetry.spans.annotation(
+                                    "prepare", update=update
+                                ):
+                                    yield self._prepare_sample_or_dummy(
+                                        s, mode=modes[i] if modes else None
+                                    )
+
+                        new_state = self._dispatch(
+                            state, "micro", prepared_slots()
                         )
 
             finished_update = update
@@ -1183,11 +1148,7 @@ class Trainer(object):
         ):
             self._fusion_audit_done = True
             if audit_args is not None:
-                kind, payload = audit_args
-                if kind == "single":
-                    self.fusion_audit(*payload)
-                else:
-                    self.fusion_audit_scan(payload)
+                self._fusion_audit_update(*audit_args)
             else:
                 logger.warning(
                     "fusion-audit: only the synchronous train-step programs "
@@ -1226,30 +1187,63 @@ class Trainer(object):
         metrics.log_stop_time("train_wall")
         return True
 
-    def _dispatch_prepared(self, state, item):
-        """Dispatch one prefetched update: the arrays are already on device
-        in their final layout, so the only per-update work here is the
-        jitted call(s) themselves."""
-        if item.kind == "single":
-            return self._launch(
-                "train_step", state, item.data,
-                self._step_scalars(0, item.weight), self._macc,
-            )
-        if item.kind == "scan":
-            return self._launch(
-                self._scan_jit_name(), state, item.data,
-                self._step_scalars(0), self._macc,
-            )
-        assert item.kind == "micro", item.kind
-        acc = None
-        for i, sample in enumerate(item.data):
-            acc = self._launch(
-                "micro_step", state["params"], state["loss_scale"], sample,
-                acc, self._step_scalars(i, item.weight),
-            )
-        return self._launch(
-            "apply_step", state, acc, self._step_scalars(0), self._macc
+    def _update_program(self, state, kind, data, weight=1.0):
+        """The program that finishes an update of ``kind`` ("single": one
+        batch; "scan": equal-shaped micro-batches stacked on a leading
+        axis; "micro": the ``(gradients, sample size, logging sums)`` its
+        micro-steps added up) and its arguments before the running sums."""
+        if kind == "single":
+            return "train_step", (state, data, self._step_scalars(0, weight))
+        if kind == "scan":
+            return self._scan_jit_name(), (state, data, self._step_scalars(0))
+        assert kind == "micro", kind
+        return "apply_step", (state, data, self._step_scalars(0))
+
+    def _dispatch(self, state, kind, data, weight=1.0):
+        """Issue one update's launches — the one place that maps an
+        update's kind to its programs, for the synchronous path and the
+        prefetcher's prepared updates alike.  ``data`` is on the device in
+        its final layout: a batch ("single"), the stacked micro-batches
+        ("scan"), or ("micro") an iterable of ``(sample, weight)`` pairs,
+        pulled one at a time.  ``weight`` is the "single" batch's.  Adds
+        the update's sums to ``self._macc``; returns the new state."""
+        if kind == "micro":
+            acc = None
+            for i, (sample, w) in enumerate(data):
+                acc = self._launch(
+                    "micro_step", state["params"], state["loss_scale"],
+                    sample, acc, self._step_scalars(i, w),
+                )
+            data = acc
+        name, args = self._update_program(state, kind, data, weight)
+        new_state, self._macc = self._launch(
+            name, *args, self._sums_in(name, args, self._macc)
         )
+        return new_state
+
+    def _sums_in(self, name, args, sums):
+        """The running sums to hand program ``name``: ``sums``, or float32
+        zeros where nothing has accumulated yet (``None``: a new process, a
+        flush, a restored snapshot, a caller's ``_macc = None``).  The
+        program never sees the ``None``, so it compiles once per shape and
+        not a second time for its first call.  The zeros have the structure
+        of the program's own sums, learned once per program without
+        compiling it, and are placed as its outputs are (replicated over
+        the mesh), so they meet the same cache entry."""
+        if sums is not None:
+            return sums
+        zeros = self._zero_sums.get(name)
+        if zeros is None:
+            out = jax.eval_shape(self._get_jit(name), *args, None)
+            # (new state, sums), or valid_step's sums alone
+            struct = out[1] if isinstance(out, tuple) else out
+            zeros = self._zero_sums[name] = jax.device_put(  # lint: explicit-sync
+                jax.tree_util.tree_map(
+                    lambda x: np.zeros(x.shape, x.dtype), struct
+                ),
+                self._replicated,
+            )
+        return zeros
 
     def _undonated_scalars(self, state):
         """Under --donate-train-state (off unless asked for) a step program
@@ -1319,7 +1313,7 @@ class Trainer(object):
         multi-host; all non-empty on single-host) — everything else takes
         the RawUpdate fallback through the synchronous path.
 
-        Returns ``(kind, data, weight)`` for :meth:`_dispatch_prepared`.
+        Returns ``(kind, data, weight)`` for :meth:`_dispatch`.
         Dummy-batch caching stays off here (``cache_dummy=False``): the
         training thread caches it on the first (synchronous) update of the
         epoch, so WHICH batch becomes the dummy is host-deterministic."""
@@ -1335,9 +1329,12 @@ class Trainer(object):
         if stacked is not None:
             return "scan", stacked, 1.0
         slots = [
-            self._prepare_shard_global(s)
-            if modes is not None
-            else self._prepare_sample(s)
+            (
+                self._prepare_shard_global(s)
+                if modes is not None
+                else self._prepare_sample(s),
+                1.0,
+            )
             for s in samples
         ]
         return "micro", slots, 1.0
@@ -1400,33 +1397,28 @@ class Trainer(object):
         ``fusion-audit`` telemetry event.  Returns the report dict (None
         when the program/HLO is unavailable — auditing never raises into
         the training loop)."""
-        return self._fusion_audit_program(
-            "train_step",
-            (self._state, sample, self._step_scalars(0, weight), self._macc),
-            top_n,
-        )
+        return self._fusion_audit_update("single", sample, weight, top_n)
 
     def fusion_audit_scan(self, stacked, top_n: int = 5):
         """Fusion audit of the grad-accumulation scan program (buffer or
         adama mode) — the program whose peak-memory section the memory-
         headroom regression checks compare across
         {zero-stage} x {grad-accum} (docs/performance.md)."""
-        return self._fusion_audit_program(
-            self._scan_jit_name(),
-            (self._state, stacked, self._step_scalars(0), self._macc),
-            top_n,
-        )
+        return self._fusion_audit_update("scan", stacked, top_n=top_n)
 
-    def _fusion_audit_program(self, name, call_args, top_n):
+    def _fusion_audit_update(self, kind, data, weight=1.0, top_n: int = 5):
+        """Audit the program an update of ``kind`` runs, on the arguments
+        :meth:`_dispatch` would launch it with."""
         from unicore_tpu.analysis import fusion_audit as _fa
 
-        fn = self._jit_cache.get(name)
-        if fn is None:
+        name, args = self._update_program(self._state, kind, data, weight)
+        if name not in self._jit_cache:
             logger.warning(f"fusion-audit: no compiled {name} program")
             return None
         try:
-            lowered = fn.lower(*call_args)
-            compiled = lowered.compile()
+            compiled = self._jit_cache[name].lower(
+                *args, self._sums_in(name, args, self._macc)
+            ).compile()
         except Exception as e:
             logger.warning(f"fusion-audit: compile failed: {e!r}")
             return None
@@ -1589,8 +1581,8 @@ class Trainer(object):
         log_interval / validation / epoch boundaries."""
         if self._macc is None:
             return
-        # fetch-and-reset: the accumulator restarts from None so fp32 sums
-        # never grow past the precision horizon on long runs
+        # fetch-and-reset: the next update's sums begin anew (_sums_in), so
+        # fp32 sums never grow past the precision horizon on long runs
         delta = {k: float(v) for k, v in jax.device_get(self._macc).items()}
         self._macc = None
         self._nan_rerun_seen = 0.0  # accumulator reset; re-arm the detector
@@ -1793,13 +1785,16 @@ class Trainer(object):
             self.init_state(sample)
         sample, weight = self._prepare_sample_or_dummy(sample)
         params = self._eval_params()
-        scalars = self._step_scalars(0, weight, seed=seed)
+        args = (params, sample, self._step_scalars(0, weight, seed=seed))
+        out = self._get_jit("valid_step")(
+            *args,
+            self._sums_in(
+                "valid_step", args, self._vacc if accumulate else None
+            ),
+        )
         if accumulate:
-            self._vacc = self._get_jit("valid_step")(
-                params, sample, scalars, self._vacc
-            )
+            self._vacc = out
             return None
-        out = self._get_jit("valid_step")(params, sample, scalars, None)
         out.pop("_n", None)
         # weight-0 dummy (shard-tail alignment) batches still RUN the step —
         # multi-host collectives must stay aligned — but their all-zero
