@@ -163,6 +163,29 @@ def moe_layer_and_params(d=32, n=48):
     return whole, params, h
 
 
+def moe_by_hand(p, h, held, top_k=MOE["top_k"], scale=MOE["routed_scale"]):
+    """The layer in the gather form, the oracle: ``top_k``'s indices, the
+    chosen scores read by ``take_along_axis``, a plain loop over the
+    experts ``held`` with ALL experts' weights in ``p``.  Returns
+    ``(y (n, d), idx (n, top_k))``."""
+    tokens = h.reshape(-1, h.shape[-1])
+    s = jax.nn.sigmoid(tokens @ p["router"])
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(p["correction"]), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=1)
+    w = chosen / chosen.sum(-1, keepdims=True) * scale
+    latent = tokens @ p["latent_down"]["kernel"]
+    routed = jnp.zeros_like(latent)
+    for e in held:
+        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)
+        routed = routed + w_e[:, None] * (
+            relu2(latent @ p["experts_fc1"][e]) @ p["experts_fc2"][e]
+        )
+    y = routed @ p["latent_up"]["kernel"] + (
+        relu2(tokens @ p["shared_fc1"]["kernel"]) @ p["shared_fc2"]["kernel"]
+    )
+    return y, idx
+
+
 def test_expert_shares_add_up_to_the_uncut_layer():
     """16 experts over 4 shares: the held parts of all shares, with the
     shared expert counted once, are the uncut layer's output."""
@@ -210,23 +233,8 @@ def test_no_pair_is_lost_under_a_routing_skewed_on_purpose(monkeypatch, favoured
     )
 
     # the same, by hand: every chosen (token, held expert) pair, one by one
-    tokens = h.reshape(n, d)
-    s = jax.nn.sigmoid(tokens @ p["router"])
-    _, idx = jax.lax.top_k(s + p["correction"], MOE["top_k"])
-    chosen = jnp.take_along_axis(s, idx, axis=1)
-    w = chosen / chosen.sum(-1, keepdims=True) * MOE["routed_scale"]
-    latent = tokens @ p["latent_down"]["kernel"]
-    routed = jnp.zeros_like(latent)
-    loads = []
-    for e in range(4, 8):
-        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)
-        loads.append(int((idx == e).sum()))
-        routed = routed + w_e[:, None] * (
-            relu2(latent @ p["experts_fc1"][e]) @ p["experts_fc2"][e]
-        )
-    want = routed @ p["latent_up"]["kernel"] + (
-        relu2(tokens @ p["shared_fc1"]["kernel"]) @ p["shared_fc2"]["kernel"]
-    )
+    want, idx = moe_by_hand(p, h, range(4, 8))
+    loads = [int((idx == e).sum()) for e in range(4, 8)]
     np.testing.assert_allclose(got.reshape(n, d), want, atol=5e-5)
     assert float(st[STATS.index("pairs_here")]) == sum(loads)
     assert float(st[STATS.index("load_max")]) == n
@@ -234,6 +242,90 @@ def test_no_pair_is_lost_under_a_routing_skewed_on_purpose(monkeypatch, favoured
     if len(favoured) == 4:  # the buffer's worst case, reached
         assert sum(loads) == n * 4
         assert latent_moe.buffer_rows(n, MOE["top_k"], 4) == n * 4 + 4 * 8
+
+
+def router_case(ties, n=96):
+    """The layer's parameters (all 16 experts) and input with a selection
+    bias that is not zero, and ties at the k-th place made on purpose:
+    ``"scores"``: two router columns equal (and their bias), so two experts
+    score alike on every token and some tokens have room for only one of
+    them; ``"bias"``: a bias of 2**22 on six experts, where float32 holds
+    halves only, so ``s + b`` ties where ``s`` does not."""
+    _, params, h = moe_layer_and_params(n=n)
+    p = dict(params["params"])
+    p["correction"] = 0.05 * jax.random.normal(jax.random.key(7), (16,))
+    if ties == "scores":
+        p["router"] = p["router"].at[:, 9].set(p["router"][:, 5])
+        p["correction"] = p["correction"].at[9].set(p["correction"][5])
+    elif ties == "bias":
+        p["correction"] = p["correction"].at[
+            jnp.asarray([2, 3, 7, 8, 12, 13])].set(2.0 ** 22)
+    return p, h
+
+
+@pytest.mark.parametrize("ties", ["scores", "bias"])
+def test_the_chosen_set_is_top_ks_under_ties(ties):
+    """Exactly ``top_k`` experts a token, and ``lax.top_k``'s own, where
+    the k-th place is tied: ``>=`` alone would take both of a tied pair."""
+    from unicore_tpu.modules.latent_moe import top_k_set
+
+    p, h = router_case(ties)
+    k = MOE["top_k"]
+    s = jax.nn.sigmoid(h.reshape(-1, h.shape[-1]) @ p["router"])
+    x = s + p["correction"]
+    vals, want = jax.lax.top_k(x, k)
+    # rows where ``>=`` alone would take more than ``top_k``
+    assert ((x >= vals[:, -1:]).sum(-1) > k).sum() >= 3, "no tie at the k-th place"
+    if ties == "scores":   # one of the twins in, the other out
+        assert ((want == 5).any(1) & ~(want == 9).any(1)).any()
+    else:                  # no two scores of a token are equal
+        assert all(len(set(row)) == 16 for row in np.asarray(s).tolist())
+    idx, sel = jax.jit(top_k_set, static_argnums=1)(x, k)
+    np.testing.assert_array_equal(idx, want)
+    np.testing.assert_array_equal(sel.sum(-1), k)
+    np.testing.assert_array_equal(
+        sel, (want[:, :, None] == jnp.arange(16)).any(1))
+
+
+@pytest.mark.parametrize("n_held,first_held,ties", [
+    (4, 0, None), (4, 4, None), (16, 0, None), (4, 4, "scores"), (16, 0, "bias"),
+], ids=["first-share", "second-share", "all-held", "ties-in-the-scores",
+        "ties-from-the-bias"])
+def test_the_dense_router_is_the_gather_form_with_its_gradients(
+        n_held, first_held, ties):
+    """The layer sums the chosen scores where they lie and cuts the held
+    weights out of the scores (no ``take_along_axis``): its output and
+    the gradients of the router, the input and the experts' weights are
+    the gather form's, to float32 rounding; the selection bias gets no
+    gradient from either."""
+    p, h = router_case(ties)
+    held = slice(first_held, first_held + n_held)
+    g = jax.random.normal(jax.random.key(8), h.shape)
+    layer = LatentMoE(h.shape[-1], n_held=n_held, first_held=first_held, **MOE)
+
+    def put(router, correction, h, fc1, fc2):
+        return dict(p, router=router, correction=correction,
+                    experts_fc1=fc1, experts_fc2=fc2), h
+
+    def dense(*a):
+        q, h = put(*a)
+        share = dict(q, experts_fc1=q["experts_fc1"][held],
+                     experts_fc2=q["experts_fc2"][held])
+        return jnp.sum(layer.apply({"params": share}, h)[0] * g)
+
+    def gathered(*a):
+        q, h = put(*a)
+        y, _ = moe_by_hand(q, h, range(held.start, held.stop))
+        return jnp.sum(y.reshape(h.shape) * g)
+
+    args = (p["router"], p["correction"], h, p["experts_fc1"], p["experts_fc2"])
+    every = tuple(range(len(args)))
+    got, g_got = jax.value_and_grad(dense, every)(*args)
+    want, g_want = jax.value_and_grad(gathered, every)(*args)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())))
+    assert g_want[0].any() and not g_got[1].any() and not g_want[1].any()
 
 
 def _loads(n, loads):
@@ -541,3 +633,27 @@ def test_the_step_holds_no_worst_case_buffer_of_rows(tmp_path):
     # index arrays and a tile of rows are in the program
     assert {n * lat, rows, TILE * lat, n * held} <= sizes
     assert rows * lat not in sizes and n * held * lat not in sizes
+
+
+def test_the_router_holds_no_gather_and_no_scatter():
+    """A small ``LatentMoE``'s loss and gradient, compiled: no ``gather``
+    and no ``scatter`` runs under ``moe_router``, forward or backward (the
+    chosen scores are summed where they lie, the held ones cut out as a
+    static slice), while the search does find the tiles' under
+    ``moe_routed``."""
+    layer = LatentMoE(32, n_held=4, first_held=4, **MOE)
+    _, params, h = moe_layer_and_params()
+    share = dict(params["params"])
+    share.update(experts_fc1=share["experts_fc1"][4:8],
+                 experts_fc2=share["experts_fc2"][4:8])
+    step = jax.jit(jax.value_and_grad(
+        lambda p, h: jnp.sum(layer.apply({"params": p}, h)[0] ** 2), (0, 1)))
+    text = step.lower(share, h).compile().as_text()
+    moved = re.findall(
+        r"= \S+ (gather|scatter)\(.*op_name=\"([^\"]*)\"", text)
+    assert {op for op, path in moved if "moe_routed" in path} == {"gather", "scatter"}
+    assert not [m for m in moved if "moe_router" in m[1]], moved
+    # the router is in the program under its name, both ways
+    router = re.findall(r"op_name=\"([^\"]*moe_router[^\"]*)\"", text)
+    assert any("transpose(" in path for path in router)
+    assert any("transpose(" not in path for path in router)

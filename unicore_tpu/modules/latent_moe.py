@@ -4,7 +4,7 @@ sigmoid scores, a selection bias, normalised and scaled weights).
 
     s = sigmoid(h W_r)                      float32, over ALL n_routed
     chosen = the top_k largest of s + b_corr
-    w = s_chosen / sum(s_chosen) * routed_scale
+    w_e = s_e / sum_{chosen} s * routed_scale   for e chosen
     l = h W_down                            embed_dim -> latent_dim
     r = sum_{e chosen and held} w_e W2_e relu(W1_e l)^2
     y = r W_up + W2_s relu(W1_s h)^2        the shared expert, full width
@@ -18,6 +18,24 @@ the shared expert are whole on every chip, so summing the ``r`` of all
 shares and adding the shared expert once gives the uncut layer
 (``tests/test_hybrid_lm.py``).  Nothing here stands in for the absent
 chips; the exchange across chips is not built (ROADMAP R8).
+
+The router reads its chosen scores where they lie.  ``top_k`` gives the
+indices; the weights need only the SUM of the chosen scores and the
+scores of the experts held, and both are in the dense ``(tokens,
+n_routed)`` array ``s`` already: the sum is ``sum(where(sel, s, 0))``
+over a mask ``sel`` of ``top_k``'s set (:func:`top_k_set`), and the held
+experts' scores are a static slice ``s[:, first_held : first_held +
+n_held]``, zeroed off the pairs.  So no ``take_along_axis`` reads 22
+scalars a token out of ``s``, and the backward has no scatter-add into
+``(tokens, n_routed)``: the cotangent of ``s`` is a pad of the slice's and
+a broadcast of the sum's under ``sel``.  ``sel`` marks what lies above the
+k-th value ``top_k`` returns, and of what is level with it the columns up
+to the last index ``top_k`` took: ``>=`` alone would take every column
+tied at the k-th place, more than ``top_k`` of them (with float32 scores
+over 512 experts two are level there about once in 40,000 tokens, and a
+selection bias can tie what the scores do not).  The 22 scores are added
+in index order where the gather form added them in score order: float32
+rounding.
 
 No capacity factor and no dropped pair.  The (token, held expert) pairs
 are laid out expert by expert in rows shared by the held experts, each
@@ -72,6 +90,20 @@ def buffer_rows(n, top_k, n_held):
     nearly empty.  No routing needs more."""
     rows = n * min(top_k, n_held) + n_held * TILE
     return -(-rows // TILE) * TILE
+
+
+def top_k_set(x, k):
+    """``lax.top_k``'s choice among each row of ``x`` (n, E), twice: its
+    indices ``idx`` (n, k) and the same set as a mask ``sel`` (n, E) with
+    exactly ``k`` true in a row, so that what is summed over the chosen
+    can be summed where it lies, with no gather.  A column is chosen when
+    it lies above the k-th value, or level with it and no further along
+    than the last index ``top_k`` took: ``top_k`` takes equal values in
+    index order, so of those level with the k-th it took the first ones."""
+    vals, idx = jax.lax.top_k(x, k)
+    kth, last = vals[:, -1:], idx[:, -1:]
+    along = jax.lax.broadcasted_iota(idx.dtype, x.shape, 1)
+    return idx, (x > kth) | ((x == kth) & (along <= last))
 
 
 def buffer_layout(pair, w_held, rows):
@@ -268,17 +300,18 @@ class LatentMoE(nn.Module):
                 else jax.lax.Precision.HIGHEST,
             )
             s = jax.nn.sigmoid(logits)
-            _, idx = jax.lax.top_k(
-                s + jax.lax.stop_gradient(b_corr.astype(f32)), self.top_k
+            # the selection is not differentiated: it only decides WHICH
+            # scores are summed
+            idx, sel = top_k_set(
+                jax.lax.stop_gradient(s + b_corr.astype(f32)), self.top_k
             )
-            chosen = jnp.take_along_axis(s, idx, axis=1)          # (n, k)
-            w = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
-            w = w * self.routed_scale
-            held = idx[:, :, None] == (
+            denom = jnp.sum(jnp.where(sel, s, 0.0), axis=-1, keepdims=True)
+            pair = (idx[:, :, None] == (
                 self.first_held + jnp.arange(Eh, dtype=idx.dtype)
-            )                                                      # (n, k, Eh)
-            w_held = jnp.sum(w[:, :, None] * held, axis=1)         # (n, Eh)
-            pair = held.any(axis=1)                                # (n, Eh)
+            )).any(axis=1)                          # (n, k, Eh) -> (n, Eh)
+            s_held = s[:, self.first_held:self.first_held + Eh]
+            w_held = jnp.where(pair, s_held, 0.0) / (denom + 1e-20)
+            w_held = w_held * self.routed_scale                    # (n, Eh)
             load = pair.sum(axis=0)                                # (Eh,)
 
         with jax.named_scope("moe_latent"):
