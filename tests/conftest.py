@@ -48,6 +48,20 @@ def pallas_interpret_mode(request):
     _pallas.set_interpret(prev)
 
 
+@pytest.fixture(autouse=True)
+def global_mesh_as_found():
+    """Every test leaves the process's global mesh as it found it: a test
+    that builds a ``Trainer`` (which lays its mesh over every device and
+    publishes it) cannot change what a later test in the same worker
+    traces (``_flash_data_parallel`` wraps the kernels in a ``shard_map``
+    over whatever mesh is published)."""
+    from unicore_tpu.parallel import mesh as mesh_mod
+
+    was = mesh_mod._global_mesh
+    yield
+    mesh_mod._global_mesh = was
+
+
 # ---------------------------------------------------------------------------
 # `-m fast` smoke subset: finishes in ~1 minute on one CPU core, touching
 # data pipeline, logging, optim/schedulers, checkpointing, kernels (jnp

@@ -8,6 +8,7 @@ from .dictionary import Dictionary
 from .lru_cache_dataset import LRUCacheDataset
 from .mask_tokens_dataset import MaskTokensDataset
 from .bert_tokenize_dataset import BertTokenizeDataset
+from .byte_tokenize_dataset import ByteDictionary, ByteTokenizeDataset
 from .misc_datasets import (
     AppendTokenDataset,
     FromNumpyDataset,
@@ -46,6 +47,8 @@ __all__ = [
     "BaseWrapperDataset",
     "BertTokenizeDataset",
     "BufferedIterator",
+    "ByteDictionary",
+    "ByteTokenizeDataset",
     "CountingIterator",
     "Dictionary",
     "EpochBatchIterator",

@@ -10,7 +10,15 @@ final hidden states instead of its logits, and the output head and the
 loss run over ``loss_chunk`` tokens at a time (:func:`chunked_lm_nll`): at
 8,192 x 16,384 the float32 logits alone would be 512 MB, and as much again
 for their gradient.  What such a model returns beside the hidden states
-(an expert layer's routing stats) goes into the logging output.
+(an expert layer's routing stats, an attention layer's key counts) goes
+into the logging output.
+
+A model that states ``num_pred_heads`` = M > 1 (models/evabyte.py) predicts
+the next M tokens at every position: its head's columns are M blocks of
+the vocabulary, block ``m`` at position ``t`` is scored against token
+``t + m`` (m = 1 .. M), a target past the row's end (or a pad) does not
+count, and every (position, head) pair that counts weighs the same: the
+loss is their sum and the sample size their number.
 """
 
 import jax
@@ -24,16 +32,19 @@ from .unicore_loss import UnicoreLoss
 def chunked_lm_nll(x, kernel, target, valid, chunk):
     """Summed next-token negative log-likelihood with the logits of only
     ``chunk`` tokens alive at a time.  ``x`` (T, d) hidden states, ``kernel``
-    (d, V), ``target`` (T,) and ``valid`` (T,) already shifted.  Each
+    (d, V), ``target`` (T,) and ``valid`` (T,) already shifted; or
+    ``kernel`` (d, M * V) with ``target`` and ``valid`` (T, M), column
+    ``m`` the head's ``m``-th block of ``V`` logits' own target.  Each
     chunk's logits are float32 (the product's accumulator, not a rounded
     copy) and are computed again in the backward pass.  The kernel's
     cotangent is summed over the chunks in float32."""
     T, d = x.shape
     pad = (-T) % chunk
     if pad:
+        rows = ((0, pad),) + ((0, 0),) * (target.ndim - 1)
         x = jnp.pad(x, ((0, pad), (0, 0)))
-        target = jnp.pad(target, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
+        target = jnp.pad(target, rows)
+        valid = jnp.pad(valid, rows)
     kernel32 = kernel.astype(jnp.float32)
 
     @jax.checkpoint
@@ -43,15 +54,31 @@ def chunked_lm_nll(x, kernel, target, valid, chunk):
             logits = jnp.dot(xc, kernel32.astype(xc.dtype),
                              preferred_element_type=jnp.float32)
         with jax.named_scope("loss"):
+            logits = logits.reshape(tc.shape + (-1,))
             lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+            picked = jnp.take_along_axis(
+                logits, tc[..., None], axis=-1
+            )[..., 0]
             return jnp.sum(jnp.where(vc, lse - picked, 0.0))
 
     n = (T + pad) // chunk
     return jnp.sum(jax.lax.map(one, (
-        x.reshape(n, chunk, d), target.reshape(n, chunk),
-        valid.reshape(n, chunk),
+        x.reshape(n, chunk, d), target.reshape((n, chunk) + target.shape[1:]),
+        valid.reshape((n, chunk) + valid.shape[1:]),
     )))
+
+
+def shifted_targets(target, heads, pad_idx):
+    """``target`` (B, L) -> what position ``t`` predicts: (B, L), token
+    ``t + 1``, for one head; (B, L, heads), tokens ``t + 1 .. t + heads``,
+    for more.  A target past the row's end is ``pad_idx``."""
+    B = target.shape[0]
+    ahead = lambda m: jnp.concatenate(
+        [target[:, m:], jnp.full((B, m), pad_idx, target.dtype)], axis=1
+    )
+    if heads == 1:
+        return ahead(1)
+    return jnp.stack([ahead(m) for m in range(1, heads + 1)], axis=-1)
 
 
 @register_loss("lm_cross_entropy")
@@ -61,8 +88,11 @@ class LMCrossEntropyLoss(UnicoreLoss):
         self.padding_idx = task.dictionary.pad()
 
     def forward(self, model, params, sample, rngs=None, train=True):
-        if getattr(model, "loss_chunk", 0):
-            return self._forward_chunked(model, params, sample, rngs, train)
+        heads = getattr(model, "num_pred_heads", 1)
+        if getattr(model, "loss_chunk", 0) or heads > 1:
+            return self._forward_chunked(
+                model, params, sample, rngs, train, heads
+            )
         logits = model.apply(
             params, **sample["net_input"], train=train, rngs=rngs
         )
@@ -86,22 +116,20 @@ class LMCrossEntropyLoss(UnicoreLoss):
         }
         return loss, sample_size, logging_output
 
-    def _forward_chunked(self, model, params, sample, rngs, train):
+    def _forward_chunked(self, model, params, sample, rngs, train, heads=1):
         x, extra = model.apply(
             params, **sample["net_input"], train=train, rngs=rngs,
             features_only=True,
         )
         B, L, d = x.shape
-        # every position predicts its successor; the last has none
-        target = jnp.concatenate(
-            [sample["target"][:, 1:],
-             jnp.full((B, 1), self.padding_idx, sample["target"].dtype)],
-            axis=1,
-        ).reshape(B * L)
+        # every position predicts its successor(s); the last has none
+        target = shifted_targets(sample["target"], heads, self.padding_idx)
+        target = target.reshape((B * L,) + target.shape[2:])
         valid = target != self.padding_idx
         loss = chunked_lm_nll(
             x.reshape(B * L, d), params["params"]["lm_head"],
-            jnp.where(valid, target, 0), valid, int(model.loss_chunk),
+            jnp.where(valid, target, 0), valid,
+            int(model.loss_chunk) or B * L,
         )
         sample_size = jnp.sum(valid).astype(jnp.float32)
         logging_output = {
@@ -136,16 +164,28 @@ class LMCrossEntropyLoss(UnicoreLoss):
         (token, held expert) pairs of all its expert layers, the tiles of
         ``latent_moe.TILE`` rows they filled (what dispatch and combine
         moved, each way) and the most loaded held expert's and the mean
-        load, per layer."""
+        load, per layer; for a model with window-plus-summary attention
+        one ``unicore:eva_keys`` mark with the keys its kernel form scored
+        and the keys its queries could see."""
+        marks = {}
         layers = sums.get("moe_layers", 0)
-        if not layers:
-            return {}
-        return {"moe_route": dict(
-            pairs_here=int(sums["moe_pairs_here"]),
-            tiles_used=int(sums["moe_tiles_used"]),
-            load_max=sums["moe_load_max"] / layers,
-            load_mean=sums["moe_load_mean"] / layers,
-        )}
+        if layers:
+            marks["moe_route"] = dict(
+                pairs_here=int(sums["moe_pairs_here"]),
+                tiles_used=int(sums["moe_tiles_used"]),
+                load_max=sums["moe_load_max"] / layers,
+                load_mean=sums["moe_load_mean"] / layers,
+            )
+        if sums.get("eva_rows", 0):
+            # per layer and head, summed over the update's queries
+            # (models/evabyte.py, ops/eva_attention.key_counts)
+            marks["eva_keys"] = dict(
+                keys_computed=int(sums["eva_keys_computed"]),
+                keys_visible=int(sums["eva_keys_visible"]),
+                windows=int(sums["eva_windows"]),
+                chunks=int(sums["eva_chunks"]),
+            )
+        return marks
 
     @staticmethod
     def logging_outputs_can_be_summed(is_train) -> bool:

@@ -1,11 +1,14 @@
 """A decoder whose layers follow a pattern string (``nemotron_h``'s
 ``hybrid_override_pattern``): ``M`` a Mamba-2 mixer, ``*`` grouped-KV
-causal attention, ``E`` a LatentMoE with a shared expert.  Every layer is
+causal attention, ``E`` a LatentMoE with a shared expert, ``A`` EVA
+attention (a window's keys plus the earlier windows' chunk summaries, with
+rotary positions), ``F`` a gated feed-forward layer.  Every layer is
 
     x = x + mixer(RMSNorm(x))
 
 with one mixer per layer, a final RMSNorm after the last, no biases (the
-convolution's apart) and no dropout.
+convolution's apart) and no dropout.  A transformer layer of the usual
+kind is two of these: ``AF``.
 
 A pattern whose tail repeats (``*EMEMEMEMEM`` = ``*`` + 5 x ``EM``) runs
 the repeated unit as ONE traced body under ``nn.scan``, its parameters
@@ -17,17 +20,19 @@ Each layer (each unit, under the scan) is rematerialized in the backward
 pass when ``remat`` is set: only the residual stream is kept.
 """
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax.numpy as jnp
 
+from .eva_attention import EvaAttention
+from .gated_mlp import GatedMLP
 from .latent_moe import STATS, LatentMoE
 from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
 from .multihead_attention import GroupedQueryAttention
 
-KINDS = "M*E"
+KINDS = "M*EAF"
 
 
 def split_pattern(pattern: str) -> Tuple[str, str, int]:
@@ -51,13 +56,17 @@ class HybridBlock(nn.Module):
     kind: str
     embed_dim: int
     norm_eps: float
-    mamba: dict
-    attention: dict
-    moe: dict
+    mamba: Optional[dict] = None
+    attention: Optional[dict] = None
+    moe: Optional[dict] = None
+    eva: Optional[dict] = None
+    mlp: Optional[dict] = None
+    norm_unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        h = RMSNorm(self.embed_dim, eps=self.norm_eps, name="norm")(x)
+        h = RMSNorm(self.embed_dim, eps=self.norm_eps, name="norm",
+                    unit_offset=self.norm_unit_offset)(x)
         stats = jnp.zeros((len(STATS),), jnp.float32)
         if self.kind == "M":
             y = Mamba2Mixer(self.embed_dim, name="mamba", **self.mamba)(h)
@@ -67,6 +76,10 @@ class HybridBlock(nn.Module):
             )(h)
         elif self.kind == "E":
             y, stats = LatentMoE(self.embed_dim, name="moe", **self.moe)(h)
+        elif self.kind == "A":
+            y = EvaAttention(self.embed_dim, name="self_attn", **self.eva)(h)
+        elif self.kind == "F":
+            y = GatedMLP(self.embed_dim, name="mlp", **self.mlp)(h)
         else:
             raise ValueError(
                 f"layer kind {self.kind!r} is not one of {KINDS!r}"
@@ -93,10 +106,14 @@ class HybridDecoder(nn.Module):
     pattern: str
     embed_dim: int
     norm_eps: float
-    mamba: dict       # Mamba2Mixer's sizes
-    attention: dict   # GroupedQueryAttention's sizes
-    moe: dict         # LatentMoE's sizes
+    # the sizes of each layer kind the pattern holds
+    mamba: Optional[dict] = None       # M: Mamba2Mixer's
+    attention: Optional[dict] = None   # *: GroupedQueryAttention's
+    moe: Optional[dict] = None         # E: LatentMoE's
+    eva: Optional[dict] = None         # A: EvaAttention's
+    mlp: Optional[dict] = None         # F: GatedMLP's
     remat: bool = True
+    norm_unit_offset: bool = False  # every norm's gain is 1 + its parameter
 
     @nn.compact
     def __call__(self, x):
@@ -104,7 +121,9 @@ class HybridDecoder(nn.Module):
         stream and the expert layers' routing stats summed over layers
         (``latent_moe.STATS``; all zero where no layer is ``E``)."""
         block = dict(embed_dim=self.embed_dim, norm_eps=self.norm_eps,
-                     mamba=self.mamba, attention=self.attention, moe=self.moe)
+                     mamba=self.mamba, attention=self.attention, moe=self.moe,
+                     eva=self.eva, mlp=self.mlp,
+                     norm_unit_offset=self.norm_unit_offset)
         wrap = nn.remat if self.remat else (lambda cls: cls)
         head, unit, repeats = split_pattern(self.pattern)
         stats = jnp.zeros((len(STATS),), jnp.float32)
@@ -116,5 +135,6 @@ class HybridDecoder(nn.Module):
                 wrap(_Unit), variable_axes={"params": 0},
                 split_rngs={"params": True}, length=repeats,
             )(pattern=unit, block=block, name="units")((x, stats), None)
-        x = RMSNorm(self.embed_dim, eps=self.norm_eps, name="final_norm")(x)
+        x = RMSNorm(self.embed_dim, eps=self.norm_eps, name="final_norm",
+                    unit_offset=self.norm_unit_offset)(x)
         return x, stats

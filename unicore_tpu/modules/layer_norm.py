@@ -147,19 +147,32 @@ class LayerNorm(nn.Module):
 
 class RMSNorm(nn.Module):
     """RMSNorm (reference /root/reference/unicore/modules/rms_norm.py):
-    no mean subtraction, scale-only affine, fp32 statistics."""
+    no mean subtraction, scale-only affine, fp32 statistics.
+
+    With ``unit_offset`` the gain is ``1 + offset`` and the parameter is
+    the offset, zero at the start (``norm_add_unit_offset`` of the Gemma /
+    EvaByte kind: weight decay and a small initializer then pull the gain
+    towards one, not towards zero)."""
 
     normalized_shape: int
     eps: float = 1e-6
     elementwise_affine: bool = True
     use_pallas: Optional[bool] = None  # None = follow --fused-norm
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         assert self.elementwise_affine
-        weight = self.param(
-            "weight", nn.initializers.ones, (self.normalized_shape,), jnp.float32
-        )
+        if self.unit_offset:
+            weight = 1.0 + self.param(
+                "offset", nn.initializers.zeros, (self.normalized_shape,),
+                jnp.float32,
+            )
+        else:
+            weight = self.param(
+                "weight", nn.initializers.ones, (self.normalized_shape,),
+                jnp.float32,
+            )
         if _use_pallas(self.use_pallas, "RMSNorm", self.normalized_shape):
             from unicore_tpu.ops.fused_norm import fused_rms_norm
 

@@ -1,0 +1,151 @@
+"""EVA attention (Zheng et al., "Efficient Attention via Control Variates",
+ICLR 2023) in the form EvaByte trains with: exact causal attention inside a
+window, and for every earlier window one learned-pooled key/value per
+chunk, all under ONE softmax.
+
+With the row cut into windows of ``window`` positions and chunks of
+``chunk`` (``window // chunk`` chunks a window), per head with its learned
+pooling vectors ``mu, phi`` (D,) and ``s`` the softmax scale:
+
+    k~_c = sum_{j in c} softmax_j(s k_j . mu) k_j
+    v~_c = sum_{j in c} softmax_j(s k_j . phi) v_j        (:func:`eva_prep_kv`)
+
+and query ``i`` of window ``w`` scores its window's keys ``j <= i`` by
+``s q_i . k_j`` and every chunk ``c`` of the windows before ``w`` by
+``s q_i . k~_c``; one softmax over the union; the output is ``sum_j p_ij
+v_j + sum_c p_ic v~_c`` (:func:`eva_agg`).  In window 0 that is plain
+causal attention.
+
+:func:`eva_agg` treats windows as batch rows of the blockwise flash kernels
+(``ops/flash_attention.py``): window ``w``'s queries against ``[its own
+keys ; every chunk summary of the row, padded to the kernel's tile]`` under
+a grouped bias ``(W, 1, window, window + summaries)`` made of iotas: the
+causal triangle on the left, "the chunk's window is before ``w``" on the
+right.  The bias is a constant, so no bias gradient is computed.  That
+form computes about twice the keys a query may see (the upper triangle and
+the later windows' summaries are scored and masked); a kernel that skips
+masked blocks is ROADMAP S6, and :func:`key_counts` is the counter that
+sizes it.  Off the TPU (and outside interpret mode) the same operands go
+through XLA's own softmax.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from unicore_tpu.ops._pallas import LANE, interpret_enabled
+from unicore_tpu.platform_utils import on_tpu
+
+NEG = -1e30  # big finite, as the kernels' own mask value
+
+
+def eva_prep_kv(k, v, mu, phi, chunk, scale):
+    """The two pooled summaries of every chunk.  ``k, v`` (B, H, L, D) with
+    ``L`` a multiple of ``chunk``; ``mu, phi`` (H, D).  Returns ``(k~, v~)``,
+    each (B, H, L // chunk, D) in ``k``'s dtype; the pooling weights are a
+    float32 softmax inside each chunk.  Memory-bound: reads ``k, v`` once,
+    writes a ``chunk``-th of them."""
+    with jax.named_scope("eva_prep_kv"):
+        B, H, L, D = k.shape
+        kc = k.reshape(B, H, L // chunk, chunk, D)
+        vc = v.reshape(B, H, L // chunk, chunk, D)
+
+        def pooled(x, vec):
+            logits = jnp.einsum(
+                "bhncd,hd->bhnc", kc, vec.astype(kc.dtype),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            w = jax.nn.softmax(logits, axis=-1)
+            return jnp.einsum(
+                "bhnc,bhncd->bhnd", w, x.astype(jnp.float32),
+            ).astype(k.dtype)
+
+        return pooled(kc, mu), pooled(vc, phi)
+
+
+def visibility_bias(n_windows, window, chunks_per_window, n_summaries, dtype):
+    """The additive mask ``(W, 1, window, window + n_summaries)`` of
+    :func:`eva_agg`: 0 where window ``w``'s query ``i`` may see the key,
+    ``NEG`` elsewhere.  Columns ``< window`` are the window's own keys
+    (``j <= i``); column ``window + c`` is chunk summary ``c`` of the row
+    (its window ``c // chunks_per_window`` is before ``w``; columns past
+    the row's last chunk are padding).  Made of iotas, so the compiled
+    program computes it and folds no constant of this size."""
+    Lk = window + n_summaries
+    shape = (n_windows, 1, window, Lk)
+    w = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 3)
+    local = col <= row
+    # (col - window) // chunks_per_window < w, without a division
+    summary = col - window < w * chunks_per_window
+    seen = jnp.where(col < window, local, summary)
+    return jnp.where(seen, 0.0, NEG).astype(dtype)
+
+
+def uses_kernel(window, head_dim, dtype):
+    """Whether :func:`eva_agg` hands its operands to the Mosaic flash
+    kernels (a TPU, or interpret mode; windows the kernel's tile divides).
+    The projections read it before q, k, v exist, to write the kernel's
+    ``(B, H, L, D)`` layout themselves."""
+    return (
+        (on_tpu() or interpret_enabled())
+        and window % LANE == 0 and head_dim % 8 == 0
+        and dtype in (jnp.float32, jnp.bfloat16)
+    )
+
+
+def eva_agg(q, k, v, k_sum, v_sum, window, chunk, scale):
+    """The joint softmax.  ``q, k, v`` (B, H, L, D); ``k_sum, v_sum``
+    (B, H, L // chunk, D) from :func:`eva_prep_kv`; ``L`` a multiple of
+    ``window``, ``window`` of ``chunk``.  Returns (B, H, L, D)."""
+    with jax.named_scope("eva_agg"):
+        B, H, L, D = q.shape
+        W, cpw = L // window, window // chunk
+        n_sum = L // chunk
+        kernel = uses_kernel(window, D, q.dtype)
+        pad = (-n_sum) % LANE if kernel else 0
+
+        def windows(x):  # (B, H, L, D) -> (W * B, H, window, D), window-major
+            x = x.reshape(B, H, W, window, D)
+            return jnp.moveaxis(x, 2, 0).reshape(W * B, H, window, D)
+
+        def with_summaries(local, summary):
+            if pad:
+                summary = jnp.pad(summary, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            summary = jnp.broadcast_to(
+                summary[None], (W,) + summary.shape
+            ).reshape(W * B, H, n_sum + pad, D)
+            return jnp.concatenate([windows(local), summary], axis=2)
+
+        qw = windows(q)
+        kw, vw = with_summaries(k, k_sum), with_summaries(v, v_sum)
+        bias = visibility_bias(W, window, cpw, n_sum + pad, q.dtype)
+        if kernel:
+            from unicore_tpu.ops.flash_attention import flash_attention
+
+            # batch row w * B + b reads bias group (w * B + b) // B = w
+            o = flash_attention(qw, kw, vw, bias=bias, sm_scale=scale)
+        else:
+            s = jnp.einsum("nhqd,nhkd->nhqk", qw, kw,
+                           preferred_element_type=jnp.float32) * scale
+            s = s.reshape(W, B, H, window, -1) + bias[:, None].astype(s.dtype)
+            p = jax.nn.softmax(s, axis=-1).reshape(W * B, H, window, -1)
+            o = jnp.einsum("nhqk,nhkd->nhqd", p.astype(vw.dtype), vw,
+                           preferred_element_type=jnp.float32).astype(q.dtype)
+        o = o.reshape(W, B, H, window, D)
+        return jnp.moveaxis(o, 0, 2).reshape(B, H, L, D)
+
+
+def key_counts(length, window, chunk):
+    """Per row and head, summed over the row's queries: the keys
+    :func:`eva_agg`'s flash form scores (``computed``: every window against
+    its own keys and all of the row's summaries, padded to the kernel's
+    tile) and the keys a query may see (``visible``: its window's keys up
+    to itself and the summaries of the windows before).  From shapes."""
+    W, cpw = length // window, window // chunk
+    n_sum = length // chunk
+    n_sum += (-n_sum) % LANE
+    computed = length * (window + n_sum)
+    visible = W * window * (window + 1) // 2 + window * cpw * W * (W - 1) // 2
+    return {"computed": computed, "visible": visible, "windows": W,
+            "chunks": length // chunk}

@@ -11,6 +11,10 @@ text.
 With ``--tokens-per-sample N`` the documents are not padded one per row but
 packed: joined end to end and cut into blocks of exactly ``N`` tokens
 (data/token_block_dataset.py), one block per row, every row full.
+
+With ``--tokenizer bytes`` a document is its UTF-8 bytes and the end id
+(data/byte_tokenize_dataset.py: 320 ids, no ``dict.txt``); shards, packing
+and padding are the same.
 """
 
 import logging
@@ -18,6 +22,8 @@ import os
 
 from unicore_tpu.data import (
     BertTokenizeDataset,
+    ByteDictionary,
+    ByteTokenizeDataset,
     Dictionary,
     EpochShuffleDataset,
     LRUCacheDataset,
@@ -57,6 +63,12 @@ class CausalLMTask(UnicoreTask):
                  "0 keeps one document per row, cut at --max-seq-len",
         )
 
+        parser.add_argument(
+            "--tokenizer", default="wordpiece", choices=("wordpiece", "bytes"),
+            help="wordpiece: BERT's, over the data directory's dict.txt; "
+                 "bytes: a document's UTF-8 bytes, 320 ids, no dict.txt",
+        )
+
     def __init__(self, args, dictionary):
         super().__init__(args)
         self.dictionary = dictionary
@@ -64,6 +76,8 @@ class CausalLMTask(UnicoreTask):
 
     @classmethod
     def setup_task(cls, args, **kwargs):
+        if getattr(args, "tokenizer", "wordpiece") == "bytes":
+            return cls(args, ByteDictionary())
         dictionary = Dictionary.load(os.path.join(args.data, "dict.txt"))
         logger.info(f"dictionary: {len(dictionary)} types")
         return cls(args, dictionary)
@@ -79,13 +93,17 @@ class CausalLMTask(UnicoreTask):
     def load_dataset(self, split, combine=False, **kwargs):
         a = self.args
         block = getattr(a, "tokens_per_sample", 0)
-        tokens = BertTokenizeDataset(
-            open_text_dataset(os.path.join(a.data, split)),
-            os.path.join(a.data, "dict.txt"),
-            # a document is cut to the model's positions only where it is
-            # a row of its own
-            max_seq_len=None if block else a.max_seq_len,
-        )
+        text = open_text_dataset(os.path.join(a.data, split))
+        # a document is cut to the model's positions only where it is a row
+        # of its own
+        max_seq_len = None if block else a.max_seq_len
+        if isinstance(self.dictionary, ByteDictionary):
+            tokens = ByteTokenizeDataset(text, max_seq_len=max_seq_len)
+        else:
+            tokens = BertTokenizeDataset(
+                text, os.path.join(a.data, "dict.txt"),
+                max_seq_len=max_seq_len,
+            )
         if block:
             # input and target read the same block: built once
             tokens = LRUCacheDataset(
