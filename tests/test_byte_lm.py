@@ -15,10 +15,14 @@ import pytest
 
 from unicore_tpu.ops import _pallas
 from unicore_tpu.ops.eva_attention import (
+    NEG,
     eva_agg,
     eva_prep_kv,
+    kernel_blocks,
+    kernel_map,
     key_counts,
     visibility_bias,
+    visible_blocks,
 )
 
 
@@ -89,16 +93,21 @@ def test_window_zero_is_plain_causal_attention():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-def test_flash_form_and_its_gradients_match_xlas_softmax():
-    """The grouped-bias route through the blockwise kernels (interpret
-    mode; windows of 128, so the summaries are padded to the tile) against
-    the same operands through XLA's softmax."""
-    q, k, v, mu, phi = _qkv(2, 2, 384, 16, seed=1)
+@pytest.mark.parametrize("L,window,chunk,skipped", [
+    (384, 128, 16, 0),   # summaries padded to the tile; one block a window
+    (1024, 256, 2, 2),   # blocks of (256, 384): 2 of 8 are NEG throughout
+])
+def test_flash_form_and_its_gradients_match_xlas_softmax(L, window, chunk, skipped):
+    """The grouped-bias, block-mapped route through the blockwise kernels
+    (interpret mode) against the same operands through XLA's softmax."""
+    q, k, v, mu, phi = _qkv(2, 2, L, 16, seed=1)
     scale = 16 ** -0.5
+    seen = kernel_map(L, window, chunk)[2]
+    assert seen.size - int(seen.sum()) == skipped
 
     def loss(q, k, v, mu, phi):
-        k_sum, v_sum = eva_prep_kv(k, v, mu, phi, 16, scale)
-        o = eva_agg(q, k, v, k_sum, v_sum, 128, 16, scale)
+        k_sum, v_sum = eva_prep_kv(k, v, mu, phi, chunk, scale)
+        o = eva_agg(q, k, v, k_sum, v_sum, window, chunk, scale)
         return jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(o.shape))), o
 
     plain = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
@@ -126,12 +135,49 @@ def test_visibility_bias_and_key_counts():
     counts = key_counts(24, 8, 4)
     assert counts["visible"] == int(seen[:, 0, :, :].sum())
     assert counts["windows"] == 3 and counts["chunks"] == 6
-    # the kernel form pads the row's summaries to its tile of 128
+    # the kernel form pads the row's summaries to its tile of 128; a window
+    # the tile does not divide is one block, scored whole
     assert counts["computed"] == 24 * (8 + 128)
+    # the real row: the keys of the blocks the kernels are told to visit
     real = key_counts(32768, 2048, 16)
-    assert real["computed"] == 32768 * 4096
+    assert kernel_blocks(2048, 4096) == (256, 512)
+    assert real["computed"] == 608 * 256 * 512 == 79_691_776
     assert real["visible"] == 16 * 2048 * 2049 // 2 + 2048 * 128 * 120
-    assert 2.0 < real["computed"] / real["visible"] < 2.1
+    assert real["computed"] / real["visible"] == pytest.approx(1.2255, abs=5e-5)
+
+
+@pytest.mark.parametrize("L,window,chunk,bq,bk", [
+    (1024, 256, 16, 128, 128),
+    (2048, 512, 16, 256, 128),
+    (768, 256, 2, 128, 160),   # a key block that straddles keys and summaries
+    (1024, 256, 16, 64, 96),
+    (128, 128, 16, 128, 128),  # one window: no summary is seen
+])
+def test_the_block_map_is_the_bias_slab_by_slab(L, window, chunk, bq, bk):
+    """A block is visited iff its slab of the bias is not NEG throughout."""
+    W = L // window
+    n_sum = 128 * -(-L // chunk // 128)
+    bias = np.asarray(
+        visibility_bias(W, window, window // chunk, n_sum, jnp.float32)
+    )[:, 0]
+    slabs = bias.reshape(W, window // bq, bq, (window + n_sum) // bk, bk)
+    want = (slabs > NEG).any(axis=(2, 4))
+    got = visible_blocks(L, window, chunk, bq, bk)
+    assert got.dtype == bool and np.array_equal(got, want)
+    assert 0 < got.sum() < got.size
+
+
+def test_the_real_rows_map_from_shapes_alone():
+    """16 windows of 2,048 at blocks of (256, 512): 608 of 1,024 blocks hold
+    a visible key, and 28 of the 128 key blocks are seen by no query block
+    (a window's own and later windows' summaries; the padding)."""
+    bq, bk, seen = kernel_map(32768, 2048, 16)
+    assert (bq, bk) == (256, 512) and seen.shape == (16, 8, 8)
+    assert np.array_equal(seen, visible_blocks(32768, 2048, 16, 256, 512))
+    assert int(seen.sum()) == 608
+    assert int((~seen.any(axis=1)).sum()) == 28
+    # every query block begins at a key all of its queries see
+    assert seen[:, :, 0].all()
 
 
 def test_rotary_is_the_complex_rotation():

@@ -222,3 +222,147 @@ def test_module_flash_pads_unaligned_lengths():
     ):
         scale = max(1.0, float(jnp.abs(b).max()))
         assert float(jnp.abs(a - b).max()) / scale < 5e-3
+
+
+# ---------------------------------------------------------------------------
+# the block map: the kernels visit only the blocks a mask leaves visible
+# ---------------------------------------------------------------------------
+
+def _seen_mask(case, G, Lq, Lk):
+    """(G, Lq, Lk) bool: which keys a query may see, per map group."""
+    i = np.arange(Lq)[:, None]
+    j = np.arange(Lk)[None, :]
+    causal = np.broadcast_to(j <= i, (Lq, Lk))
+    if case == "grouped":
+        # group 0 causal; group 1 its own 128 keys and the row's first 128
+        return np.stack([causal, (j // 128 == i // 128) | (j < 128)])
+    if case == "unseen-key-block":
+        # causal over 512 keys, but nobody sees keys 256 .. 383
+        return (causal & ~((j >= 256) & (j < 384)))[None]
+    if case == "single-visit":
+        # queries 128 .. 255 see the first 128 keys only, the rest causal
+        return np.where((i >= 128) & (i < 256), j < 128, causal)[None]
+    return causal[None]
+
+
+MAPPED_CASES = {
+    # name: (B, H, Lq, Lk, D, G, (block_q, block_k))
+    "causal-256x512": (2, 2, 1024, 1024, 32, 1, (256, 512)),
+    "causal-128x128": (1, 2, 1024, 1024, 32, 1, (128, 128)),
+    "grouped": (4, 1, 512, 512, 32, 2, (128, 128)),
+    "unseen-key-block": (2, 1, 512, 512, 32, 1, (128, 128)),
+    "single-visit": (1, 2, 512, 512, 32, 1, (128, 128)),
+    "bias-gradient": (1, 1, 256, 256, 32, 1, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MAPPED_CASES))
+def test_block_map_visits_what_is_seen_and_changes_no_bit(case):
+    """``flash_attention(..., block_map=m)``: forward, dq, dk and dv equal
+    the unmapped call's on the same operands and bias EXACTLY (interpret
+    mode forces no tolerance: a skipped block adds exact zeros); a key block
+    with no visitor comes back as zeros; a bias gradient is refused."""
+    B, H, Lq, Lk, D, G, (bq, bk) = MAPPED_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(keys[0], (B, H, Lq, D), jnp.float32)
+    k = jax.random.normal(keys[1], (B, H, Lk, D), jnp.float32)
+    v = jax.random.normal(keys[2], (B, H, Lk, D), jnp.float32)
+    w = jnp.cos(jnp.arange(B * H * Lq * D, dtype=jnp.float32)).reshape(q.shape)
+    seen = _seen_mask(case, G, Lq, Lk)
+    # a learned part under the mask, so the bias is not only 0 / NEG_INF
+    bias = jnp.where(
+        seen, 0.1 * jax.random.normal(keys[3], seen.shape), fa.NEG_INF
+    )[:, None].astype(jnp.float32)
+    visible = seen.reshape(G, Lq // bq, bq, Lk // bk, bk).any(axis=(2, 4))
+    assert not visible.all()  # the map has something to skip
+    block_map = fa.block_map(visible)
+
+    def loss(q, k, v, bias, block_map):
+        out = fa.flash_attention(
+            q, k, v, bias=bias, sm_scale=D ** -0.5, block_q=bq, block_k=bk,
+            block_map=block_map,
+        )
+        return jnp.sum(out * w), out
+
+    if case == "bias-gradient":
+        with pytest.raises(fa.KernelGeometryError, match="constant bias"):
+            jax.grad(loss, argnums=3, has_aux=True)(q, k, v, bias, block_map)
+        return
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+    (_, out_dense), g_dense = grad(q, k, v, bias, None)
+    (_, out_mapped), g_mapped = jax.jit(grad)(q, k, v, bias, block_map)
+    assert np.array_equal(np.asarray(out_mapped), np.asarray(out_dense))
+    for name, a, b in zip(("dq", "dk", "dv"), g_mapped, g_dense):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    assert float(jnp.abs(g_dense[1]).max()) > 0
+
+    if case == "grouped":
+        assert not np.array_equal(visible[0], visible[1])
+    if case == "single-visit":
+        assert block_map.kv_counts[0, 1] == 1 and not visible[0, 1, 1:].any()
+    if case == "unseen-key-block":
+        assert block_map.q_counts[0, 2] == 0
+        # the call before left non-zero dk, dv there (no mask at all); on a
+        # chip a kernel that skipped the write would hand those back
+        full = jax.grad(
+            lambda k, v: jnp.sum(fa.flash_attention(
+                q, k, v, sm_scale=D ** -0.5, block_q=bq, block_k=bk) * w),
+            argnums=(0, 1),
+        )(k, v)
+        assert all(float(jnp.abs(g[:, :, 256:384]).min()) > 0 for g in full)
+        for g in g_mapped[1:]:
+            assert not np.asarray(g[:, :, 256:384]).any()
+            assert np.asarray(g[:, :, :256]).any()
+
+
+def _unpacked(items):
+    """[(group, row, other, first, last, live)] of a map's packed list."""
+    return [
+        (int(i) >> 20 & 255, int(i) >> 10 & 1023, int(i) & 1023,
+         bool(i & fa._FIRST), bool(i & fa._LAST), bool(i & fa._LIVE))
+        for i in items
+    ]
+
+
+def test_block_map_lists_visits_both_ways():
+    """The map's two readings of one relation, each one flat list over all
+    groups and rows (a row with no visit keeps one dead item), and the
+    shapes it is held to."""
+    visible = np.array([[[1, 0, 0, 0], [1, 1, 0, 0]],
+                        [[0, 0, 0, 0], [1, 0, 1, 0]]], bool)
+    m = fa.block_map(visible)
+    assert m.kv_items.dtype == np.int32 and m.q_counts.dtype == np.int32
+    assert m.kv_counts.tolist() == [[1, 2], [0, 2]]
+    assert _unpacked(m.kv_items) == [
+        (0, 0, 0, True, True, True),
+        (0, 1, 0, True, False, True), (0, 1, 1, False, True, True),
+        (1, 0, 0, True, True, False),   # no visit: initialised and written
+        (1, 1, 0, True, False, True), (1, 1, 2, False, True, True),
+    ]
+    assert m.q_counts.tolist() == [[2, 1, 0, 0], [1, 0, 1, 0]]
+    assert _unpacked(m.q_items) == [
+        (0, 0, 0, True, False, True), (0, 0, 1, False, True, True),
+        (0, 1, 1, True, True, True),
+        (0, 2, 0, True, True, False), (0, 3, 0, True, True, False),
+        (1, 0, 1, True, True, True), (1, 1, 0, True, True, False),
+        (1, 2, 1, True, True, True), (1, 3, 0, True, True, False),
+    ]
+    # every live item is a visible block, each once
+    live = {(g, r, o) for g, r, o, _, _, alive in _unpacked(m.kv_items) if alive}
+    assert live == set(zip(*np.nonzero(visible)))
+    assert len(fa.block_map(np.zeros((1, 2, 3), bool)).kv_items) == 2
+    with pytest.raises(fa.KernelGeometryError, match="packs at most"):
+        fa.block_map(np.zeros((1, 1, 1025), bool))
+    q = jnp.zeros((2, 1, 256, 32), jnp.float32)
+    kv = jnp.zeros((2, 1, 512, 32), jnp.float32)
+    with pytest.raises(fa.KernelGeometryError, match="block_map"):
+        fa.block_map(np.zeros((2, 3), bool))
+    with pytest.raises(fa.KernelGeometryError, match="does not fit"):
+        # the map is of (2, 4) blocks; (128, 256) blocks make (2, 2)
+        fa.flash_attention(q, kv, kv, block_q=128, block_k=256, block_map=m)
+    with pytest.raises(fa.KernelGeometryError, match="does not fit"):
+        fa.flash_attention(  # three groups do not divide two batch rows
+            q, kv, kv, block_q=128, block_k=128,
+            block_map=fa.block_map(np.ones((3, 2, 4), bool)),
+        )

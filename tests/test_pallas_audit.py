@@ -118,6 +118,55 @@ def test_bounds_clean_kernel_passes(tmp_path):
     assert findings == {}
 
 
+_PREFETCH_MAPPED = """
+        import numpy as np
+
+        def _kernel(ids_ref, x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+
+        def _gather(ids, jit=False):
+            x = jnp.zeros((512, 256), jnp.float32)
+
+            def run(ids):
+                return _pallas_call(
+                    _kernel,
+                    name="fx",
+                    grid_spec=pltpu.PrefetchScalarGridSpec(
+                        num_scalar_prefetch=1,
+                        grid=(3,),
+                        in_specs=[pl.BlockSpec(
+                            (128, 256), lambda i, ids_ref: (ids_ref[i], 0))],
+                        out_specs=pl.BlockSpec(
+                            (128, 256), lambda i, ids_ref: (i, 0)),
+                    ),
+                    out_shape=jax.ShapeDtypeStruct((384, 256), jnp.float32),
+                )(ids, x)
+
+            return (jax.jit(run) if jit else run)(ids)
+"""
+
+
+@pytest.mark.parametrize("ids,jit,rule,says", [
+    ("[3, 0, 2]", False, None, None),
+    # the blocks a map can NAME are checked, not only computed indices
+    ("[3, 0, 4]", False, pa.RULE_BOUNDS, "outside extent 512"),
+    # a traced map has no values to evaluate: opaque, not a silent pass
+    ("[3, 0, 2]", True, pa.RULE_COVERAGE, "geometry not enumerable"),
+])
+def test_bounds_evaluates_maps_that_read_scalar_prefetch(
+    tmp_path, ids, jit, rule, says
+):
+    findings = audit_fixture(tmp_path, "fx_prefetch_map", _PREFETCH_MAPPED + f"""
+        @audit_case("fx-prefetch-map")
+        def _case():
+            _gather(np.asarray({ids}, np.int32), jit={jit})
+    """)
+    if rule is None:
+        assert findings == {}
+    else:
+        assert says in findings[rule][0], findings
+
+
 # ---------------------------------------------------------------------------
 # (b) tiling legality
 # ---------------------------------------------------------------------------
@@ -541,6 +590,41 @@ def test_tree_audit_is_clean():
     # multi-kernel families (flash fwd + dq/dkv/dbias) all reported in
     assert result.captures >= 11
     assert result.cases >= 8
+
+
+def test_the_mapped_flash_case_names_only_the_blocks_of_its_map():
+    """``flash-attention-block-map``: the three walked kernels capture, and
+    enumerating the key-side index map (it reads the map's ids from scalar
+    prefetch) names, for the first window, no summary block, and for the
+    last one all of them; the key-major kernel still writes every block."""
+    from unicore_tpu.analysis import kernel_geometry as kg
+
+    path = os.path.realpath("unicore_tpu/ops/flash_attention.py")
+    captures, errors = pa.run_audit_cases({path})
+    assert not errors, errors
+    caps = {
+        c.name: c for c in captures if c.case == "flash-attention-block-map"
+    }
+    assert set(caps) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    for cap in caps.values():
+        assert kg.check_block_bounds(cap) == [], cap.name
+
+    def named(cap, operand, batch_row):
+        use = cap.inputs()[operand]
+        idx = [use.index_map(*pid) for pid in kg._grid_points(cap.grid)]
+        return {i[2] for i in idx if i[0] == batch_row}
+
+    for name in ("flash_fwd", "flash_bwd_dq"):
+        # the last axis walks the map's 20 visits, all groups in one list
+        assert caps[name].grid == (1, 2, 1, 20)
+        assert named(caps[name], 1, 0) == {0, 1}       # k: own keys only
+        assert named(caps[name], 1, 3) == {0, 1, 2, 3}
+    # key-major: four summary blocks (two of the first window) have no visitor and
+    # keep a dead item each, which names query block 0
+    dkv = caps["flash_bwd_dkv"]
+    assert dkv.grid == (1, 2, 1, 24)
+    assert named(dkv, 0, 0) == {0, 1}
+    assert named(dkv, 1, 0) == {0, 1, 2, 3}            # every dk block written
 
 
 # ---------------------------------------------------------------------------

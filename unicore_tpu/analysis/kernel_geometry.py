@@ -6,10 +6,14 @@ output, and scratch buffer.  Index maps are tiny pure lambdas, so rather
 than symbolically reasoning about them this module **concretely enumerates
 the grid**: every index map is executed at every program id (capped; see
 ``GRID_ENUM_CAP``) and the resulting block origins are checked against the
-operand extents.  The constants the checks price against (``LANE``,
-``SUBLANE_BY_ITEMSIZE``, ``VMEM_BUDGET``) are imported from
-``ops/_pallas.py`` — the SAME values the dispatch gates use, so the
-auditor and the runtime can never disagree about what a legal block is.
+operand extents.  A map that READS a scalar-prefetch operand (a block map's
+ids: ``ops/flash_attention.py``) is handed the values the audit case passed
+(``pallas_audit.py`` closes each captured map over them), so the blocks a
+map can name are checked like any computed index.  The constants the
+checks price against (``LANE``, ``SUBLANE_BY_ITEMSIZE``, ``VMEM_BUDGET``)
+are imported from ``ops/_pallas.py`` — the SAME values the dispatch gates
+use, so the auditor and the runtime can never disagree about what a legal
+block is.
 
 Checks implemented here (findings are plain strings; ``pallas_audit.py``
 attaches them to the call site as lint violations):

@@ -435,14 +435,15 @@ def run_audit_cases(kernel_paths: Set[str]):
         def runner(*operands):
             uses: List[BlockUse] = []
             arrays = operands[nsp:]
+            prefetch = _concrete(operands[:nsp])
             for i, (spec, arr) in enumerate(zip(in_specs, arrays)):
                 uses.append(_block_use("in", i, spec, tuple(arr.shape),
-                                       arr.dtype))
+                                       arr.dtype, prefetch))
             for i, (spec, sd) in enumerate(
                 zip(out_specs_list, out_shapes_list)
             ):
                 uses.append(_block_use("out", i, spec, tuple(sd.shape),
-                                       sd.dtype))
+                                       sd.dtype, prefetch))
             for i, s in enumerate(scratch):
                 shape = tuple(int(d) for d in s.shape)
                 uses.append(BlockUse("scratch", i, shape, s.dtype, shape))
@@ -458,7 +459,18 @@ def run_audit_cases(kernel_paths: Set[str]):
 
         return runner
 
-    def _block_use(kind, index, spec, array_shape, dtype):
+    def _concrete(prefetch):
+        """The scalar-prefetch operands as numpy arrays, so an index map
+        that READS one (a block map: ``ids_ref[...]``) is evaluated on the
+        values the case passed; ``()`` when any is a tracer (a case run
+        under ``jit``), which leaves such a map to fail as opaque."""
+        import numpy as np
+
+        if any(isinstance(x, jax.core.Tracer) for x in prefetch):
+            return ()
+        return tuple(np.asarray(x) for x in prefetch)
+
+    def _block_use(kind, index, spec, array_shape, dtype, prefetch):
         if spec is None or getattr(spec, "block_shape", None) is None:
             return BlockUse(kind, index, array_shape, dtype, array_shape,
                             None)
@@ -466,7 +478,11 @@ def run_audit_cases(kernel_paths: Set[str]):
             int(b) if b is not None else int(d)
             for b, d in zip(spec.block_shape, array_shape)
         )
-        imap = spec.index_map if None not in spec.block_shape else None
+        imap = None
+        if None not in spec.block_shape:
+            # Pallas hands an index map the program ids, then the prefetch refs
+            def imap(*pid, _map=spec.index_map):
+                return _map(*pid, *prefetch)
         return BlockUse(kind, index, blk, dtype, array_shape, imap)
 
     saved_gates = []

@@ -21,18 +21,25 @@ causal attention.
 keys ; every chunk summary of the row, padded to the kernel's tile]`` under
 a grouped bias ``(W, 1, window, window + summaries)`` made of iotas: the
 causal triangle on the left, "the chunk's window is before ``w``" on the
-right.  The bias is a constant, so no bias gradient is computed.  That
-form computes about twice the keys a query may see (the upper triangle and
-the later windows' summaries are scored and masked); a kernel that skips
-masked blocks is ROADMAP S6, and :func:`key_counts` is the counter that
-sizes it.  Off the TPU (and outside interpret mode) the same operands go
-through XLA's own softmax.
+right.  The bias is a constant, so no bias gradient is computed.  Scored
+whole, that form computes about twice the keys a query may see (the upper
+triangle and the later windows' summaries are scored and masked), so the
+kernels also get a BLOCK MAP (:func:`visible_blocks`, from the bias's own
+predicate; ``flash_attention.block_map``): of a window's ``(block_q,
+block_k)`` blocks they visit those that hold a visible key and skip the
+ones that are ``NEG`` throughout, which changes no bit of the output or of
+a gradient.  At ``(256, 512)`` and 16 windows of 2,048 that is 608 of
+1,024 blocks, 1.23 times the visible keys (the partly masked blocks are
+still scored whole); :func:`key_counts` counts both from the same map.
+Off the TPU (and outside interpret mode) the same operands go through XLA's
+own softmax.
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from unicore_tpu.ops._pallas import LANE, interpret_enabled
+from unicore_tpu.ops._pallas import LANE, interpret_enabled, pick_block
 from unicore_tpu.platform_utils import on_tpu
 
 NEG = -1e30  # big finite, as the kernels' own mask value
@@ -75,11 +82,57 @@ def visibility_bias(n_windows, window, chunks_per_window, n_summaries, dtype):
     w = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 3)
+    seen = _seen(w, row, col, window, chunks_per_window)
+    return jnp.where(seen, 0.0, NEG).astype(dtype)
+
+
+def _seen(w, row, col, window, chunks_per_window):
+    """Whether query ``row`` of window ``w`` may see key column ``col`` of
+    :func:`eva_agg`'s layout: THE predicate, of the bias (iotas) and of the
+    block map (numpy corners) alike."""
     local = col <= row
     # (col - window) // chunks_per_window < w, without a division
     summary = col - window < w * chunks_per_window
-    seen = jnp.where(col < window, local, summary)
-    return jnp.where(seen, 0.0, NEG).astype(dtype)
+    return ((col < window) & local) | ((col >= window) & summary)
+
+
+def kernel_blocks(window, n_keys):
+    """The ``(block_q, block_k)`` :func:`eva_agg` runs the flash kernels at:
+    their defaults, cut to what divides a window and its keys.  A window
+    the kernels' tile does not divide (:func:`uses_kernel`) is one block."""
+    if window % LANE:
+        return window, n_keys
+    return pick_block(window, 256), pick_block(n_keys, 512)
+
+
+def visible_blocks(length, window, chunk, block_q, block_k):
+    """``(W, window // block_q, keys // block_k)`` bool, numpy, from shapes:
+    whether the slab of :func:`visibility_bias` (the kernel form's, its
+    summaries padded to the tile) that a block covers holds a key some query
+    of the block may see, i.e. is not ``NEG`` throughout.  :func:`_seen` is
+    monotone, so a slab's best corner decides: its last query against its
+    first own key, or against its first summary."""
+    W, cpw = length // window, window // chunk
+    n_keys = window + _padded_summaries(length, chunk)
+    w = np.arange(W)[:, None, None]
+    last_row = (np.arange(window // block_q)[None, :, None] + 1) * block_q - 1
+    col = np.arange(n_keys // block_k)[None, None, :] * block_k
+    summary = np.maximum(col, window)  # the slab's first summary, if it has one
+    return _seen(w, last_row, col, window, cpw) | (
+        (summary < col + block_k) & _seen(w, last_row, summary, window, cpw)
+    )
+
+
+def _padded_summaries(length, chunk):
+    n_sum = length // chunk
+    return n_sum + (-n_sum) % LANE
+
+
+def kernel_map(length, window, chunk):
+    """``(block_q, block_k, visible)`` of :func:`eva_agg`'s kernel form:
+    the one place the kernels' map and :func:`key_counts` both come from."""
+    bq, bk = kernel_blocks(window, window + _padded_summaries(length, chunk))
+    return bq, bk, visible_blocks(length, window, chunk, bq, bk)
 
 
 def uses_kernel(window, head_dim, dtype):
@@ -121,10 +174,17 @@ def eva_agg(q, k, v, k_sum, v_sum, window, chunk, scale):
         kw, vw = with_summaries(k, k_sum), with_summaries(v, v_sum)
         bias = visibility_bias(W, window, cpw, n_sum + pad, q.dtype)
         if kernel:
-            from unicore_tpu.ops.flash_attention import flash_attention
+            from unicore_tpu.ops.flash_attention import (
+                block_map,
+                flash_attention,
+            )
 
-            # batch row w * B + b reads bias group (w * B + b) // B = w
-            o = flash_attention(qw, kw, vw, bias=bias, sm_scale=scale)
+            # batch row w * B + b reads bias and map group (w * B + b) // B = w
+            bq, bk, visible = kernel_map(L, window, chunk)
+            o = flash_attention(
+                qw, kw, vw, bias=bias, sm_scale=scale, block_q=bq, block_k=bk,
+                block_map=block_map(visible),
+            )
         else:
             s = jnp.einsum("nhqd,nhkd->nhqk", qw, kw,
                            preferred_element_type=jnp.float32) * scale
@@ -138,14 +198,14 @@ def eva_agg(q, k, v, k_sum, v_sum, window, chunk, scale):
 
 def key_counts(length, window, chunk):
     """Per row and head, summed over the row's queries: the keys
-    :func:`eva_agg`'s flash form scores (``computed``: every window against
-    its own keys and all of the row's summaries, padded to the kernel's
-    tile) and the keys a query may see (``visible``: its window's keys up
-    to itself and the summaries of the windows before).  From shapes."""
+    :func:`eva_agg`'s flash form scores (``computed``: the blocks of the
+    map the kernels get, :func:`kernel_map`,
+    each scored whole) and the keys a query may see (``visible``: its
+    window's keys up to itself and the summaries of the windows before).
+    From shapes."""
     W, cpw = length // window, window // chunk
-    n_sum = length // chunk
-    n_sum += (-n_sum) % LANE
-    computed = length * (window + n_sum)
+    bq, bk, blocks = kernel_map(length, window, chunk)
+    computed = int(blocks.sum()) * bq * bk
     visible = W * window * (window + 1) // 2 + window * cpw * W * (W - 1) // 2
     return {"computed": computed, "visible": visible, "windows": W,
             "chunks": length // chunk}

@@ -26,16 +26,27 @@ Capabilities (superset of the reference kernel's semantics):
   (softmax_dropout_kernel.cu:60-68)
 - backward recomputes probabilities from the saved (out, logsumexp), i.e.
   activation memory is O(L) per head
+- a static BLOCK MAP (:func:`block_map`): which key blocks each query block
+  may see, per bias group.  With one, the inner grid axis of every kernel
+  walks the map's VISITS — one flat list of (group, block, block) over all
+  groups, so no step is idle — instead of all blocks of the other sequence
+  axis: the list rides in scalar prefetch and the index maps read a step's
+  blocks from it.  A visited block computes what it computed without the
+  map, bias and all, so where every skipped block is ``NEG_INF``
+  throughout and each query's first visited block holds a key it sees,
+  outputs and gradients are bit for bit the unmapped call's
+  (docs/performance.md, "The block map")
 
 Softmax statistics are fp32 regardless of input dtype; the p @ v matmul runs
 in the input dtype on the MXU with fp32 accumulation.
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -87,64 +98,241 @@ def _keep_mask(shape, dropout_rate):
 
 
 # ---------------------------------------------------------------------------
+# the block map: which blocks a kernel's inner grid axis visits
+# ---------------------------------------------------------------------------
+
+#: one visit, packed in an int32: the key (or query) block it names, the
+#: block of the kernel's outer sequence axis it belongs to, its map group,
+#: whether it is its row's first / last, and whether it does any work
+_BLOCK_BITS, _GROUP_BITS = 10, 8
+_ROW_SHIFT, _GROUP_SHIFT = _BLOCK_BITS, 2 * _BLOCK_BITS
+_FIRST, _LAST, _LIVE = (1 << (_GROUP_SHIFT + _GROUP_BITS + i) for i in range(3))
+
+
+class BlockMap(NamedTuple):
+    """What the walked kernels visit.  ``kv_items`` lists, for every map
+    group ``g`` (batch row ``b`` reads group ``b // (B / G)``, the
+    grouped-bias contract) and query block ``iq`` in turn, the key blocks
+    ``iq`` visits, ascending: one packed int32 a visit (:data:`_FIRST` ...).
+    ``q_items`` is the same relation listed by key block, for the key-major
+    ``flash_bwd_dkv``.  A row with NO visit keeps one dead item (first and
+    last, not live), so its output block is still initialised and written.
+    ``kv_counts`` ``(G, nq)`` / ``q_counts`` ``(G, nk)`` are the visits of
+    each row; the kernels read the lists only.  Made by :func:`block_map`."""
+
+    kv_items: np.ndarray   # (visits + query blocks without one,)
+    kv_counts: np.ndarray  # (G, nq)
+    q_items: np.ndarray    # (visits + key blocks without one,)
+    q_counts: np.ndarray   # (G, nk)
+
+
+def block_map(visible) -> BlockMap:
+    """The :class:`BlockMap` of ``visible`` ``(G, nq, nk)`` bool: whether
+    query block ``iq`` of group ``g`` holds a query that may see a key of
+    key block ``ik``.  Host-side numpy: the map is a property of the mask,
+    not of the data, so a jitted caller hands the kernels a constant."""
+    visible = np.asarray(visible, bool)
+    if visible.ndim != 3:
+        raise KernelGeometryError(
+            f"block_map takes (groups, query blocks, key blocks), got "
+            f"shape {visible.shape}"
+        )
+    G, nq, nk = visible.shape
+    if G > 1 << _GROUP_BITS or max(nq, nk) > 1 << _BLOCK_BITS:
+        raise KernelGeometryError(
+            f"block_map packs at most {1 << _GROUP_BITS} groups of "
+            f"{1 << _BLOCK_BITS} blocks a side, got {visible.shape}"
+        )
+
+    def items(vis):
+        counts = vis.sum(-1)
+        # a row without a visit keeps one dead item that names block 0
+        listed = vis.copy()
+        listed[..., 0] |= counts == 0
+        g, row, other = np.nonzero(listed)  # sorted by (g, row, other)
+        new_row = np.ones(g.size + 1, bool)
+        new_row[1:-1] = (g[1:] != g[:-1]) | (row[1:] != row[:-1])
+        packed = (
+            other | row << _ROW_SHIFT | g << _GROUP_SHIFT
+            | new_row[:-1] * _FIRST | new_row[1:] * _LAST
+            | vis[g, row, other] * _LIVE
+        )
+        return packed.astype(np.int32), counts.astype(np.int32)
+
+    return BlockMap(*items(visible), *items(visible.transpose(0, 2, 1)))
+
+
+class _Walk(NamedTuple):
+    """How a kernel's grid finds its blocks (:func:`_walk`).  The grid is
+    ``(grid[0], H, grid[1], grid[2])``; at program ids ``(b, h, row, t)``,
+    ``at(b, row, t, pre)`` is the ``(batch row, block of the outer sequence
+    axis, block of the other one)`` the step works on, ``first(t, pre)`` /
+    ``last(t, pre)`` whether it is the first / last of its output block and
+    ``live(t, pre)`` whether it does work (``live`` is None without a map:
+    every step does); ``pre`` are the scalar-prefetch refs ``(seed,
+    items)``, so index maps and kernel bodies share the one expression.
+    ``operands`` follow the seed."""
+
+    grid: tuple
+    at: object
+    first: object
+    last: object
+    live: object
+    operands: tuple
+
+
+def _walk(items, counts, B, rows, n_other):
+    """The :class:`_Walk` of a kernel whose outer sequence axis has ``rows``
+    blocks and the other ``n_other``.  Without a map the grid is ``(B,
+    rows, n_other)`` and the ids are the blocks.  With one, the last axis
+    walks the map's flat list of visits, over all groups and rows (no idle
+    step: a list padded per row spent 0.4 us on each of its dead steps, a
+    tenth of EVA's kernel time), so the grid is ``(batch rows a group, 1,
+    visits)`` and the list is the one operand."""
+    if items is None:
+        return _Walk(
+            (B, rows, n_other),
+            at=lambda b, row, t, pre: (b, row, t),
+            first=lambda t, pre: t == 0,
+            last=lambda t, pre: t == n_other - 1,
+            live=None, operands=(),
+        )
+    per_group = B // counts.shape[0]
+    mask = (1 << _BLOCK_BITS) - 1
+
+    def at(b, row, t, pre):
+        item = pre[1][t]
+        group = (item >> _GROUP_SHIFT) & ((1 << _GROUP_BITS) - 1)
+        return (group * per_group + b, (item >> _ROW_SHIFT) & mask,
+                item & mask)
+
+    def flag(bit):
+        return lambda t, pre: (pre[1][t] & bit) != 0
+
+    return _Walk(
+        (per_group, 1, items.shape[0]), at, flag(_FIRST), flag(_LAST),
+        flag(_LIVE), (jnp.asarray(items),),
+    )
+
+
+def _index_maps(B, at, kv_major):
+    """``(qi, ki, maski, biasi)``: the index maps of ``(1, 1, BQ, .)``
+    query-side blocks, ``(1, 1, BK, .)`` key-side blocks, the ``(1, 1, BK)``
+    padding mask and (``biasi(Bb, Hb)``) the grouped bias, for a grid whose
+    third axis walks query blocks or, ``kv_major``, key blocks; ``at`` as
+    :func:`_walk` gives it."""
+    def where(b, row, t, pre):  # -> (batch row, iq, ik)
+        b, row, other = at(b, row, t, pre)
+        return (b, other, row) if kv_major else (b, row, other)
+
+    def qi(b, h, row, t, *pre):
+        b, iq, _ = where(b, row, t, pre)
+        return (b, h, iq, 0)
+
+    def ki(b, h, row, t, *pre):
+        b, _, ik = where(b, row, t, pre)
+        return (b, h, ik, 0)
+
+    def maski(b, h, row, t, *pre):
+        b, _, ik = where(b, row, t, pre)
+        return (b, 0, ik)
+
+    def biasi(Bb, Hb):
+        """Grouped-broadcast bias indexing: batch b reads bias group
+        b // (B/Bb).
+
+        Bb == 1 (one shared bias) and Bb == B (per-batch bias) are the
+        degenerate cases; 1 < Bb < B is the Evoformer/Uni-Fold layout, where
+        consecutive runs of B/Bb flattened batches (MSA rows of one sequence,
+        lead rows of one pair matrix) share a pair-bias slab — the same
+        broadcast contract as the reference kernel
+        (/root/reference/csrc/softmax_dropout/interface.cpp:37-48).
+        """
+        gb = B // Bb
+
+        def idx(b, h, row, t, *pre):
+            b, iq, ik = where(b, row, t, pre)
+            return (b // gb, h if Hb > 1 else 0, iq, ik)
+
+        return idx
+
+    return qi, ki, maski, biasi
+
+
+def _step(walk, t, pre, body):
+    """Run a grid step's ``body``: always without a map, with one unless
+    the step is a row's dead item."""
+    if walk.live is None:
+        body()
+    else:
+        pl.when(walk.live(t, pre))(body)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(
-    seed_ref,
+    pre,
     q_ref, k_ref, v_ref, bias_ref, mask_ref,
     o_ref, lse_ref,
     m_s, l_s, acc_s,
-    *, sm_scale, dropout_rate, nk, has_bias, has_mask,
+    *, sm_scale, dropout_rate, walk, has_bias, has_mask,
 ):
-    b, h, iq, ik = (pl.program_id(i) for i in range(4))
+    b, h, iq, t = (pl.program_id(i) for i in range(4))
+    b, iq, ik = walk.at(b, iq, t, pre)
 
-    @pl.when(ik == 0)
+    @pl.when(walk.first(t, pre))
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[0, 0]  # (BQ, D)
-    k = k_ref[0, 0]  # (BK, D)
-    v = v_ref[0, 0]  # (BK, D)
+    def _visit():
+        q = q_ref[0, 0]  # (BQ, D)
+        k = k_ref[0, 0]  # (BK, D)
+        v = v_ref[0, 0]  # (BK, D)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    if has_bias:
-        s = s + bias_ref[0, 0].astype(jnp.float32)
-    if has_mask:
-        kv_mask = mask_ref[0] != 0  # (1, BK) True = masked out
-        s = jnp.where(kv_mask, NEG_INF, s)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        s = s * sm_scale
+        if has_bias:
+            s = s + bias_ref[0, 0].astype(jnp.float32)
+        if has_mask:
+            kv_mask = mask_ref[0] != 0  # (1, BK) True = masked out
+            s = jnp.where(kv_mask, NEG_INF, s)
 
-    m_prev = m_s[:, :1]  # (BQ, 1)
-    l_prev = l_s[:, :1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_next = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_next)
-    if has_mask:
-        p = jnp.where(kv_mask, 0.0, p)  # exact zero for fully-masked rows
-    corr = jnp.exp(m_prev - m_next)
-    l_next = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        m_prev = m_s[:, :1]  # (BQ, 1)
+        l_prev = l_s[:, :1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_next = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_next)
+        if has_mask:
+            p = jnp.where(kv_mask, 0.0, p)  # exact zero for fully-masked rows
+        corr = jnp.exp(m_prev - m_next)
+        l_next = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
 
-    if dropout_rate > 0.0:
-        _seed_block(seed_ref, b, h, iq, ik)
-        keep = _keep_mask(p.shape, dropout_rate)
-        p_use = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-    else:
-        p_use = p
+        if dropout_rate > 0.0:
+            _seed_block(pre[0], b, h, iq, ik)
+            keep = _keep_mask(p.shape, dropout_rate)
+            p_use = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+        else:
+            p_use = p
 
-    pv = jax.lax.dot_general(
-        p_use.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_s[...] = acc_s[...] * corr + pv
-    m_s[...] = jnp.broadcast_to(m_next, m_s.shape)
-    l_s[...] = jnp.broadcast_to(l_next, l_s.shape)
+        pv = jax.lax.dot_general(
+            p_use.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_s[...] = acc_s[...] * corr + pv
+        m_s[...] = jnp.broadcast_to(m_next, m_s.shape)
+        l_s[...] = jnp.broadcast_to(l_next, l_s.shape)
 
-    @pl.when(ik == nk - 1)
+    _step(walk, t, pre, _visit)
+
+    # a query block with no visit at all writes zeros, as a row whose keys
+    # are all padding does
+    @pl.when(walk.last(t, pre))
     def _finish():
         l = l_s[:, :1]
         inv_l = jnp.where(l > 0.0, 1.0 / l, 0.0)
@@ -153,25 +341,8 @@ def _fwd_kernel(
         lse_ref[0, 0] = lse.astype(jnp.float32)  # (BQ, 1)
 
 
-def _bias_index(B, Bb, Hb):
-    """Grouped-broadcast bias indexing: batch b reads bias group b // (B/Bb).
-
-    Bb == 1 (one shared bias) and Bb == B (per-batch bias) are the
-    degenerate cases; 1 < Bb < B is the Evoformer/Uni-Fold layout, where
-    consecutive runs of B/Bb flattened batches (MSA rows of one sequence,
-    lead rows of one pair matrix) share a pair-bias slab — the same
-    broadcast contract as the reference kernel
-    (/root/reference/csrc/softmax_dropout/interface.cpp:37-48).
-    """
-    gb = B // Bb
-
-    def idx(b, h, iq, ik, *_):
-        return (b // gb, h if Hb > 1 else 0, iq, ik)
-
-    return idx
-
-
-def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q, block_k):
+def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
+         block_k, block_map=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     BQ, BK = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
@@ -179,6 +350,9 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q, block_k)
 
     has_bias = bias is not None
     has_mask = kv_mask is not None
+    kv_items, kv_counts = (block_map or (None,) * 4)[:2]
+    walk = _walk(kv_items, kv_counts, B, nq, nk)
+    visits = walk.operands
 
     # refuse here (rather than let Mosaic OOM on-device) when one grid
     # step's resident blocks bust the shared budget — the --kernels
@@ -198,34 +372,33 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q, block_k)
          ((BQ, D), jnp.float32)],
     )
 
+    qi, ki, maski, biasi = _index_maps(B, walk.at, kv_major=False)
     in_specs = [
-        pl.BlockSpec((1, 1, BQ, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, BK, D), lambda b, h, iq, ik, *_: (b, h, ik, 0)),
-        pl.BlockSpec((1, 1, BK, D), lambda b, h, iq, ik, *_: (b, h, ik, 0)),
+        pl.BlockSpec((1, 1, BQ, D), qi),
+        pl.BlockSpec((1, 1, BK, D), ki),
+        pl.BlockSpec((1, 1, BK, D), ki),
     ]
     inputs = [q, k, v]
     if has_bias:
-        Bb, Hb = bias.shape[0], bias.shape[1]
         in_specs.append(
-            pl.BlockSpec((1, 1, BQ, BK), _bias_index(B, Bb, Hb))
+            pl.BlockSpec((1, 1, BQ, BK), biasi(bias.shape[0], bias.shape[1]))
         )
         inputs.append(bias)
     if has_mask:
-        in_specs.append(
-            pl.BlockSpec((1, 1, BK), lambda b, h, iq, ik, *_: (b, 0, ik))
-        )
+        in_specs.append(pl.BlockSpec((1, 1, BK), maski))
         inputs.append(kv_mask)
 
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale,
         dropout_rate=dropout_rate,
-        nk=nk,
+        walk=walk,
         has_bias=has_bias,
         has_mask=has_mask,
     )
 
-    def wrapped(seed_ref, *refs):
+    def wrapped(*refs):
+        pre, refs = refs[:1 + len(visits)], refs[1 + len(visits):]
         n_in = len(inputs)
         in_refs = refs[:n_in]
         out_refs = refs[n_in:n_in + 2]
@@ -235,21 +408,19 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q, block_k)
         bias_ref = in_refs[i] if has_bias else None
         i += int(has_bias)
         mask_ref = in_refs[i] if has_mask else None
-        kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, mask_ref, *out_refs,
+        kernel(pre, q_ref, k_ref, v_ref, bias_ref, mask_ref, *out_refs,
                *scratch)
 
     out, lse = _pallas_call(
         wrapped,
         name="flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H, nq, nk),
+            num_scalar_prefetch=1 + len(visits),
+            grid=(walk.grid[0], H, *walk.grid[1:]),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, 1, BQ, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-                pl.BlockSpec(
-                    (1, 1, BQ, 1), lambda b, h, iq, ik, *_: (b, h, iq, 0)
-                ),
+                pl.BlockSpec((1, 1, BQ, D), qi),
+                pl.BlockSpec((1, 1, BQ, 1), qi),
             ],
             scratch_shapes=[
                 pltpu.VMEM((BQ, 128), jnp.float32),
@@ -261,7 +432,7 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q, block_k)
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((B, H, Lq, 1), jnp.float32),
         ],
-    )(seed, *inputs)
+    )(seed, *visits, *inputs)
     return out, lse
 
 
@@ -310,76 +481,90 @@ def _ds_block(seed_ref, p, kv_mask, do_ref, v_ref, di_ref, dropout_rate,
 
 
 def _dq_kernel(
-    seed_ref,
+    pre,
     q_ref, k_ref, v_ref, bias_ref, mask_ref, lse_ref, di_ref, do_ref,
     dq_ref,
     dq_s,
-    *, sm_scale, dropout_rate, nk, has_bias, has_mask,
+    *, sm_scale, dropout_rate, walk, has_bias, has_mask,
 ):
-    b, h, iq, ik = (pl.program_id(i) for i in range(4))
+    b, h, iq, t = (pl.program_id(i) for i in range(4))
+    b, iq, ik = walk.at(b, iq, t, pre)
 
-    @pl.when(ik == 0)
+    @pl.when(walk.first(t, pre))
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    p, kv_mask = _recompute_p(
-        q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale, has_bias, has_mask
-    )
-    ds = _ds_block(
-        seed_ref, p, kv_mask, do_ref, v_ref, di_ref, dropout_rate, b, h, iq, ik
-    )
-    k = k_ref[0, 0]
-    dq_s[...] += sm_scale * jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    def _visit():
+        p, kv_mask = _recompute_p(
+            q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale, has_bias,
+            has_mask
+        )
+        ds = _ds_block(
+            pre[0], p, kv_mask, do_ref, v_ref, di_ref, dropout_rate,
+            b, h, iq, ik
+        )
+        k = k_ref[0, 0]
+        dq_s[...] += sm_scale * jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(ik == nk - 1)
+    _step(walk, t, pre, _visit)
+
+    @pl.when(walk.last(t, pre))
     def _finish():
         dq_ref[0, 0] = dq_s[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
-    seed_ref,
+    pre,
     q_ref, k_ref, v_ref, bias_ref, mask_ref, lse_ref, di_ref, do_ref,
     dk_ref, dv_ref,
     dk_s, dv_s,
-    *, sm_scale, dropout_rate, nq, has_bias, has_mask,
+    *, sm_scale, dropout_rate, walk, has_bias, has_mask,
 ):
-    b, h, ik, iq = (pl.program_id(i) for i in range(4))
+    b, h, ik, t = (pl.program_id(i) for i in range(4))
+    b, ik, iq = walk.at(b, ik, t, pre)
 
-    @pl.when(iq == 0)
+    @pl.when(walk.first(t, pre))
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    p, kv_mask = _recompute_p(
-        q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale, has_bias, has_mask
-    )
+    def _visit():
+        p, kv_mask = _recompute_p(
+            q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale, has_bias,
+            has_mask
+        )
 
-    # dv += dropout(p)^T @ do
-    do = do_ref[0, 0]
-    if dropout_rate > 0.0:
-        _seed_block(seed_ref, b, h, iq, ik)
-        keep = _keep_mask(p.shape, dropout_rate)
-        p_drop = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
-    else:
-        p_drop = p
-    dv_s[...] += jax.lax.dot_general(
-        p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        # dv += dropout(p)^T @ do
+        do = do_ref[0, 0]
+        if dropout_rate > 0.0:
+            _seed_block(pre[0], b, h, iq, ik)
+            keep = _keep_mask(p.shape, dropout_rate)
+            p_drop = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
+        else:
+            p_drop = p
+        dv_s[...] += jax.lax.dot_general(
+            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    ds = _ds_block(
-        seed_ref, p, kv_mask, do_ref, v_ref, di_ref, dropout_rate, b, h, iq, ik
-    )
-    q = q_ref[0, 0]
-    dk_s[...] += sm_scale * jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        ds = _ds_block(
+            pre[0], p, kv_mask, do_ref, v_ref, di_ref, dropout_rate,
+            b, h, iq, ik
+        )
+        q = q_ref[0, 0]
+        dk_s[...] += sm_scale * jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(iq == nq - 1)
+    _step(walk, t, pre, _visit)
+
+    # a key block no query block visits still writes: zeros, not what the
+    # output buffer held
+    @pl.when(walk.last(t, pre))
     def _finish():
         dk_ref[0, 0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
@@ -416,37 +601,14 @@ def _db_kernel(
         db_ref[0, 0] = db_s[...].astype(db_ref.dtype)
 
 
-def _bwd_inputs(q, k, v, bias, kv_mask, lse, di, do, BQ, BK, *, kv_major):
+def _bwd_inputs(q, k, v, bias, kv_mask, lse, di, do, BQ, BK, *, kv_major,
+                at=lambda b, row, t, pre: (b, row, t)):
     """Input arrays + specs shared by the bwd kernels.
 
-    ``kv_major=False``: grid (B, H, nq, nk); True: grid (B, H, nk, nq).
+    ``kv_major=False``: the grid's third axis walks query blocks; True: key
+    blocks; ``at`` as :func:`_walk` gives it (default: no map).
     """
-    B = q.shape[0]
-    if kv_major:
-        qi, ki = (lambda b, h, ik, iq, *_: (b, h, iq, 0)), (
-            lambda b, h, ik, iq, *_: (b, h, ik, 0)
-        )
-        rowi = lambda b, h, ik, iq, *_: (b, h, iq, 0)
-        maski = lambda b, h, ik, iq, *_: (b, 0, ik)
-
-        def bi(Bb, Hb):
-            gb = B // Bb
-            return lambda b, h, ik, iq, *_: (
-                b // gb, h if Hb > 1 else 0, iq, ik
-            )
-    else:
-        qi, ki = (lambda b, h, iq, ik, *_: (b, h, iq, 0)), (
-            lambda b, h, iq, ik, *_: (b, h, ik, 0)
-        )
-        rowi = lambda b, h, iq, ik, *_: (b, h, iq, 0)
-        maski = lambda b, h, iq, ik, *_: (b, 0, ik)
-
-        def bi(Bb, Hb):
-            gb = B // Bb
-            return lambda b, h, iq, ik, *_: (
-                b // gb, h if Hb > 1 else 0, iq, ik
-            )
-
+    qi, ki, maski, biasi = _index_maps(q.shape[0], at, kv_major)
     D = q.shape[-1]
     specs = [
         pl.BlockSpec((1, 1, BQ, D), qi),
@@ -455,14 +617,16 @@ def _bwd_inputs(q, k, v, bias, kv_mask, lse, di, do, BQ, BK, *, kv_major):
     ]
     inputs = [q, k, v]
     if bias is not None:
-        specs.append(pl.BlockSpec((1, 1, BQ, BK), bi(bias.shape[0], bias.shape[1])))
+        specs.append(
+            pl.BlockSpec((1, 1, BQ, BK), biasi(bias.shape[0], bias.shape[1]))
+        )
         inputs.append(bias)
     if kv_mask is not None:
         specs.append(pl.BlockSpec((1, 1, BK), maski))
         inputs.append(kv_mask)
-    specs.append(pl.BlockSpec((1, 1, BQ, 1), rowi))
+    specs.append(pl.BlockSpec((1, 1, BQ, 1), qi))
     inputs.append(lse)
-    specs.append(pl.BlockSpec((1, 1, BQ, 1), rowi))
+    specs.append(pl.BlockSpec((1, 1, BQ, 1), qi))
     inputs.append(di)
     specs.append(pl.BlockSpec((1, 1, BQ, D), qi))
     inputs.append(do)
@@ -487,13 +651,16 @@ def _make_ref_unpacker(has_bias, has_mask, n_outs, n_scratch):
 
 
 def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
-         block_k, out, lse, do):
+         block_k, out, lse, do, block_map=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     BQ, BK = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
     nq, nk = _cdiv(Lq, BQ), _cdiv(Lk, BK)
     has_bias = bias is not None
     has_mask = kv_mask is not None
+    # with a map the bias is a constant (``_constant_bias`` refuses to
+    # differentiate it): the dbias kernel walks every block and is not run
+    want_dbias = has_bias and block_map is None
 
     # same budget refusal as the forward, per backward kernel family
     io_common = [
@@ -515,7 +682,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
         io_common + [((1, 1, BK, D), k.dtype), ((1, 1, BK, D), v.dtype)],
         [((BK, D), jnp.float32), ((BK, D), jnp.float32)],
     )
-    if has_bias:
+    if want_dbias:
         check_vmem_budget(
             "flash_attention bwd dbias",
             io_common + [((1, 1, BQ, BK), jnp.float32)],
@@ -524,50 +691,56 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
 
     di = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                  axis=-1, keepdims=True)
+    kv_items, kv_counts, q_items, q_counts = block_map or (None,) * 4
 
-    # ---- dq: grid (B, H, nq, nk) -------------------------------------
-    inputs, specs = _bwd_inputs(
-        q, k, v, bias, kv_mask, lse, di, do, BQ, BK, kv_major=False
-    )
-    unpack = _make_ref_unpacker(has_bias, has_mask, 1, 1)
-
-    def dq_wrapped(seed_ref, *refs):
-        in_refs, outs, scratch = unpack(refs, len(inputs))
-        _dq_kernel(
-            seed_ref, *in_refs, *outs, *scratch,
-            sm_scale=sm_scale, dropout_rate=dropout_rate, nk=nk,
-            has_bias=has_bias, has_mask=has_mask,
+    def walked(kernel, kv_major, items, counts, rows, n_other, n_outs):
+        """The body, map operands, inputs, specs, output index map and grid
+        of one of the two walked kernels."""
+        walk = _walk(items, counts, B, rows, n_other)
+        visits = walk.operands
+        inputs, specs = _bwd_inputs(
+            q, k, v, bias, kv_mask, lse, di, do, BQ, BK, kv_major=kv_major,
+            at=walk.at,
         )
+        unpack = _make_ref_unpacker(has_bias, has_mask, n_outs, n_outs)
 
+        def wrapped(*refs):
+            pre, refs = refs[:1 + len(visits)], refs[1 + len(visits):]
+            in_refs, outs, scratch = unpack(refs, len(inputs))
+            kernel(
+                pre, *in_refs, *outs, *scratch,
+                sm_scale=sm_scale, dropout_rate=dropout_rate, walk=walk,
+                has_bias=has_bias, has_mask=has_mask,
+            )
+
+        def outi(b, h, row, t, *pre):
+            b, row, _ = walk.at(b, row, t, pre)
+            return (b, h, row, 0)
+
+        return (wrapped, visits, inputs, specs, outi,
+                (walk.grid[0], H, *walk.grid[1:]))
+
+    # ---- dq: the third grid axis walks query blocks ------------------
+    dq_wrapped, visits, inputs, specs, outi, grid = walked(
+        _dq_kernel, False, kv_items, kv_counts, nq, nk, 1
+    )
     dq = _pallas_call(
         dq_wrapped,
         name="flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H, nq, nk),
+            num_scalar_prefetch=1 + len(visits),
+            grid=grid,
             in_specs=specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, BQ, D), lambda b, h, iq, ik, *_: (b, h, iq, 0)),
-            ],
+            out_specs=[pl.BlockSpec((1, 1, BQ, D), outi)],
             scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-    )(seed, *inputs)[0]
+    )(seed, *visits, *inputs)[0]
 
-    # ---- dk, dv: grid (B, H, nk, nq) ---------------------------------
-    inputs, specs = _bwd_inputs(
-        q, k, v, bias, kv_mask, lse, di, do, BQ, BK, kv_major=True
+    # ---- dk, dv: the third grid axis walks key blocks ----------------
+    dkv_wrapped, visits, inputs, specs, outi, grid = walked(
+        _dkv_kernel, True, q_items, q_counts, nk, nq, 2
     )
-    unpack2 = _make_ref_unpacker(has_bias, has_mask, 2, 2)
-
-    def dkv_wrapped(seed_ref, *refs):
-        in_refs, outs, scratch = unpack2(refs, len(inputs))
-        _dkv_kernel(
-            seed_ref, *in_refs, *outs, *scratch,
-            sm_scale=sm_scale, dropout_rate=dropout_rate, nq=nq,
-            has_bias=has_bias, has_mask=has_mask,
-        )
-
     # dkv regenerates the SAME dropout mask the forward applied
     # (recompute-from-counters design, module docstring)
     # lint: shared-prng-stream
@@ -575,12 +748,12 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
         dkv_wrapped,
         name="flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, H, nk, nq),
+            num_scalar_prefetch=1 + len(visits),
+            grid=grid,
             in_specs=specs,
             out_specs=[
-                pl.BlockSpec((1, 1, BK, D), lambda b, h, ik, iq, *_: (b, h, ik, 0)),
-                pl.BlockSpec((1, 1, BK, D), lambda b, h, ik, iq, *_: (b, h, ik, 0)),
+                pl.BlockSpec((1, 1, BK, D), outi),
+                pl.BlockSpec((1, 1, BK, D), outi),
             ],
             scratch_shapes=[
                 pltpu.VMEM((BK, D), jnp.float32),
@@ -591,7 +764,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-    )(seed, *inputs)
+    )(seed, *visits, *inputs)
 
     # ---- dbias -------------------------------------------------------
     # One kernel for every broadcast layout: grid (Bb, H, nq, nk, R) with
@@ -599,7 +772,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
     # classic shared-bias reduction, Bb == B degenerates to "ds IS the
     # grad", and 1 < Bb < B is the grouped Evoformer layout.
     dbias = None
-    if has_bias:
+    if want_dbias:
         Bb, Hb = bias.shape[0], bias.shape[1]
         if Hb not in (1, H):
             raise KernelGeometryError(
@@ -638,6 +811,8 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
             pl.BlockSpec((1, 1, BQ, D),
                          lambda g, h, iq, ik, r, *_: (bat(g, r), h, iq, 0)),
         ])
+
+        unpack = _make_ref_unpacker(has_bias, has_mask, 1, 1)
 
         def db_wrapped(seed_ref, *refs):
             in_refs, outs, scratch = unpack(refs, len(inputs))
@@ -680,33 +855,53 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
 # public op with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _flash(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, blocks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _flash(q, k, v, bias, kv_mask, seed, block_map, sm_scale, dropout_rate,
+           blocks):
     out, _ = _fwd(
         q, k, v, bias, kv_mask, seed,
-        sm_scale, dropout_rate, blocks[0], blocks[1],
+        sm_scale, dropout_rate, blocks[0], blocks[1], block_map,
     )
     return out
 
 
-def _flash_fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, blocks):
+def _flash_fwd(q, k, v, bias, kv_mask, seed, block_map, sm_scale,
+               dropout_rate, blocks):
     out, lse = _fwd(
         q, k, v, bias, kv_mask, seed,
-        sm_scale, dropout_rate, blocks[0], blocks[1],
+        sm_scale, dropout_rate, blocks[0], blocks[1], block_map,
     )
-    return out, (q, k, v, bias, kv_mask, seed, out, lse)
+    return out, (q, k, v, bias, kv_mask, seed, block_map, out, lse)
 
 
 def _flash_bwd(sm_scale, dropout_rate, blocks, residuals, do):
-    q, k, v, bias, kv_mask, seed, out, lse = residuals
+    q, k, v, bias, kv_mask, seed, block_map, out, lse = residuals
     dq, dk, dv, dbias = _bwd(
         q, k, v, bias, kv_mask, seed,
-        sm_scale, dropout_rate, blocks[0], blocks[1], out, lse, do,
+        sm_scale, dropout_rate, blocks[0], blocks[1], out, lse, do, block_map,
     )
-    return dq, dk, dv, dbias, None, None
+    return dq, dk, dv, dbias, None, None, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@jax.custom_vjp
+def _constant_bias(bias):
+    """The bias of a mapped call: the identity, whose backward rule runs
+    only if something differentiates through the bias, and refuses."""
+    return bias
+
+
+def _constant_bias_bwd(_, cotangent):
+    raise KernelGeometryError(
+        "flash_attention with a block_map takes a constant bias: the "
+        "bias-gradient kernel walks every block (stop_gradient the bias, "
+        "or drop the map)"
+    )
+
+
+_constant_bias.defvjp(lambda bias: (bias, None), _constant_bias_bwd)
 
 
 def flash_attention(
@@ -720,6 +915,7 @@ def flash_attention(
     sm_scale: float = 1.0,
     block_q: int = 256,
     block_k: int = 512,
+    block_map: Optional[BlockMap] = None,
 ) -> jnp.ndarray:
     """Blockwise-online attention: softmax(q k^T * scale + bias, mask) v.
 
@@ -739,6 +935,14 @@ def flash_attention(
         kv_padding_mask: (B, Lk) bool/int; nonzero = masked out.
         dropout_rate: attention dropout applied to the probabilities.
         dropout_seed: int32 seed; fold in step/layer ids for decorrelation.
+        block_map: :func:`block_map` of which ``(block_q, block_k)`` blocks
+            hold a key some query of the block may see, per group ``G``
+            (``B % G == 0``, grouped as the bias is): the kernels visit
+            those blocks only.  The map decides WHICH blocks are scored,
+            never how: bias and mask still apply inside a visited block,
+            so the map must not drop a block that holds a visible key.  The
+            bias is then a constant (no bias gradient: asking for one
+            raises ``KernelGeometryError``).
     """
     if bias is not None:
         if bias.ndim == 3:
@@ -758,11 +962,27 @@ def flash_attention(
             raise KernelGeometryError(
                 f"bias heads {bias.shape[1]} must be 1 or {q.shape[1]}"
             )
+    if block_map is not None:
+        nq = q.shape[2] // _pick_block(q.shape[2], block_q)
+        nk = k.shape[2] // _pick_block(k.shape[2], block_k)
+        G = block_map.kv_counts.shape[0]
+        if (
+            q.shape[0] % G != 0
+            or block_map.kv_counts.shape != (G, nq)
+            or block_map.q_counts.shape != (G, nk)
+        ):
+            raise KernelGeometryError(
+                f"block_map of {block_map.kv_counts.shape} x "
+                f"{block_map.q_counts.shape} (groups, blocks) does not fit "
+                f"batch {q.shape[0]} with {nq} query and {nk} key blocks"
+            )
+        if bias is not None:
+            bias = _constant_bias(bias)
     if kv_padding_mask is not None:
         kv_padding_mask = kv_padding_mask.astype(jnp.int32)[:, None, :]
     seed = jnp.reshape(jnp.asarray(dropout_seed, dtype=jnp.int32), (1,))
     return _flash(
-        q, k, v, bias, kv_padding_mask, seed,
+        q, k, v, bias, kv_padding_mask, seed, block_map,
         # lint: host-sync-in-jit; dropout_rate is a static hyperparameter
         sm_scale, float(dropout_rate), (block_q, block_k),
     )
@@ -816,3 +1036,27 @@ def _audit_flash_bf16():
     q = jnp.zeros((2, 4, 512, 64), jnp.bfloat16)
     kv = jnp.zeros((2, 4, 512, 64), jnp.bfloat16)
     flash_attention(q, kv, kv, sm_scale=0.125)
+
+
+@audit_case("flash-attention-block-map")
+def _audit_flash_block_map():
+    """An EVA-like geometry (ops/eva_attention.py): four windows of 256 as
+    batch rows, each against ``[its own 256 keys ; 256 chunk summaries]``
+    under a grouped constant bias and a block map of G = 4 groups at blocks
+    of (128, 128) — the causal triangle on the left, summary block ``s``
+    seen by the windows past ``2 s`` on the right, so window 0 visits
+    neither summary block (key blocks with no visitor).  The three walked
+    kernels capture with index maps that read the map from scalar prefetch;
+    the auditor evaluates them on the map's values."""
+    q = jnp.zeros((4, 2, 256, 64), jnp.bfloat16)
+    kv = jnp.zeros((4, 2, 512, 64), jnp.bfloat16)
+    bias = jnp.zeros((4, 1, 256, 512), jnp.bfloat16)
+    w, iq, ik = np.ogrid[:4, :2, :4]
+    visits = block_map(np.where(ik < 2, ik <= iq, (ik - 2) * 2 < w))
+
+    def loss(q, kv):
+        out = flash_attention(q, kv, kv, bias=bias, block_q=128, block_k=128,
+                              block_map=visits)
+        return jnp.sum(out.astype(jnp.float32))
+
+    jax.grad(loss, argnums=(0, 1))(q, kv)
