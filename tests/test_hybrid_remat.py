@@ -1,0 +1,205 @@
+"""What the hybrid decoder's rematerialization keeps (``hybrid_decoder.
+_remat``: one policy, the arrays the layer kinds name).  At sizes a CPU
+holds: the values are those of no rematerialization and of the bare
+``nn.remat`` the decoder had before, bit for bit; the backward pass of an
+expert layer runs no second router product, ``top_k``, ``latent_down``,
+routed forward loop or ``shared_fc2``; a pattern whose kinds name nothing
+(``AFAF``) traces to the bare form's program; no name is dead on either
+side."""
+
+import collections
+import functools
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import pytest
+
+from unicore_tpu.modules import hybrid_decoder, latent_moe
+from unicore_tpu.modules.hybrid_decoder import HybridDecoder
+
+# every width differs from every other, so a product is known by its shapes
+D, B, S = 32, 2, 24
+N = B * S
+SIZES = dict(
+    embed_dim=D, norm_eps=1e-5,
+    mamba=dict(num_heads=4, head_dim=8, n_groups=2, state_size=20,
+               conv_kernel=4, chunk_size=8),
+    attention=dict(num_heads=4, num_kv_heads=2, head_dim=8),
+    moe=dict(latent_dim=16, expert_dim=28, shared_dim=40, n_routed=12,
+             top_k=3, n_held=4, first_held=4, routed_scale=2.5),
+    eva=dict(num_heads=2, head_dim=16, window_size=8, chunk_size=4,
+             rope_theta=1e4),
+    mlp=dict(ffn_dim=48, row_chunk=16),
+)
+#: the right-hand shapes of an ``E`` layer's forward products over its tokens
+PRODUCTS = dict(router=(D, 12), latent_down=(D, 16), latent_up=(16, D),
+                shared_fc1=(D, 40), shared_fc2=(40, D))
+
+
+def decoder(pattern, remat=True):
+    return HybridDecoder(pattern=pattern, remat=remat, **SIZES)
+
+
+@functools.lru_cache
+def inputs(pattern, dtype):
+    x = jax.random.normal(jax.random.key(0), (B, S, D), dtype)
+    params = decoder(pattern).init(jax.random.key(1), x)
+    # off the initial point: a router that spreads its choices, norms off 1
+    params = jax.tree_util.tree_map(
+        lambda a: (a + 0.3 * jax.random.normal(jax.random.key(2), a.shape)
+                   ).astype(dtype), params)
+    return params, x
+
+
+def loss_of(model):
+    def loss(params, x):
+        y, stats = model.apply(params, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32))), stats
+    return loss
+
+
+def make_bare(monkeypatch):
+    """The decoder as it was: ``nn.remat`` with no policy."""
+    monkeypatch.setattr(hybrid_decoder, "_remat", nn.remat)
+
+
+def run(pattern, dtype, remat):
+    params, x = inputs(pattern, dtype)
+    (value, stats), grads = jax.jit(jax.value_and_grad(
+        loss_of(decoder(pattern, remat)), argnums=(0, 1), has_aux=True,
+    ))(params, x)
+    return jax.tree_util.tree_leaves((value, stats, grads))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("pattern", ["*EMEM", "*EE", "AFAF"])
+@pytest.mark.parametrize("other", ["no_remat", "bare_remat"])
+def test_keeping_named_arrays_changes_no_bit(pattern, dtype, other,
+                                             monkeypatch):
+    """Loss, routing stats and every gradient, with the decoder's policy,
+    without rematerialization and under a bare ``nn.remat``."""
+    got = run(pattern, dtype, True)
+    if other == "bare_remat":
+        make_bare(monkeypatch)
+    want = run(pattern, dtype, other == "bare_remat")
+    assert len(got) == len(want) > 10
+    # a scanned Mamba layer's float32 gradients differ in their last bits
+    # between a rematerialized scan and a plain one, under the bare form
+    # too (``MM`` alone shows it): there, close; everywhere else, equal
+    exact = (other, dtype, "M" in pattern) != ("no_remat", jnp.float32, True)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        if exact:
+            assert bool(jnp.all(g == w))
+        else:
+            assert jnp.allclose(g, w, rtol=1e-4, atol=1e-4)
+    assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g in got)
+    if "E" in pattern:
+        assert float(got[1][latent_moe.STATS.index("pairs_here")]) > 0
+
+
+def equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the programs its equations hold,
+    each with the names of the primitives it lies inside."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub, inside + (eqn.primitive.name,))
+
+
+def backward(pattern):
+    params, x = inputs(pattern, jnp.float32)
+    grad = jax.grad(lambda p, x: loss_of(decoder(pattern))(p, x)[0], (0, 1))
+    return jax.make_jaxpr(grad)(params, x).jaxpr
+
+
+def remat_primitive():
+    """What this JAX calls the equation ``jax.checkpoint`` leaves."""
+    return jax.make_jaxpr(jax.checkpoint(jnp.sin))(1.0).eqns[0].primitive.name
+
+
+def rematerialized(jaxpr):
+    """What the backward pass runs inside its rematerializing equations
+    (the layers' forward made again, and their transposes): forward
+    products over the tokens by right-hand shape, and other primitives by
+    name."""
+    found = collections.Counter()
+    remat = remat_primitive()
+    for eqn, inside in equations(jaxpr):
+        if remat not in inside:
+            continue
+        name = eqn.primitive.name
+        if name == "dot_general":
+            lhs, rhs = (v.aval.shape for v in eqn.invars)
+            (lc, rc), _ = eqn.params["dimension_numbers"]
+            if lhs[0] == N and len(lhs) == 2 and (lc, rc) == ((1,), (0,)):
+                name = rhs
+        found[name] += 1
+    return found
+
+
+def test_the_backward_pass_makes_no_kept_array_again(monkeypatch):
+    """``*EMEM`` is ``*`` and one scanned ``EM`` body, so each count is one
+    ``E`` layer's.  Against the bare form the rematerialized part loses
+    the router's product and ``top_k``, ``latent_down``, ``shared_fc2``,
+    the routed experts' forward loop and the layout's sort; ``shared_fc1``
+    and ``latent_up`` are still made again, once each."""
+    kept = rematerialized(backward("*EMEM"))
+    make_bare(monkeypatch)
+    bare = rematerialized(backward("*EMEM"))
+    for name in ("router", "latent_down", "shared_fc2"):
+        assert (bare[PRODUCTS[name]], kept[PRODUCTS[name]]) == (1, 0), name
+    # left on purpose: the big one (its result is not named) and the small
+    # one that makes the next layer's input (latent_moe.KEPT says why)
+    for name in ("shared_fc1", "latent_up"):
+        assert (bare[PRODUCTS[name]], kept[PRODUCTS[name]]) == (1, 1), name
+    assert (bare["top_k"], kept["top_k"]) == (1, 0)
+    # the layout's stable sort of the pairs
+    assert (bare["sort"], kept["sort"]) == (1, 0)
+    # the loops over the tiles in use: the forward one goes, the backward
+    # one stays
+    assert (bare["while"], kept["while"]) == (2, 1)
+    # nothing else is kept (the Mamba layer's and the attention layer's
+    # products are made again as they were) and nothing is added
+    gone = bare - kept
+    assert {k for k in gone if isinstance(k, tuple)} == {
+        PRODUCTS[name] for name in ("router", "latent_down", "shared_fc2")}
+    assert gone["dot_general"] == 2      # the forward loop's two, per tile
+    assert not kept - bare
+
+
+def test_a_pattern_that_names_nothing_traces_to_the_bare_program(monkeypatch):
+    """``AFAF`` (``evabyte``'s kinds): with the policy the backward pass is
+    the bare ``nn.remat``'s, equation for equation."""
+    def listing(jaxpr):
+        return [
+            (eqn.primitive.name, inside,
+             tuple(str(v.aval) for v in eqn.invars),
+             tuple(str(v.aval) for v in eqn.outvars))
+            for eqn, inside in equations(jaxpr)
+        ]
+
+    kept = listing(backward("AFAF"))
+    make_bare(monkeypatch)
+    bare = listing(backward("AFAF"))
+    assert len(kept) > 100 and remat_primitive() in {e[0] for e in kept}
+    assert kept == bare
+
+
+def names_in(jaxpr):
+    return {eqn.params["name"] for eqn, _ in equations(jaxpr)
+            if eqn.primitive.name == "name"}
+
+
+def test_no_name_is_dead_on_either_side():
+    """Every name the decoder's policy lists is given to an array by some
+    layer kind, and every array a layer names is on the policy's list."""
+    params, x = inputs("*EMAF", jnp.float32)
+    produced = names_in(jax.make_jaxpr(decoder("*EMAF").apply)(params, x).jaxpr)
+    assert produced == set(hybrid_decoder.KEPT)
+    assert len(set(hybrid_decoder.KEPT)) == len(hybrid_decoder.KEPT)

@@ -17,22 +17,40 @@ stacked on a leading axis (``units/layer_<j>/...`` with shape ``(repeats,
 of five of each, which is what keeps its compilation inside a benchmark
 run's time limit.  Layers before the repeated tail are ``layers_<i>``.
 Each layer (each unit, under the scan) is rematerialized in the backward
-pass when ``remat`` is set: only the residual stream is kept.
+pass when ``remat`` is set.  Kept across the forward pass are the residual
+stream and the arrays a layer kind NAMES (``jax.ad_checkpoint.
+checkpoint_name``) because they are cheap to hold and dear to make again:
+one policy for every pattern (:func:`_remat`), and what it keeps in a layer
+is that layer kind's to say, which knows its shapes.  ``E`` names the
+router's scores, ``top_k``'s choice, both latent arrays, the layout and
+the shared expert's result (``latent_moe.KEPT``: 123 MB a layer at 8,192
+tokens, for which the backward pass runs no second router product,
+``top_k``, ``latent_down``, routed forward loop or ``shared_fc2``); ``M``,
+``*``, ``A`` and ``F`` name nothing and are made again whole.
 """
 
 from typing import Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from .eva_attention import EvaAttention
 from .gated_mlp import GatedMLP
-from .latent_moe import STATS, LatentMoE
+from .latent_moe import KEPT, STATS, LatentMoE
 from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
 from .multihead_attention import GroupedQueryAttention
 
 KINDS = "M*EAF"
+
+
+def _remat(cls):
+    """``cls`` rematerialized in the backward pass, but for what its
+    layers name: ``KEPT``, every name a layer kind gives an array it wants
+    kept (``E``'s alone so far; a kind that names one adds its tuple)."""
+    return nn.remat(
+        cls, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 
 
 def split_pattern(pattern: str) -> Tuple[str, str, int]:
@@ -124,7 +142,7 @@ class HybridDecoder(nn.Module):
                      mamba=self.mamba, attention=self.attention, moe=self.moe,
                      eva=self.eva, mlp=self.mlp,
                      norm_unit_offset=self.norm_unit_offset)
-        wrap = nn.remat if self.remat else (lambda cls: cls)
+        wrap = _remat if self.remat else (lambda cls: cls)
         head, unit, repeats = split_pattern(self.pattern)
         stats = jnp.zeros((len(STATS),), jnp.float32)
         for i, kind in enumerate(head):

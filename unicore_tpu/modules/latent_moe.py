@@ -55,6 +55,12 @@ layer is dropless by construction and has no bound to set.  No array of
 that many rows of activations, and none of (tokens x held experts x
 latent), is built, forward or backward (``tests/test_hybrid_lm.py``
 searches the compiled step for one).
+
+What a rematerializing caller should keep.  The layer names
+(``checkpoint_name``) the arrays that are small beside the work that makes
+them (:data:`KEPT`), and ``modules/hybrid_decoder.py`` keeps arrays by
+those names across the forward pass and nothing else.  A name changes no
+value and no dtype, and without such a caller it does nothing.
 """
 
 import functools
@@ -62,6 +68,7 @@ import functools
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from unicore_tpu.quant.dense import QuantDense
 
@@ -73,6 +80,25 @@ STATS = ("pairs_here", "load_max", "load_mean", "layers", "tiles_used")
 
 #: rows per tile of the grouped products (the MXU's 128 rows)
 TILE = 128
+
+#: the arrays :meth:`LatentMoE.__call__` names (``checkpoint_name``) for a
+#: rematerializing caller to keep across the forward pass: cheap to hold,
+#: dear to make again.  For ``n`` tokens, in this order: the router's
+#: float32 product ``(n, n_routed)``, ``top_k``'s indices ``(n, top_k)``
+#: int32 and its set ``(n, n_routed)`` bool (with the three no second
+#: product and no second sort); ``latent_down``'s result and the routed
+#: experts' sum after its cast, ``(n, latent_dim)`` each (no second
+#: ``latent_down``, no second forward loop over the tiles); the layout's
+#: index arrays (no second sort of the pairs); the shared expert's result
+#: ``(n, embed_dim)`` (no second ``shared_fc2``).  At the benchmark's 8,192
+#: tokens 123 MB a layer.  Not among them, each for a measured reason
+#: (PERF.md, PR 36): ``shared_fc1``'s result (88 MB more), and the layer's
+#: own result in the shared expert's place: ``latent_up``'s small second
+#: forward is left in the backward pass on purpose, because the array that
+#: the NEXT layer's second forward starts from is then made by a product,
+#: and that keeps the backward loop in the forward loop's layout.
+KEPT = ("moe_logits", "moe_top_k_idx", "moe_top_k_sel", "moe_latent_down",
+        "moe_routed_sum", "moe_layout", "moe_shared_out")
 
 
 def relu2(x):
@@ -239,7 +265,8 @@ def routed_experts(latent, w_held, w1, w2, rows, pair):
 
 
 def _routed_fwd(latent, w_held, w1, w2, rows, pair):
-    lay = buffer_layout(pair, w_held, rows)
+    lay = {k: checkpoint_name(v, "moe_layout")
+           for k, v in buffer_layout(pair, w_held, rows).items()}
     return _grouped_ffn(latent, w1, w2, lay), (latent, w1, w2, lay)
 
 
@@ -294,17 +321,19 @@ class LatentMoE(nn.Module):
             # float32 scores.  bfloat16 operands (a bf16 run's activations
             # and parameter copies) multiply exactly into the float32
             # accumulator; float32 operands take the full-precision product
-            logits = jnp.dot(
+            logits = checkpoint_name(jnp.dot(
                 tokens, w_r.astype(dtype), preferred_element_type=f32,
                 precision=None if dtype == jnp.bfloat16
                 else jax.lax.Precision.HIGHEST,
-            )
+            ), "moe_logits")
             s = jax.nn.sigmoid(logits)
             # the selection is not differentiated: it only decides WHICH
             # scores are summed
             idx, sel = top_k_set(
                 jax.lax.stop_gradient(s + b_corr.astype(f32)), self.top_k
             )
+            idx = checkpoint_name(idx, "moe_top_k_idx")
+            sel = checkpoint_name(sel, "moe_top_k_sel")
             denom = jnp.sum(jnp.where(sel, s, 0.0), axis=-1, keepdims=True)
             pair = (idx[:, :, None] == (
                 self.first_held + jnp.arange(Eh, dtype=idx.dtype)
@@ -315,7 +344,9 @@ class LatentMoE(nn.Module):
             load = pair.sum(axis=0)                                # (Eh,)
 
         with jax.named_scope("moe_latent"):
-            latent = dense("latent_down", self.latent_dim)(tokens)
+            latent = checkpoint_name(
+                dense("latent_down", self.latent_dim)(tokens),
+                "moe_latent_down")
 
         with jax.named_scope("moe_routed"):
             w1 = self.param("experts_fc1", _init,
@@ -329,13 +360,13 @@ class LatentMoE(nn.Module):
                 load.sum(), load.max(), load.astype(f32).mean(), 1,
                 tiles_of(load).sum(),
             ]).astype(f32)
-            routed = routed.astype(dtype)
+            routed = checkpoint_name(routed.astype(dtype), "moe_routed_sum")
 
         with jax.named_scope("moe_latent"):
             y = dense("latent_up", d)(routed)
 
         with jax.named_scope("moe_shared"):
-            y = y + dense("shared_fc2", d)(
+            y = y + checkpoint_name(dense("shared_fc2", d)(
                 relu2(dense("shared_fc1", self.shared_dim)(tokens))
-            )
+            ), "moe_shared_out")
         return y.reshape(B, S, d), stats
