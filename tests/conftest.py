@@ -87,8 +87,24 @@ _FAST_FILES = {
 }
 
 
+#: a test whose file this tree's PR may not edit (the benchmark's own files
+#: are a `benchmark` PR's), expected to fail until that PR repairs it; strict,
+#: so the entry has to go the day the test passes again
+_AWAITING_A_BENCHMARK_PR = {
+    "tests/benchmark/test_evabyte.py::"
+    "test_the_cell_and_its_metrics_are_in_the_manifest":
+        "line 81 pins EvaByte's five per_layer entries as the manifest's LAST "
+        "five; PR 37 appended eight (entries may only go at the end). "
+        "tests/benchmark/test_scope_work.py runs the same body on the "
+        "manifest less those eight (ROADMAP D17)",
+}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
+        why = _AWAITING_A_BENCHMARK_PR.get(item.nodeid)
+        if why:
+            item.add_marker(pytest.mark.xfail(reason=why, strict=True))
         if os.path.basename(str(item.fspath)) in _FAST_FILES:
             # slow-marked items in an otherwise-fast file (test_serve's
             # subprocess e2e) stay out of the quick smoke subset

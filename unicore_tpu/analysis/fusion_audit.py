@@ -97,6 +97,8 @@ _COLLECTIVE_OPS = frozenset({
 _COLLECTIVE_START_SUFFIX = "-start"
 
 _SHAPE_RE = re.compile(r"\b([a-z]+\d*(?:e\d+m\d+(?:fn)?)?)\[([\d,]*)\]")
+#: a layout's memory space, ``S(<n>)``; none written is HBM
+_MEMORY_SPACE_RE = re.compile(r"S\((\d+)\)")
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\([^=]*?\)|\S+)\s+([\w\-]+)\("
 )
@@ -156,10 +158,20 @@ def _comm_tier(groups: List[List[int]], devices_per_pod: Optional[int]):
     return "ici"
 
 
-def _shape_bytes(text: str) -> int:
-    """Total bytes of every ``dtype[dims]`` shape literal in ``text``."""
+def _shape_bytes(text: str, hbm_only: bool = False) -> int:
+    """Total bytes of every ``dtype[dims]`` shape literal in ``text``;
+    with ``hbm_only``, of those whose layout names no other memory space
+    (``{1,0:T(8,128)S(1)}`` is an array the TPU compiler placed in the
+    core's own memory: using it moves nothing to or from HBM)."""
     total = 0
-    for dtype, dims in _SHAPE_RE.findall(text):
+    for m in _SHAPE_RE.finditer(text):
+        dtype, dims = m.groups()
+        if hbm_only and text.startswith("{", m.end()):
+            space = _MEMORY_SPACE_RE.search(
+                text, m.end(), text.find("}", m.end()) + 1
+            )
+            if space and space.group(1) != "0":
+                continue
         per = _DTYPE_BYTES.get(dtype, 0)
         n = 1
         for d in dims.split(","):
