@@ -16,7 +16,11 @@ import jax.numpy as jnp
 from unicore_tpu import utils
 from .layer_norm import LayerNorm
 from .multihead_attention import CrossMultiheadAttention, SelfMultiheadAttention
-from .transformer_encoder import bert_init, make_rp_bucket
+from .transformer_encoder import (
+    bert_init,
+    keep_ffn_activation,
+    make_rp_bucket,
+)
 
 
 class TransformerDecoderLayer(nn.Module):
@@ -114,6 +118,7 @@ class TransformerDecoderLayer(nn.Module):
         )(x)
         x = act(x)
         x = act_dropout(x)
+        x = keep_ffn_activation(x, self.activation_fn)
         x = nn.Dense(
             self.embed_dim, name="fc2", kernel_init=bert_init,
             dtype=x.dtype, param_dtype=jnp.float32,

@@ -24,13 +24,56 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from unicore_tpu.quant.dense import QuantDense
+from unicore_tpu.quant import QuantDense, check_mode
 from .layer_norm import LayerNorm
 from .multihead_attention import SelfMultiheadAttention
 
 # BERT initialization (reference transformer_encoder.py:16-30): all linear /
 # embedding weights N(0, 0.02), biases 0, pad embedding row 0.
 bert_init = nn.initializers.normal(0.02)
+
+
+# activations dear enough to evaluate that ``fc2``'s products should read
+# them from memory: the exact GELU is some fifty vector operations, an
+# exponential and two divides an element.  ``relu`` / ``silu`` / ``linear``
+# cost a product's producer fusion nothing and stay unkept (a kept
+# ``(B, L, ffn)`` array a layer would buy nothing).
+KEPT_ACTIVATIONS = frozenset({"gelu", "gelu_fast", "gelu_accurate", "tanh"})
+
+
+@jax.custom_vjp
+def _pinned(x):
+    return jax.lax.optimization_barrier(x)
+
+
+def _pinned_fwd(x):
+    return jax.lax.optimization_barrier(x), None
+
+
+def _pinned_bwd(_, g):
+    # transparent to the cotangent: autodiff's own rule mirrors the barrier
+    # there, which cuts fc2's dx product from the activation's derivative
+    # (one fusion otherwise) and hands back all the forward pass gained
+    return (g,)
+
+
+_pinned.defvjp(_pinned_fwd, _pinned_bwd)
+
+
+def keep_ffn_activation(x, activation_fn: str):
+    """What ``fc2`` is about to read, pinned in memory where it is dear.
+
+    XLA keeps ``fc1``'s pre-activation only and clones the activation into
+    every consumer: as the producer of ``fc2``'s forward operand, again for
+    ``fc2``'s weight gradient, and as the epilogue with the derivative for
+    ``dx``.  Behind a forward-only ``optimization_barrier`` the value is made
+    once, under ``fc1``'s product, and both ``fc2`` products read a plain
+    bfloat16 operand.  Same operations on the same values; the cost is the
+    kept array (and the activation's own residual, now free to keep): two
+    ``(B, L, ffn)`` arrays a layer.  Called after ``act_dropout`` so the
+    array kept is the one ``fc2`` really reads.
+    """
+    return _pinned(x) if activation_fn in KEPT_ACTIVATIONS else x
 
 
 def init_bert_params(rng, module, sample):
@@ -156,6 +199,8 @@ class TransformerEncoderLayer(nn.Module):
             activation=self.activation_fn,
         )(x)
         x = act_dropout(x)
+        if check_mode(self.quantize) == "off":
+            x = keep_ffn_activation(x, self.activation_fn)
         x = QuantDense(
             self.embed_dim,
             name="fc2",
