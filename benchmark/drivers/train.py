@@ -68,23 +68,41 @@ def seeded_model_class(base, seed):
     return type("Seeded" + base.__name__, (base,), {"init_params": init_params})
 
 
-def endless(task, args, workers, buffer):
-    epoch = 1
-    while True:
-        itr = task.get_batch_iterator(
-            task.datasets["train"], batch_size=args.batch_size,
-            seed=args.seed, epoch=epoch, num_workers=workers,
-            data_buffer_size=buffer,
-        ).next_epoch_itr(shuffle=True)
-        yield from itr
-        epoch += 1
+class Feed:
+    """The program's batch iterator, epoch after epoch without end.  It
+    counts what it hands out: the ``epoch`` the last batch came from, that
+    batch's place ``at`` in it and the ``batches`` the epoch holds, so that
+    a run can say whether an epoch ended inside its window (a new iterator
+    and an empty buffer: a gap no device work fills)."""
+
+    def __init__(self, task, args, workers, buffer):
+        self.epoch = self.at = self.batches = 0
+        self._stream = self._endless(task, args, workers, buffer)
+
+    def _endless(self, task, args, workers, buffer):
+        while True:
+            self.epoch += 1
+            itr = task.get_batch_iterator(
+                task.datasets["train"], batch_size=args.batch_size,
+                seed=args.seed, epoch=self.epoch, num_workers=workers,
+                data_buffer_size=buffer,
+            ).next_epoch_itr(shuffle=True)
+            self.batches = len(itr)
+            for self.at, batch in enumerate(itr, 1):
+                yield batch
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return next(self._stream)
 
 
 def open_feed(cell, seed, work):
     """The cell's traffic as the program's own pipeline delivers it: the
     seeded corpus written under ``work``, the program's task over it, an
-    endless stream of collated batches, and ``shaped`` (the cell's host
-    padding to its edges, where it has any)."""
+    endless stream of collated batches (:class:`Feed`), and ``shaped`` (the
+    cell's host padding to its edges, where it has any)."""
     from unicore_tpu.tasks import TASK_REGISTRY
 
     cfg, tr = cell.config, cell.traffic
@@ -104,8 +122,8 @@ def open_feed(cell, seed, work):
             return batch, traffic._get(batch, tr["token_key"]).shape[1]
         return traffic.pad_to_edges(batch, edges, pad_values, tr["token_key"])
 
-    batches = endless(task, args, int(tr["data_workers"]),
-                      int(tr["data_buffer"]))
+    batches = Feed(task, args, int(tr["data_workers"]),
+                   int(tr["data_buffer"]))
     return args, task, batches, shaped, pad_values
 
 
@@ -298,11 +316,13 @@ def run(cell, seed, seconds, trace, device, peaks):
         phase("warm-up and trace")
 
         # -- the measured window --------------------------------------------
+        epoch_at_open, batch_at_open = batches.epoch, batches.at
         t0 = time.perf_counter()
         setup_s = t0 - harness.T_START
         drive(t0, seconds)
         t1 = time.perf_counter()
         window_s = t1 - t0
+        epochs_ended = batches.epoch - epoch_at_open
 
         recompiles = trainer._recompile_count - compiled_before
         macc = read_sums(trainer)
@@ -317,7 +337,10 @@ def run(cell, seed, seconds, trace, device, peaks):
     harness.say(
         f"window: {updates} updates, {tokens} real tokens in "
         f"{window_s:.3f}s; shapes {dict(sorted(shapes.items()))}; "
-        f"recompiles_in_window={recompiles} skipped_updates={skipped}"
+        f"recompiles_in_window={recompiles} skipped_updates={skipped}; "
+        f"epochs_ended_in_window={epochs_ended} (opened after batch "
+        f"{batch_at_open} of epoch {epoch_at_open}, closed on batch "
+        f"{batches.at} of {batches.batches})"
     )
 
     # -- outside the window: free the program, then follow it ---------------
@@ -344,7 +367,8 @@ def run(cell, seed, seconds, trace, device, peaks):
         "end_to_end": {"train_tokens_per_s": tokens_per_s,
                        "setup_s": setup_s},
         # what the per-layer readers read
-        "window_s": window_s, "updates": updates, "sum_n": tokens,
+        "window_s": window_s, "updates": updates,
+        "epochs_ended_in_window": epochs_ended, "sum_n": tokens,
         "sum_n2": sum_n2, "mask_prob": float(tr["task_args"]["mask_prob"]),
         "peaks": peaks, "chips": cell.chips, "config": cfg, "base": cell.base,
         "data_wait_ms_median": 1e3 * statistics.median(

@@ -290,7 +290,23 @@ def reduce_events(per_device, modules, threads, tables):
         },
         "idle_s": {k: v / n for k, v in idle.most_common()},
         "host": host_phases(threads, main),
+        "mapped_pairs": mapped_pairs(threads),
     }
+
+
+def mapped_pairs(threads):
+    """The (query, key) pairs a head of one mapped attention call scores,
+    as the program states them: the ``keys_computed`` stat of whichever of
+    its annotations holds one (``unicore:eva_keys``; a later map states the
+    same stat under a mark of its own), the mean over the traced updates'
+    marks (a cell has one step shape, so they agree).  None where no mark
+    holds it: ``flops/kernels.py`` then counts no mapped call."""
+    stated = [
+        float(s[3]["keys_computed"]) for spans in threads.values()
+        for s in spans
+        if s[2].startswith(PROGRAM) and "keys_computed" in s[3]
+    ]
+    return statistics.mean(stated) if stated else None
 
 
 def host_phases(threads, main):
@@ -384,8 +400,10 @@ def kernels_pct(run, names):
 
 def kernels_roofline_pct(run, names):
     """Matmul operations the kernels ``names`` performed (``flops/kernels``
-    from each event's own operand shapes, times its calls) over their
-    device time and the chip's bf16 peak, in %."""
+    from each event's own operand shapes, times its calls; a call under a
+    block map by the pairs the program states it scores) over their device
+    time and the chip's bf16 peak, in %.  None where a mapped call ran and
+    nothing states its pairs."""
     trace = of(run)
     if not trace or not any(k in trace["kernels_s"] for k in names):
         return None
@@ -393,10 +411,11 @@ def kernels_roofline_pct(run, names):
     flops = seconds = 0.0
     for k in names:
         if k in trace["kernels_s"]:
-            flops += sum(
-                count.matmul_flops(k, shapes) * calls
-                for shapes, calls in trace["kernel_calls"][k]
-            )
+            for shapes, calls in trace["kernel_calls"][k]:
+                one = count.matmul_flops(k, shapes, trace.get("mapped_pairs"))
+                if one is None:
+                    return None
+                flops += one * calls
             seconds += trace["kernels_s"][k]
     peak = run["peaks"]["bf16_flops_per_s"]
     return 100.0 * flops / seconds / peak if seconds else None

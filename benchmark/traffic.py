@@ -70,10 +70,13 @@ def text_corpus(params, out_dir, seed):
                        int(params["n_docs"]))
     rng.shuffle(sizes)
     ids = rng.choice(n_words, size=int(sizes.sum()), p=zipf_p(n_words))
+    # every word has four letters: a document is its words' five bytes
+    # each (the word and a space) less the last space, taken in one gather
+    spaced = np.array([w + " " for w in words], dtype="S5")
     builder = make_builder(os.path.join(out_dir, "train"))
     at = 0
     for n in sizes:
-        builder.add_item(" ".join(words[ids[at:at + n]]))
+        builder.add_item(spaced[ids[at:at + n]].tobytes()[:-1].decode())
         at += n
     builder.finalize()
     return {"sizes": sizes}

@@ -107,3 +107,32 @@ def tiny_checkout(tmp_path, cell_name, float32=False):
     return root, base
 
 
+
+def tiny_epoch_rows(tmp_path, cell_name, seed=12345678901):
+    """The rows of every batch of the tiny cell's first epoch and of the
+    first batch of its second, as the driver's feed hands them out; checks
+    on the way that the feed counts its epochs.  A tiny cell's epoch has to
+    hold whole batches only (``assert_whole_batches``): a short last batch
+    is a shape of its own, which a window that reaches it compiles
+    (``recompiles_in_window`` 1, by the host's speed)."""
+    from benchmark import harness, traffic
+    from benchmark.drivers import train
+
+    root, base = tiny_checkout(tmp_path, cell_name)
+    cell = harness.Cell(harness.load_manifest(root), cell_name, base, root)
+    feed = train.open_feed(cell, seed, os.path.join(root, "corpus"))[2]
+
+    def rows_of_next():
+        return len(traffic._get(next(feed), cell.traffic["token_key"]))
+
+    rows = [rows_of_next()]  # the first batch opens the epoch: its length is known
+    rows += [rows_of_next() for _ in range(feed.batches - 1)]
+    assert (feed.epoch, feed.at) == (1, feed.batches)
+    rows.append(rows_of_next())
+    assert (feed.epoch, feed.at) == (2, 1)
+    return rows
+
+
+def assert_whole_batches(tmp_path, cell_name):
+    rows = tiny_epoch_rows(tmp_path, cell_name)
+    assert set(rows) == {TINY[cell_name]["traffic"]["batch_size"]}, rows

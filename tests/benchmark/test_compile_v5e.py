@@ -4,6 +4,16 @@ the cell's batch.  No chip, no chip time; a compile that passes is not a
 chip run.  ``memory_analysis()`` here is the record of how each batch was
 chosen (the cell files quote these bytes).
 
+What is HELD is what the chip holds: ``peak_memory_in_bytes``, the most the
+program keeps at one time with buffers reused, under the described chip's
+16,911,433,728 with 1 GB of room (:func:`fits_the_chip`), and over the
+quarter of a chip a cell has to fill.  ``total_bytes`` (arguments + outputs
++ temporaries - aliases) counts every temporary as if none shared its
+bytes with another: it reads 19.0 GB for a step that loads and runs on the
+chip (PERF.md, PR 31), so it is printed and bounds nothing.  Neither number
+is pinned to what it read when the batch was chosen: a later PR that keeps
+an activation, or frees one, moves both and may not edit this file.
+
 The topology is described inside a module-scoped fixture, never at import
 (only one process may load libtpu, and every xdist worker imports every
 test file); where it cannot be described the tests skip.
@@ -137,20 +147,36 @@ def total_bytes(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("name,length,kernels,low,high", [
-    # bytes read when the batch was chosen: 7,439,044,608
-    ("bert_base.train_mlm512", 512, 24, 6.5e9, 8.5e9),
-    # 8,307,357,696 at the largest edge; the common edge has to fit too
-    ("unimol.train_mol256", 256, 30, 7.5e9, 9.5e9),
-    ("unimol.train_mol256", 128, 30, 1.5e9, 9.5e9),
+def fits_the_chip(compiled, what, fills=True):
+    """Hold the step to what the chip holds: its peak leaves 1 GB of the
+    described chip's memory and (``fills``: at the cell's largest shape) is
+    over the quarter a cell has to fill.  Prints the peak and
+    ``total_bytes`` (``pytest -s`` shows them); returns the peak."""
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    print(f"{what}: peak_memory_in_bytes {peak:,} of {HBM:,}; "
+          f"total_bytes {total_bytes(compiled):,} (no bound)")
+    assert HBM - peak >= 1e9, peak
+    if fills:
+        assert peak > 0.25 * HBM, peak
+    return peak
+
+
+@pytest.mark.parametrize("name,length,kernels", [
+    # read when the batch was chosen: total_bytes 7,439,044,608; since PR 38
+    # keeps two activations a layer, peak 10,031,237,120 (total_bytes
+    # 10,268,964,864)
+    ("bert_base.train_mlm512", 512, 24),
+    # total_bytes 8,307,357,696 at the largest edge when the batch was
+    # chosen; the common edge has to fit too
+    ("unimol.train_mol256", 256, 30),
+    ("unimol.train_mol256", 128, 30),
 ])
-def test_cell_step_compiles_for_v5e(name, length, kernels, low, high,
-                                    one_chip, monkeypatch):
+def test_cell_step_compiles_for_v5e(name, length, kernels, one_chip,
+                                    monkeypatch):
     cell = harness.Cell(manifest_with_candidates(), name)
     compiled = compile_step(cell, length, one_chip, monkeypatch)
     assert compiled.as_text().count("tpu_custom_call") >= kernels
-    total = total_bytes(compiled)
-    assert low < total < high, total
-    # above the quarter of a chip a cell has to fill, and with room left
-    if length == max(cell.traffic.get("pad_edges", [length])):
-        assert 0.25 * HBM < total < 0.75 * HBM
+    fits_the_chip(
+        compiled, f"{name} at {length}",
+        fills=length == max(cell.traffic.get("pad_edges", [length])),
+    )

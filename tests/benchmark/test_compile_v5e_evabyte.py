@@ -41,18 +41,15 @@ def test_cell_step_compiles_for_v5e(one_chip, monkeypatch):  # noqa: F811
     )
     text = compiled.as_text()
     m = compiled.memory_analysis()
-    total = rehearsal.total_bytes(compiled)
-    peak = m.peak_memory_in_bytes
     # the blockwise attention kernels (forward, its rematerialized copy, dq,
     # dkv); the visibility mask is a constant and needs no bias gradient
     assert text.count("tpu_custom_call") >= 4
     assert "flash_bwd_dbias" not in text
     assert "eva_agg" in text and "eva_prep_kv" in text and "rotary" in text
     # bytes read when the heads were chosen: peak 15,768,649,728 (8 heads:
-    # 14,610,534,400), total_bytes 18,997,351,936
-    assert rehearsal.HBM - peak >= 1e9, peak
-    assert 15.3e9 < peak < 15.92e9, peak
-    assert 18.5e9 < total < 19.5e9, total
-    assert 0.25 * rehearsal.HBM < peak < rehearsal.HBM
+    # 14,610,534,400), total_bytes 18,997,351,936.  Held: the peak, with
+    # 1 GB of the chip left; neither number is pinned to those readings (a
+    # later PR that frees an activation may not edit this file)
+    rehearsal.fits_the_chip(compiled, CELL)
     # the state is donated: parameters, master and moments are updated in place
     assert m.alias_size_in_bytes > 9.5e9

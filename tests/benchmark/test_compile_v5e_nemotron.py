@@ -37,11 +37,11 @@ def test_cell_step_compiles_for_v5e(one_chip, monkeypatch):  # noqa: F811
     assert "flash_bwd_dbias" not in text
     # one traced body per layer kind: the five EM units are one while loop
     assert text.count("ssd_scan") > 0
-    total = rehearsal.total_bytes(compiled)
-    # bytes read when the batch was chosen: 13,835,767,296 (2 x 8,192
-    # needs about 2.5 GB more, which leaves the allocator under 1 GB)
-    assert 12.5e9 < total < 14.5e9, total
-    assert 0.25 * rehearsal.HBM < total < rehearsal.HBM
+    # read when the batch was chosen: total_bytes 13,835,767,296 (2 x 8,192
+    # needs about 2.5 GB more); since PR 36 names what the E layers keep,
+    # peak 12,724,507,136 and total_bytes 14,228,393,472.  Held: the peak,
+    # with 1 GB of the chip left (a further kept activation has that room)
+    rehearsal.fits_the_chip(compiled, CELL)
     m = compiled.memory_analysis()
     # the state is donated: parameters, master and moments are updated in place
     assert m.alias_size_in_bytes > 9.5e9

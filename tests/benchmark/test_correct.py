@@ -9,6 +9,7 @@ import json
 
 import pytest
 
+import bench_tiny
 from bench_tiny import fake_chip, load, tiny_checkout
 from benchmark import control, harness
 
@@ -42,6 +43,13 @@ def test_reference_follows_the_program_in_float32(cell, run_tiny):
 def test_sound_bfloat16_run_is_correct_on_a_large_seed(cell, run_tiny):
     out, last = run_tiny(cell, seed=2 ** 31 + 977)
     assert last["correct"] is True, out["checks"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_tiny_epoch_holds_whole_batches(cell, tmp_path):
+    """No batch of a tiny cell's epoch is short (a shape of its own, which
+    a window that reaches it compiles), and the feed counts its epochs."""
+    bench_tiny.assert_whole_batches(tmp_path, cell)
 
 
 def _state_unchanged(monkeypatch):

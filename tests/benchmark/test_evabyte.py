@@ -45,27 +45,26 @@ def checks_of(out):
 
 # -- what the files state ---------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def manifest():
-    return load(os.path.join(ROOT, "BENCHMARK.json"))
-
+# ``checkout`` / ``manifest``: conftest.py's, the manifest as it is and with
+# an append (what is asserted of it has to hold on both)
 
 @pytest.fixture(scope="module")
 def config():
     return load(os.path.join(BENCH, "configs", "evabyte.json"))
 
 
-NEW = {"eva_agg_device_pct", "eva_agg_roofline_pct", "eva_prep_kv_device_pct",
-       "eva_prep_kv_roofline_pct", "eva_keys_computed_over_visible"}
+NEW = ["eva_agg_device_pct", "eva_agg_roofline_pct", "eva_prep_kv_device_pct",
+       "eva_prep_kv_roofline_pct", "eva_keys_computed_over_visible"]
 
 
-def test_the_cell_and_its_metrics_are_in_the_manifest(manifest):
-    cell = harness.Cell(manifest, CELL)
+def test_the_cell_and_its_metrics_are_in_the_manifest(checkout):
+    manifest = checkout.manifest
+    cell = checkout.cell(CELL)
     assert cell.chips == 1 and cell.traffic["driver"] == "train"
     assert {m["name"] for m in cell.metrics("end_to_end")} == {
         "train_tokens_per_s", "setup_s"}
     mine = {m["name"] for m in cell.metrics("per_layer")}
-    assert NEW | {
+    assert set(NEW) | {
         "train_mfu_pct", "peak_hbm_gib", "train_step_ms", "data_wait_ms",
         "device_idle_pct", "pallas_device_pct",
         # the accepted readers that find something to read in the cell
@@ -77,16 +76,26 @@ def test_the_cell_and_its_metrics_are_in_the_manifest(manifest):
         "data_buffer_depth", "data_produce_ms"} <= mine
     assert not mine & {"ssm_device_pct", "moe_device_pct",
                        "moe_load_max_over_mean"}
-    # the new metrics are this cell's alone, the manifest's last entries
-    assert [m["name"] for m in manifest["per_layer"][-5:]] == [
-        "eva_agg_device_pct", "eva_agg_roofline_pct", "eva_prep_kv_device_pct",
-        "eva_prep_kv_roofline_pct", "eva_keys_computed_over_visible"]
-    for m in manifest["per_layer"][-5:]:
-        assert m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s"
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert manifest["configs"][-1]["name"] == "evabyte"
+    # the new metrics are this cell's alone, each listed once, in the order
+    # they were appended in and after the last the benchmark had then (all
+    # found by name: whoever appends after them moves nothing asserted here)
+    listed = [m["name"] for m in manifest["per_layer"]]
+    at = [listed.index(name) for name in NEW]
+    assert at == sorted(at) and listed.index("moe_load_max_over_mean") < at[0]
+    assert all(listed.count(name) == 1 for name in NEW)
+    for m in manifest["per_layer"]:
+        if m["name"] in NEW:
+            assert m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s"
+    entry = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    cfg_entry = next(c for c in manifest["configs"] if c["name"] == "evabyte")
+    assert entry["config"] == "evabyte" and entry["traffic"] == "train_pack32k"
+    # the cell and the configuration that were there last stand before it
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert cells.index("nemotron3_super_120b.train_pack8k") < cells.index(CELL)
+    configs = [c["name"] for c in manifest["configs"]]
+    assert configs.index("nemotron3_super_120b") < configs.index("evabyte")
     for name in mine:  # every reader is there, and finds nothing to read
-        reader = harness.load_module("layer_metrics", name)
+        reader = harness.load_module("layer_metrics", name, checkout.base)
         assert reader.read({"peaks": {}, "base": BENCH}) is None or name in (
             "peak_hbm_gib",)
     for kind in ("reference", "flops"):
@@ -98,8 +107,8 @@ def test_the_cell_and_its_metrics_are_in_the_manifest(manifest):
                             "doc_words": [256, 16384]}
     assert tr["task_args"]["seq_pad_multiple"] == 128
     assert (tr["data_workers"], tr["data_buffer"]) == (2, 8)
-    assert len(manifest["workloads"][-1]["why"]) <= 200
-    assert len(manifest["configs"][-1]["why"]) <= 200
+    assert len(entry["why"]) <= 200
+    assert len(cfg_entry["why"]) <= 200
 
 
 def test_the_configuration_states_its_source_its_cuts_and_what_it_assumed(
@@ -200,12 +209,19 @@ def test_reference_follows_the_program_in_float32(run_tiny):
         harness.Cell(load(os.path.join(ROOT, "BENCHMARK.json")), CELL),
         out, trace=True))["metrics"]
     assert line["train_mfu_pct"]["value"] > 0
-    assert not NEW & set(line)  # no trace on a CPU: left out, not raised
+    assert not set(NEW) & set(line)  # no trace on a CPU: left out, not raised
 
 
 def test_sound_bfloat16_run_is_correct_on_a_large_seed(run_tiny):
     out, last = run_tiny(CELL, seed=2 ** 31 + 977)
     assert last["correct"] is True, out["checks"]
+
+
+def test_the_tiny_epoch_holds_whole_batches(tmp_path):
+    """No batch of the tiny cell's epoch is short (a shape of its own, which
+    a window that reaches it compiles: ROADMAP D18), and the feed counts
+    its epochs."""
+    bench_tiny.assert_whole_batches(tmp_path, CELL)
 
 
 def _no_summaries(monkeypatch):

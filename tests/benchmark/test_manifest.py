@@ -1,6 +1,8 @@
 """``BENCHMARK.json`` against the contract's rules that a CPU can check, and
 the proof that a new cell, configuration or per-layer metric needs only new
-files and new manifest entries."""
+files and new manifest entries.  Every check that takes ``manifest`` or
+``checkout`` (``conftest.py``) runs twice: on the manifest as it is, and on
+it with a configuration, a cell and two per-layer metrics appended."""
 
 import json
 import os
@@ -10,26 +12,25 @@ import pytest
 
 from benchmark import harness
 
-from bench_tiny import BENCH, ROOT, fake_chip, load, tiny_checkout
+from bench_tiny import fake_chip, load, tiny_checkout
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module")
-def manifest():
-    return load(os.path.join(ROOT, "BENCHMARK.json"))
-
-
-def test_keys_and_sizes(manifest):
+def test_keys_and_sizes(checkout):
+    manifest = checkout.manifest
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    assert os.path.getsize(
+        os.path.join(checkout.root, "BENCHMARK.json")) <= 64 * 1024
     assert 1 <= manifest["run_seconds"] <= 51
-    cells = len(manifest["workloads"])
     assert (2 + 14 * 24) * (manifest["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
-    assert 1 <= cells <= 24
+    assert 1 <= len(manifest["workloads"]) <= 24
+    assert 1 <= len(manifest["configs"]) <= 24
+    assert 1 <= len(manifest["end_to_end"]) <= 16
+    assert 1 <= len(manifest["per_layer"]) <= 128
     for word in manifest["command"]:
         assert not word.startswith("/") and ".." not in word
 
@@ -53,30 +54,29 @@ def test_names_units_and_sources(manifest):
     assert any(m["name"] == "setup_s" for m in manifest["end_to_end"])
 
 
-def test_every_named_file_exists(manifest):
+def test_every_named_file_exists(checkout):
+    manifest, root, base = checkout
+    files = [cfg["file"] for cfg in manifest["configs"]]
+    assert len(set(files)) == len(files)  # no configuration's file is another's
     for cfg in manifest["configs"]:
-        assert os.path.isfile(os.path.join(ROOT, cfg["file"]))
-        body = load(os.path.join(ROOT, cfg["file"]))
+        assert os.path.isfile(os.path.join(root, cfg["file"]))
+        body = load(os.path.join(root, cfg["file"]))
         for key in cfg["reduced"]:
             assert key in body, (cfg["name"], key)
             assert not re.search(r"(_dim|_rank|hidden|intermediate|head)", key)
+        assert any(w["config"] == cfg["name"] for w in manifest["workloads"])
     for w in manifest["workloads"]:
-        cell = harness.Cell(manifest, w["name"])
-        assert os.path.isfile(
-            os.path.join(BENCH, "drivers", cell.traffic["driver"] + ".py")
-        )
+        cell = checkout.cell(w["name"])
+        harness.find("drivers", cell.traffic["driver"] + ".py", base)
         for kind in ("reference", "flops"):
-            assert os.path.isfile(
-                os.path.join(BENCH, kind, cell.config[kind] + ".py")
-            )
+            harness.find(kind, cell.config[kind] + ".py", base)
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
     for m in manifest["per_layer"]:
-        assert os.path.isfile(
-            os.path.join(BENCH, "layer_metrics", m["name"] + ".py")
-        )
+        harness.find("layer_metrics", m["name"] + ".py", base)
 
 
-def test_every_moves_is_reported_where_the_metric_is(manifest):
+def test_every_moves_is_reported_where_the_metric_is(checkout):
+    manifest = checkout.manifest
     end = {m["name"]: m for m in manifest["end_to_end"]}
     cells = [w["name"] for w in manifest["workloads"]]
     for m in manifest["per_layer"]:
@@ -85,7 +85,7 @@ def test_every_moves_is_reported_where_the_metric_is(manifest):
         for cell in m.get("workloads", reported_in):
             assert cell in reported_in, (m["name"], cell)
     for cell in cells:
-        c = harness.Cell(manifest, cell)
+        c = checkout.cell(cell)
         assert len(c.metrics("end_to_end")) >= 2
         assert len(c.metrics("per_layer")) >= 1
 

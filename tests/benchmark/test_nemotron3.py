@@ -33,7 +33,11 @@ bench_tiny.TINY.setdefault(CELL, {
         moe_intermediate_size=48, moe_shared_expert_intermediate_size=96,
         loss_chunk=48, vocab_size=200,
     ),
-    "corpus": dict(vocab=200, n_docs=64, doc_words=[30, 200]),
+    # 64 documents of 32 .. 200 words are 118 blocks of 64 tokens, the same
+    # for every seed: every batch of an epoch has both its rows (at 30 ..
+    # 200 they were 117, and a window that reached the epoch's last, single
+    # row compiled that shape: ``test_every_tiny_epoch_holds_whole_batches``)
+    "corpus": dict(vocab=200, n_docs=64, doc_words=[32, 200]),
     "traffic": dict(
         batch_size=2, warm_updates=1, reference_rows=1,
         task_args=dict(mask_prob=1.0, tokens_per_sample=64, seq_pad_multiple=8),
@@ -47,18 +51,16 @@ def checks_of(out):
 
 # -- what the files state ---------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def manifest():
-    return load(os.path.join(ROOT, "BENCHMARK.json"))
-
+# ``checkout`` / ``manifest``: conftest.py's, the manifest as it is and with
+# an append (what is asserted of it has to hold on both)
 
 @pytest.fixture(scope="module")
 def config():
     return load(os.path.join(BENCH, "configs", "nemotron3_super_120b.json"))
 
 
-def test_the_cell_and_its_metrics_are_in_the_manifest(manifest):
-    cell = harness.Cell(manifest, CELL)
+def test_the_cell_and_its_metrics_are_in_the_manifest(checkout):
+    cell = checkout.cell(CELL)
     assert cell.chips == 1 and cell.traffic["driver"] == "train"
     assert {m["name"] for m in cell.metrics("end_to_end")} == {
         "train_tokens_per_s", "setup_s"}
@@ -76,7 +78,7 @@ def test_the_cell_and_its_metrics_are_in_the_manifest(manifest):
             "data_buffer_depth", "data_produce_ms"} <= mine
     assert "ffn_device_pct" not in mine  # no fc1 / fc2 in this model
     for name in mine:  # every reader is there, and finds nothing to read
-        reader = harness.load_module("layer_metrics", name)
+        reader = harness.load_module("layer_metrics", name, checkout.base)
         assert reader.read({"peaks": {}, "base": BENCH}) is None or name in (
             "peak_hbm_gib",)
     for kind in ("reference", "flops"):
@@ -160,6 +162,12 @@ def test_reference_follows_the_program_in_float32(run_tiny):
 def test_sound_bfloat16_run_is_correct_on_a_large_seed(run_tiny):
     out, last = run_tiny(CELL, seed=2 ** 31 + 977)
     assert last["correct"] is True, out["checks"]
+
+def test_the_tiny_epoch_holds_whole_batches(tmp_path):
+    """No batch of the tiny cell's epoch is short (a shape of its own, which
+    a window that reaches it compiles: ROADMAP D18), and the feed counts
+    its epochs."""
+    bench_tiny.assert_whole_batches(tmp_path, CELL)
 
 
 def _no_skip_term(monkeypatch):
@@ -335,8 +343,10 @@ def test_dp4_candidate_is_the_one_chip_cell_at_the_same_per_chip_batch():
     assert dp4.traffic["driver"] == "train" and dp4.config == one.config
     for same in ("batch_size", "task_args", "limits", "token_key"):
         assert dp4.traffic[same] == one.traffic[same], same
-    # 128 sequences an update: as many batches an epoch as the one-chip cell
-    assert (dp4.traffic["corpus"]["n_docs"] // (dp4.traffic["batch_size"] * 4)
-            == one.traffic["corpus"]["n_docs"] // one.traffic["batch_size"])
+    # 128 sequences an update, and an epoch that outlasts a run on either:
+    # 192 batches for its 80 updates of 250 ms (PERF.md section 5), 512 for
+    # the one-chip cell's 260 of 92 ms and twice that rate (PR 39)
+    assert dp4.traffic["corpus"]["n_docs"] // (dp4.traffic["batch_size"] * 4) == 192
+    assert one.traffic["corpus"]["n_docs"] // one.traffic["batch_size"] == 512
     real = load(os.path.join(ROOT, "BENCHMARK.json"))
     assert all(w["name"] != "bert_base.train_dp4" for w in real["workloads"])

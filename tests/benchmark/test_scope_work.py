@@ -137,11 +137,11 @@ def test_the_pass_by_hand():
 NEW_METRICS = sorted(EXPECTED)
 
 
-def test_the_eight_entries_are_in_the_manifest():
+def test_the_eight_entries_are_in_the_manifest(checkout):
     """Each of the eight is listed once, keeps the contract's rules a CPU
     can check, names a reader, and reaches the cells that have what it
-    reads."""
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
+    reads (``checkout``: conftest.py's, as it is and with an append)."""
+    manifest = checkout.manifest
     mine = [m for m in manifest["per_layer"] if m["name"] in EXPECTED]
     assert sorted(m["name"] for m in mine) == NEW_METRICS
     # appended: the 32 entries the benchmark had stand first, as they were
@@ -159,7 +159,7 @@ def test_the_eight_entries_are_in_the_manifest():
         harness.find("layer_metrics", m["name"] + ".py")
     lists = {
         cell: {m["name"] for m in
-               harness.Cell(manifest, cell).metrics("per_layer")} & set(NEW_METRICS)
+               checkout.cell(cell).metrics("per_layer")} & set(NEW_METRICS)
         for cell in cells
     }
     everywhere = {"xla_matmul_device_pct", "xla_matmul_roofline_pct",
@@ -170,19 +170,6 @@ def test_the_eight_entries_are_in_the_manifest():
         "moe_shared_roofline_pct", "data_pack_ms"}
     assert lists["evabyte.train_pack32k"] == everywhere | {
         "ffn_roofline_pct", "data_pack_ms"}
-
-
-def test_evabytes_cell_is_stated_as_it_was():
-    """``test_evabyte.py``'s check of the manifest on the manifest less the
-    eight: its line 81 takes EvaByte's five for the list's last five, which
-    they were until this PR appended (that test is expected to fail in
-    ``tests/conftest.py`` until a ``benchmark`` PR looks them up by name)."""
-    import test_evabyte
-
-    manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
-    manifest["per_layer"] = [
-        m for m in manifest["per_layer"] if m["name"] not in EXPECTED]
-    test_evabyte.test_the_cell_and_its_metrics_are_in_the_manifest(manifest)
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
