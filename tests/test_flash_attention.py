@@ -366,3 +366,108 @@ def test_block_map_lists_visits_both_ways():
             q, kv, kv, block_q=128, block_k=128,
             block_map=fa.block_map(np.ones((3, 2, 4), bool)),
         )
+
+
+# -- the band: a causal mask (with or without a window) that is no operand ------
+
+#: name -> (L, window, (block_q, block_k)).  At blocks of (128, 128) a row of
+#: 512 has 4 x 4 blocks; ``window-in-block``: every query's keys lie in its
+#: own block or the one before; ``window-across``: a window wider than a
+#: block, so whole blocks inside the band are wholly visible
+BANDS = {
+    "causal": (512, None, (128, 128)),
+    "causal-wide-keys": (512, None, (128, 256)),
+    "window-in-block": (512, 48, (128, 128)),
+    "window-across": (512, 300, (128, 128)),
+    "window-wide-keys": (1024, 400, (128, 256)),
+}
+
+
+def _band_seen(L, window):
+    diff = np.arange(L)[:, None] - np.arange(L)[None, :]
+    return (diff >= 0) & (diff < (window or L))
+
+
+def _dense_masked(q, k, v, seen, sm_scale):
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * sm_scale
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("case", sorted(BANDS))
+def test_band_matches_a_dense_masked_softmax_and_the_bias_path(case):
+    """``flash_attention(..., band=Band(window))`` against a dense masked
+    float32 softmax (output and all three gradients), and bit for bit
+    against the same kernels handed the mask as a bias operand (the band's
+    hidden pairs and the bias's ``NEG_INF`` both weigh exactly zero).  The
+    bitwise pair both carry a bias operand, the band's a zero one: the CPU
+    compiler of interpret mode folds ``sm_scale`` into the product where no
+    bias is added to it, which moves the last bit and is not the band's
+    doing."""
+    L, window, (bq, bk) = BANDS[case]
+    B, H, D = 2, 2, 32
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q, k, v = (jax.random.normal(key, (B, H, L, D), jnp.float32) for key in keys)
+    w = jnp.cos(jnp.arange(B * H * L * D, dtype=jnp.float32)).reshape(q.shape)
+    seen = jnp.asarray(_band_seen(L, window))
+    band = fa.Band(window)
+    visible = fa.band_visible(band, L // bq, L // bk, bq, bk)
+    # the predicate the kernels decide by: a block that needs no mask
+    first = lambda n, b: np.arange(n)[:, None] * b
+    whole = fa._band_whole(
+        first(L // bq, bq) - first(L // bk, bk).T, bq, bk, band.width(L))[None]
+    blocks = np.asarray(seen).reshape(L // bq, bq, L // bk, bk)
+    assert np.array_equal(visible[0], blocks.any(axis=(1, 3)))
+    assert np.array_equal(whole[0], blocks.all(axis=(1, 3)))
+    assert not visible.all() and (visible & ~whole).any()
+    if case in ("causal-wide-keys", "window-across", "window-wide-keys"):
+        assert whole.any()  # a block the band needs no mask for
+    computed, pairs = fa.band_counts(band, L, L, bq, bk)
+    assert pairs == int(np.asarray(seen).sum())
+    assert computed == int(visible.sum()) * bq * bk
+
+    def loss(fn):
+        def f(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out * w), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    banded = lambda q, k, v: fa.flash_attention(
+        q, k, v, sm_scale=D ** -0.5, block_q=bq, block_k=bk, band=band)
+    biased = lambda q, k, v: fa.flash_attention(
+        q, k, v, bias=jnp.where(seen, 0.0, fa.NEG_INF)[None, None],
+        sm_scale=D ** -0.5, block_q=bq, block_k=bk)
+    banded_zero = lambda q, k, v: fa.flash_attention(
+        q, k, v, bias=jnp.zeros((1, 1, L, L), jnp.float32),
+        sm_scale=D ** -0.5, block_q=bq, block_k=bk, band=band)
+    dense = lambda q, k, v: _dense_masked(q, k, v, seen, D ** -0.5)
+    (_, o_band), g_band = jax.jit(loss(banded))(q, k, v)
+    (_, o_ref), g_ref = loss(dense)(q, k, v)
+    np.testing.assert_allclose(o_band, o_ref, atol=2e-5, rtol=2e-5)
+    for a, b in zip(g_band, g_ref):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+    (_, o_zero), g_zero = jax.jit(loss(banded_zero))(q, k, v)
+    (_, o_bias), g_bias = loss(biased)(q, k, v)
+    assert np.array_equal(np.asarray(o_zero), np.asarray(o_bias))
+    for name, a, b in zip(("dq", "dk", "dv"), g_zero, g_bias):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+
+def test_band_takes_bfloat16_a_padding_mask_and_no_second_map():
+    L, D = 256, 32
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (jax.random.normal(key, (1, 2, L, D), jnp.bfloat16) for key in keys)
+    pad = jnp.asarray((np.arange(L) >= 200)[None].astype(np.int32))
+    seen = jnp.asarray(_band_seen(L, 100)) & (np.arange(L) < 200)[None, :]
+    out = fa.flash_attention(q, k, v, kv_padding_mask=pad, sm_scale=D ** -0.5,
+                             block_q=128, block_k=128, band=fa.Band(100))
+    want = _dense_masked(*(t.astype(jnp.float32) for t in (q, k, v)), seen,
+                         D ** -0.5)
+    np.testing.assert_allclose(np.asarray(out[:, :, :200], np.float32),
+                               np.asarray(want[:, :, :200]), atol=2e-2)
+    with pytest.raises(fa.KernelGeometryError, match="not both"):
+        fa.flash_attention(q, k, v, band=fa.Band(), block_q=128, block_k=128,
+                           block_map=fa.block_map(np.ones((1, 2, 2), bool)))
+    assert fa.Band().width(512) == 512 and fa.Band(64).width(512) == 64

@@ -166,7 +166,13 @@ class LMCrossEntropyLoss(UnicoreLoss):
         moved, each way) and the most loaded held expert's and the mean
         load, per layer; for a model with window-plus-summary attention
         one ``unicore:eva_keys`` mark with the keys its kernel form scored
-        and the keys its queries could see."""
+        and the keys its queries could see; for a model whose attention
+        runs under a band the kernels mask themselves one
+        ``unicore:attn_band`` mark with, for its sliding-window and its
+        full layers apart (two maps: none of its stats is named
+        ``keys_computed``, which a reader takes for the pairs of ONE mapped
+        call), the pairs the kernels scored and the pairs a query could
+        see, per row and head, summed over the layers of each kind."""
         marks = {}
         layers = sums.get("moe_layers", 0)
         if layers:
@@ -185,6 +191,15 @@ class LMCrossEntropyLoss(UnicoreLoss):
                 windows=int(sums["eva_windows"]),
                 chunks=int(sums["eva_chunks"]),
             )
+        rows = sums.get("band_rows", 0)
+        if rows:
+            # models/mellum.py: ops/flash_attention.band_counts of the maps
+            # the kernels are handed
+            marks["attn_band"] = {
+                f"{kind}_{stat}": int(sums[f"band_{kind}_{stat}"] / rows)
+                for kind in ("window", "full")
+                for stat in ("keys_computed", "keys_visible", "layers")
+            }
         return marks
 
     @staticmethod

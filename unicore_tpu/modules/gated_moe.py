@@ -1,0 +1,181 @@
+"""Routed gated experts, as a layer that is TOLD which experts it holds:
+a softmax router over all ``n_routed`` experts, ``top_k`` a token with the
+chosen scores renormalised, each expert a gated three-matrix feed-forward
+layer at the model's width; no latent, no shared expert.
+
+    p = softmax(h W_r)                      float32, over ALL n_routed
+    chosen = the top_k largest of p;   w_e = p_e / sum_{chosen} p
+    y = sum_{e chosen and held} w_e W_down,e (silu(W_gate,e h) * (W_up,e h))
+
+It is ``modules/latent_moe.py``'s machinery with another body: the chosen
+scores are read where they lie (:func:`~.latent_moe.top_k_set`, no gather),
+the (token, held expert) pairs are laid out expert by expert in whole
+tiles (:func:`~.latent_moe.buffer_layout`), one loop walks the tiles in
+use and its written-out backward walks them again
+(:func:`~.latent_moe.routed_experts` with ``act="silu_gate"``:
+``experts_fc1`` holds ``[W_gate | W_up]`` side by side, the gate's
+columns first, ``experts_fc2`` is ``W_down``); dropless, no capacity
+factor.  The layer holds experts ``first_held .. first_held + n_held - 1``,
+routes over all ``n_routed`` and computes its own experts' part; what the
+absent experts would add is left out, as on one chip of an expert-parallel
+deployment before the exchange, so the shares' results add up to the uncut
+layer's (``tests/test_mellum.py``).  The exchange is not built (ROADMAP
+R8), and there is no auxiliary balancing loss.
+
+``balancing="batch_bias"`` is a rule of TRAINING: loss-free balancing
+(Wang et al., arXiv:2408.15664, DeepSeek-V3's rule: a bias an expert on
+the scores that CHOOSE, raised where an expert got fewer tokens than its
+share and lowered where it got more, the weights still the scores'), with
+the bias solved anew on every batch instead of carried from step to step
+(:func:`balanced_scores`), because the trainer carries no state beside
+parameters and moments.  What it is solved on is each expert's logits
+standardised over the batch's tokens plus noisy top-k gating's noise
+(Shazeer et al., arXiv:1701.06538) at one spread, from a fixed table: a
+bias an expert cannot part tokens whose logits are the same, and a few
+updates into training most of a batch's are (PERF.md, PR 40).  Scores and
+bias are over ALL ``n_routed`` experts and a function of the router's
+product alone, so every share of a layer chooses the same set.  With it
+each expert gets its share of the batch's pairs to within a few percent,
+on seeded weights and while training draws the hidden states together.
+
+It returns the same ``STATS`` and names the same arrays for a
+rematerializing caller (``latent_moe.KEPT``: the router's product,
+``top_k``'s indices and set, the layout, and the routed sum, here the
+layer's own result).
+"""
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from .latent_moe import (
+    STATS, buffer_rows, routed_experts, tiles_of, top_k_set,
+)
+
+_init = nn.initializers.normal(0.02)
+
+BALANCINGS = ("none", "batch_bias")
+
+#: :func:`balanced_scores`: the noise's scale in spreads of an expert's
+#: logits, the count-and-correct rounds, and the step of each (in the
+#: standardised scores' units)
+NOISE = 1.0
+BIAS_ROUNDS = 8
+BIAS_GAIN = 0.55
+
+
+def noise_table(n, E):
+    """The fixed ``(n, E)`` standard normal table the scores that choose
+    are dithered with: the same numbers on every backend, every step and
+    every layer (the plain reference draws it the same way)."""
+    return jax.random.normal(
+        jax.random.key(0, impl="threefry2x32"), (n, E), jnp.float32)
+
+
+def balanced_scores(logits, k, rounds=BIAS_ROUNDS, gain=BIAS_GAIN):
+    """``logits`` (n, E) float32.  Scores ``u + b`` whose ``k`` largest a
+    row fall evenly on the columns:
+
+        m_e = mean_t logits_te,   s_e = sqrt(mean_t (logits_te - m_e)^2)
+        u_te = (logits_te - m_e) / s_e + NOISE * table_te
+        b = 0;  rounds times:  c_e = the tokens whose k largest hold e
+                               b_e = b_e - gain * ln((c_e + 1) / (n k / E + 1))
+
+    ``m`` takes out what all tokens have in common (most of a seeded
+    router's logits, and the direction in which a share's router unlearns
+    its held experts).  The step of ``b`` is loss-free balancing's (an
+    expert with more than its share is lowered), in proportion to the log
+    of the excess.  The noise is what makes the rounds settle: a step of
+    the bias wins or loses tokens in proportion to how densely their
+    scores lie about the k-th place, and with ``u``'s spread never under
+    ``NOISE`` the gain stays under one, whether the logits are spread like
+    a seeded router's or, a few updates later, nearly the same for most
+    tokens (without it the rounds swing between all and nothing there)."""
+    n, E = logits.shape
+    share = n * k / E
+    mean = jnp.mean(logits, axis=0)
+    spread = jnp.sqrt(jnp.mean(jnp.square(logits - mean), axis=0))
+    u = (logits - mean) / (spread + 1e-6) + NOISE * noise_table(n, E)
+    b = jnp.zeros((E,), logits.dtype)
+    for _ in range(rounds):
+        _, sel = top_k_set(u + b, k)
+        c = jnp.sum(sel, axis=0).astype(logits.dtype)
+        b = b - gain * jnp.log((c + 1.0) / (share + 1.0))
+    return u + b
+
+
+class GatedMoE(nn.Module):
+    embed_dim: int
+    expert_dim: int
+    n_routed: int
+    top_k: int
+    n_held: int = 0           # 0: all of n_routed
+    first_held: int = 0
+    norm_topk_prob: bool = True
+    balancing: str = "none"   # of BALANCINGS
+
+    @nn.compact
+    def __call__(self, h):
+        """``h`` (B, S, embed_dim), already normalised by the block.
+        Returns ``(y, stats)``; ``stats`` is float32 of ``len(STATS)``."""
+        E = self.n_routed
+        Eh = self.n_held or E
+        if not 0 <= self.first_held <= E - Eh:
+            raise ValueError(
+                f"experts {self.first_held}..{self.first_held + Eh - 1} "
+                f"are not among {E}"
+            )
+        if self.balancing not in BALANCINGS:
+            raise ValueError(
+                f"balancing {self.balancing!r} is not one of {BALANCINGS}")
+        B, S, d = h.shape
+        n = B * S
+        dtype = h.dtype
+        f32 = jnp.float32
+        tokens = h.reshape(n, d)
+
+        with jax.named_scope("moe_router"):
+            w_r = self.param("router", _init, (d, E), jnp.float32)
+            # float32 scores: bfloat16 operands multiply exactly into the
+            # float32 accumulator, float32 ones take the full product
+            logits = checkpoint_name(jnp.dot(
+                tokens, w_r.astype(dtype), preferred_element_type=f32,
+                precision=None if dtype == jnp.bfloat16
+                else jax.lax.Precision.HIGHEST,
+            ), "moe_logits")
+            p = jax.nn.softmax(logits, axis=-1)
+            # the selection is not differentiated: it only decides WHICH
+            # scores are summed
+            chooser = jax.lax.stop_gradient(p)
+            if self.balancing == "batch_bias":
+                chooser = balanced_scores(
+                    jax.lax.stop_gradient(logits), self.top_k)
+            idx, sel = top_k_set(chooser, self.top_k)
+            idx = checkpoint_name(idx, "moe_top_k_idx")
+            sel = checkpoint_name(sel, "moe_top_k_sel")
+            pair = sel[:, self.first_held:self.first_held + Eh]     # (n, Eh)
+            w_held = jnp.where(
+                pair, p[:, self.first_held:self.first_held + Eh], 0.0)
+            if self.norm_topk_prob:
+                w_held = w_held / jnp.sum(
+                    jnp.where(sel, p, 0.0), axis=-1, keepdims=True)
+            load = pair.sum(axis=0)                                 # (Eh,)
+
+        with jax.named_scope("moe_routed"):
+            w1 = self.param("experts_fc1", _init,
+                            (Eh, d, 2 * self.expert_dim),
+                            jnp.float32).astype(dtype)
+            w2 = self.param("experts_fc2", _init,
+                            (Eh, self.expert_dim, d),
+                            jnp.float32).astype(dtype)
+            routed = routed_experts(
+                tokens, w_held, w1, w2, buffer_rows(n, self.top_k, Eh), pair,
+                "silu_gate",
+            )
+            stats = jnp.stack([
+                load.sum(), load.max(), load.astype(f32).mean(), 1,
+                tiles_of(load).sum(),
+            ]).astype(f32)
+            y = checkpoint_name(routed.astype(dtype), "moe_routed_sum")
+        return y.reshape(B, S, d), stats

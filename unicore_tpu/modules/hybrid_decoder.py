@@ -2,7 +2,11 @@
 ``hybrid_override_pattern``): ``M`` a Mamba-2 mixer, ``*`` grouped-KV
 causal attention, ``E`` a LatentMoE with a shared expert, ``A`` EVA
 attention (a window's keys plus the earlier windows' chunk summaries, with
-rotary positions), ``F`` a gated feed-forward layer.  Every layer is
+rotary positions), ``F`` a gated feed-forward layer, ``S`` and ``G``
+grouped-KV attention with rotary positions under a band the kernels mask
+themselves (``S`` over a sliding window, ``G`` over the whole row; each
+with its own rotary table), ``R`` routed gated experts with a softmax
+router and no shared expert.  Every layer is
 
     x = x + mixer(RMSNorm(x))
 
@@ -25,8 +29,10 @@ is that layer kind's to say, which knows its shapes.  ``E`` names the
 router's scores, ``top_k``'s choice, both latent arrays, the layout and
 the shared expert's result (``latent_moe.KEPT``: 123 MB a layer at 8,192
 tokens, for which the backward pass runs no second router product,
-``top_k``, ``latent_down``, routed forward loop or ``shared_fc2``); ``M``,
-``*``, ``A`` and ``F`` name nothing and are made again whole.
+``top_k``, ``latent_down``, routed forward loop or ``shared_fc2``); ``R``
+names the same router, ``top_k`` and layout arrays and its routed sum
+(``gated_moe.py``); ``M``, ``*``, ``A``, ``F``, ``S`` and ``G`` name nothing
+and are made again whole.
 """
 
 from typing import Optional, Tuple
@@ -37,12 +43,13 @@ import jax.numpy as jnp
 
 from .eva_attention import EvaAttention
 from .gated_mlp import GatedMLP
+from .gated_moe import GatedMoE
 from .latent_moe import KEPT, STATS, LatentMoE
 from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
 from .multihead_attention import GroupedQueryAttention
 
-KINDS = "M*EAF"
+KINDS = "M*EAFSGR"
 
 
 def _remat(cls):
@@ -79,6 +86,9 @@ class HybridBlock(nn.Module):
     moe: Optional[dict] = None
     eva: Optional[dict] = None
     mlp: Optional[dict] = None
+    window_attention: Optional[dict] = None
+    full_attention: Optional[dict] = None
+    gated_moe: Optional[dict] = None
     norm_unit_offset: bool = False
 
     @nn.compact
@@ -98,6 +108,15 @@ class HybridBlock(nn.Module):
             y = EvaAttention(self.embed_dim, name="self_attn", **self.eva)(h)
         elif self.kind == "F":
             y = GatedMLP(self.embed_dim, name="mlp", **self.mlp)(h)
+        elif self.kind in "SG":
+            y = GroupedQueryAttention(
+                self.embed_dim, name="self_attn", banded=True,
+                **(self.window_attention if self.kind == "S"
+                   else self.full_attention),
+            )(h)
+        elif self.kind == "R":
+            y, stats = GatedMoE(
+                self.embed_dim, name="moe", **self.gated_moe)(h)
         else:
             raise ValueError(
                 f"layer kind {self.kind!r} is not one of {KINDS!r}"
@@ -130,6 +149,10 @@ class HybridDecoder(nn.Module):
     moe: Optional[dict] = None         # E: LatentMoE's
     eva: Optional[dict] = None         # A: EvaAttention's
     mlp: Optional[dict] = None         # F: GatedMLP's
+    # S, G: GroupedQueryAttention's, banded (S states a window; each its rope)
+    window_attention: Optional[dict] = None
+    full_attention: Optional[dict] = None
+    gated_moe: Optional[dict] = None   # R: GatedMoE's
     remat: bool = True
     norm_unit_offset: bool = False  # every norm's gain is 1 + its parameter
 
@@ -141,6 +164,9 @@ class HybridDecoder(nn.Module):
         block = dict(embed_dim=self.embed_dim, norm_eps=self.norm_eps,
                      mamba=self.mamba, attention=self.attention, moe=self.moe,
                      eva=self.eva, mlp=self.mlp,
+                     window_attention=self.window_attention,
+                     full_attention=self.full_attention,
+                     gated_moe=self.gated_moe,
                      norm_unit_offset=self.norm_unit_offset)
         wrap = _remat if self.remat else (lambda cls: cls)
         head, unit, repeats = split_pattern(self.pattern)

@@ -56,6 +56,12 @@ that many rows of activations, and none of (tokens x held experts x
 latent), is built, forward or backward (``tests/test_hybrid_lm.py``
 searches the compiled step for one).
 
+The layout, the tile loop and its written-out backward are shared with
+``modules/gated_moe.py``: :func:`routed_experts` takes the expert's
+activation by name (:data:`ACTS`; ``relu2`` here, ``silu_gate`` for a
+gated three-matrix expert whose first kernel holds ``[gate | up]``), and
+with ``relu2`` traces what it traced before it took one.
+
 What a rematerializing caller should keep.  The layer names
 (``checkpoint_name``) the arrays that are small beside the work that makes
 them (:data:`KEPT`), and ``modules/hybrid_decoder.py`` keeps arrays by
@@ -103,6 +109,35 @@ KEPT = ("moe_logits", "moe_top_k_idx", "moe_top_k_sel", "moe_latent_down",
 
 def relu2(x):
     return jnp.square(jax.nn.relu(x))
+
+
+def _relu2_vjp(pre):
+    r = jax.nn.relu(pre)
+    return jnp.square(r), lambda dh: dh * 2.0 * r
+
+
+def silu_gate(pre):
+    """``silu(gate) * up`` of ``pre = [gate | up]`` (the gate's columns
+    first), float32."""
+    f = pre.shape[-1] // 2
+    return jax.nn.silu(pre[..., :f]) * pre[..., f:]
+
+
+def _silu_gate_vjp(pre):
+    f = pre.shape[-1] // 2
+    g, u = pre[..., :f], pre[..., f:]
+    sg = jax.nn.sigmoid(g)
+    a = g * sg
+    return a * u, lambda dh: jnp.concatenate(
+        [dh * u * (sg * (1.0 + g * (1.0 - sg))), dh * a], axis=-1)
+
+
+#: an expert's activation between its two products, by name: what the
+#: forward loop applies to ``x W1_e`` (float32), and the same with its
+#: written-out backward, ``pre -> (h, dh -> dpre)``.  ``relu2``: ``W1_e``
+#: has the expert's width; ``silu_gate``: ``W1_e`` is ``[gate | up]``, twice
+#: the width ``W2_e`` reads
+ACTS = {"relu2": (relu2, _relu2_vjp), "silu_gate": (silu_gate, _silu_gate_vjp)}
 
 
 def tiles_of(load):
@@ -195,20 +230,21 @@ def _tile_rows(table, token, valid):
     return jnp.where(valid[:, None], table[token], 0)
 
 
-def _grouped_ffn(latent, w1, w2, lay):
-    """``sum_e weight * relu2(latent W1_e) W2_e`` over the pairs of the
+def _grouped_ffn(latent, w1, w2, lay, act="relu2"):
+    """``sum_e weight * act(latent W1_e) W2_e`` over the pairs of the
     first ``tiles_used`` tiles of ``lay`` (:func:`buffer_layout`), (n, lat)
     float32.  Each trip gathers its tile's ``TILE`` rows of ``latent``,
     runs them through its expert and adds the weighted result at their
     tokens.  A loop with a trip count from the data: only forward (the
     backward is :func:`_grouped_ffn_bwd`)."""
     f32 = jnp.float32
+    act_fn = ACTS[act][0]
 
     def body(t, out):
         e = lay["tile_expert"][t]
         token, weight, valid = _tile_of(lay, t)
         x_t = _tile_rows(latent, token, valid)
-        h = relu2(jnp.dot(x_t, w1[e], preferred_element_type=f32))
+        h = act_fn(jnp.dot(x_t, w1[e], preferred_element_type=f32))
         y_t = jnp.dot(h.astype(latent.dtype), w2[e],
                       preferred_element_type=f32)
         # a row without a pair adds an exact zero (x_t and its weight are)
@@ -219,12 +255,13 @@ def _grouped_ffn(latent, w1, w2, lay):
     )
 
 
-def _grouped_ffn_bwd(latent, d_out, w1, w2, lay):
+def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2"):
     """Cotangents of :func:`_grouped_ffn` for ``d_out`` (n, lat) float32:
     ``d_latent`` (n, lat), the pairs' weights' (Eh, n), ``dw1``, ``dw2``,
     all float32.  The hidden states are computed again tile by tile."""
     dtype = latent.dtype
     f32 = jnp.float32
+    act_vjp = ACTS[act][1]
 
     def body(t, carry):
         dx, dweight, dw1, dw2 = carry
@@ -232,12 +269,13 @@ def _grouped_ffn_bwd(latent, d_out, w1, w2, lay):
         token, weight, valid = _tile_of(lay, t)
         x_t = _tile_rows(latent, token, valid)
         d_t = _tile_rows(d_out, token, valid)
-        r = jax.nn.relu(jnp.dot(x_t, w1[e], preferred_element_type=f32))
-        h = jnp.square(r).astype(dtype)
+        h, act_bwd = act_vjp(
+            jnp.dot(x_t, w1[e], preferred_element_type=f32))
+        h = h.astype(dtype)
         y_t = jnp.dot(h, w2[e], preferred_element_type=f32)
         dy_t = (d_t * weight[:, None]).astype(dtype)
         dh = jnp.dot(dy_t, w2[e].T, preferred_element_type=f32)
-        dpre = (dh * 2.0 * r).astype(dtype)
+        dpre = act_bwd(dh).astype(dtype)
         dx_t = jnp.dot(dpre, w1[e].T, preferred_element_type=f32)
         dw1 = dw1.at[e].add(jnp.dot(x_t.T, dpre, preferred_element_type=f32))
         dw2 = dw2.at[e].add(jnp.dot(h.T, dy_t, preferred_element_type=f32))
@@ -254,26 +292,28 @@ def _grouped_ffn_bwd(latent, d_out, w1, w2, lay):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def routed_experts(latent, w_held, w1, w2, rows, pair):
-    """``sum_e w_held[n, e] * W2_e relu2(W1_e latent[n])`` over the pairs
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6))
+def routed_experts(latent, w_held, w1, w2, rows, pair, act="relu2"):
+    """``sum_e w_held[n, e] * W2_e act(W1_e latent[n])`` over the pairs
     ``pair`` marks.  ``latent`` (n, lat); ``w_held`` (n, Eh) float32, zero
-    off the pairs; ``w1`` (Eh, lat, f), ``w2`` (Eh, f, lat); ``rows``
-    static, the layout's length (:func:`buffer_rows`); ``pair`` (n, Eh)
-    bool, not differentiated.  Returns (n, lat) float32."""
-    return _routed_fwd(latent, w_held, w1, w2, rows, pair)[0]
+    off the pairs; ``w1`` (Eh, lat, f), ``w2`` (Eh, f, lat) (``act``
+    ``silu_gate``: ``w1`` (Eh, lat, 2 f), :data:`ACTS`); ``rows`` static,
+    the layout's length (:func:`buffer_rows`); ``pair`` (n, Eh) bool, not
+    differentiated.  Returns (n, lat) float32."""
+    return _routed_fwd(latent, w_held, w1, w2, rows, pair, act)[0]
 
 
-def _routed_fwd(latent, w_held, w1, w2, rows, pair):
+def _routed_fwd(latent, w_held, w1, w2, rows, pair, act="relu2"):
     lay = {k: checkpoint_name(v, "moe_layout")
            for k, v in buffer_layout(pair, w_held, rows).items()}
-    return _grouped_ffn(latent, w1, w2, lay), (latent, w1, w2, lay)
+    return _grouped_ffn(latent, w1, w2, lay, act), (latent, w1, w2, lay)
 
 
-def _routed_bwd(rows, residuals, d_out):
+def _routed_bwd(rows, act, residuals, d_out):
     latent, w1, w2, lay = residuals
     d_out = d_out.astype(jnp.float32)
-    d_latent, dweight, dw1, dw2 = _grouped_ffn_bwd(latent, d_out, w1, w2, lay)
+    d_latent, dweight, dw1, dw2 = _grouped_ffn_bwd(
+        latent, d_out, w1, w2, lay, act)
     return (d_latent.astype(latent.dtype), dweight.T, dw1.astype(w1.dtype),
             dw2.astype(w2.dtype), None)
 

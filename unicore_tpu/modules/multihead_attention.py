@@ -21,6 +21,7 @@ execution paths behind the same API:
   reference kernel's semantics.
 """
 
+import contextlib
 import logging
 from typing import Optional
 
@@ -117,7 +118,7 @@ def _flash_pad_waste_ok(tgt_len, src_len):
 
 
 def _flash_grouped(q, k, v, bias, kvm, Lq, Lk, dropout_rate=0.0,
-                   dropout_seed=0, try_fullrow=False):
+                   dropout_seed=0, try_fullrow=False, band=None):
     """Pad (N, H, L, hd) operands to the kernel's 128 tiles and run the
     grouped flash kernel (or the fullrow one-shot variant when its row
     budget allows and ``try_fullrow``): padded keys mask out, padded query
@@ -127,7 +128,12 @@ def _flash_grouped(q, k, v, bias, kvm, Lq, Lk, dropout_rate=0.0,
     seq-sharded route.
 
     ``kvm``: (N, Lk) int, nonzero = masked OUT; ``bias``: grouped
-    (G, 1|H, Lq, Lk) with N % G == 0, or None."""
+    (G, 1|H, Lq, Lk) with N % G == 0, or None; ``band``: a
+    ``flash_attention.Band`` (causal, with or without a window), which the
+    blockwise kernels mask themselves from the positions of the PADDED row
+    (padding is at the end, so no real query's positions move; the block
+    count, and so the band's map, is the padded row's).  Under a band a
+    padded key needs no mask of its own: it lies after every real query."""
     from unicore_tpu.ops.flash_attention import flash_attention
 
     N = q.shape[0]
@@ -136,13 +142,14 @@ def _flash_grouped(q, k, v, bias, kvm, Lq, Lk, dropout_rate=0.0,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        if pad_k:  # only padded KEYS need masking out
+        if pad_k and not (band is not None and kvm is None):
+            # only padded KEYS need masking out
             if kvm is None:
                 kvm = jnp.zeros((N, Lk), jnp.int32)
             kvm = jnp.pad(kvm, ((0, 0), (0, pad_k)), constant_values=1)
         if bias is not None:
             bias = jnp.pad(bias, ((0, 0), (0, 0), (0, pad_q), (0, pad_k)))
-    if try_fullrow:
+    if try_fullrow and band is None:
         # moderate rows: one-shot softmax + single-pass fused backward
         from unicore_tpu.ops.attention_fullrow import (
             fullrow_attention, supported as _fullrow_supported,
@@ -161,11 +168,12 @@ def _flash_grouped(q, k, v, bias, kvm, Lq, Lk, dropout_rate=0.0,
         q, k, v, bias=bias, kv_padding_mask=kvm,
         dropout_rate=dropout_rate, dropout_seed=dropout_seed,
         sm_scale=1.0,  # q is pre-scaled
+        band=band,
     )[:, :, :Lq]
 
 
 def _flash_data_parallel(q, k, v, bias, kvm, Lq, Lk, dropout_rate,
-                         dropout_seed):
+                         dropout_seed, band=None):
     """The module router's flash call under a live multi-device mesh.
 
     Mosaic kernels cannot be partitioned by XLA's SPMD pass ("wrap the
@@ -176,7 +184,8 @@ def _flash_data_parallel(q, k, v, bias, kvm, Lq, Lk, dropout_rate,
     with them, a shared bias rides replicated (its cotangent is psummed by
     the shard_map transpose).  The dropout seed folds in the shard's index
     so shards draw different masks.  Single-device meshes, batches the dp
-    tier does not divide, meshes with another live tier (tensor / seq /
+    tier does not divide (a ``band`` is static numbers: every shard makes the
+    same map of it), meshes with another live tier (tensor / seq /
     pipeline layouts route attention themselves, some already inside a
     shard_map) and traces already inside a manual region over the dp tier
     (``parallel/hierarchy.py``'s two-level reduction: the rows are local
@@ -189,7 +198,7 @@ def _flash_data_parallel(q, k, v, bias, kvm, Lq, Lk, dropout_rate,
     def run(q_, k_, v_, bias_, kvm_, seed_):
         return _flash_grouped(
             q_, k_, v_, bias_, kvm_, Lq, Lk, dropout_rate=dropout_rate,
-            dropout_seed=seed_, try_fullrow=True,
+            dropout_seed=seed_, try_fullrow=True, band=band,
         )
 
     mesh = get_global_mesh()
@@ -420,11 +429,19 @@ def _attend(
     use_ring=False,
     seq_impl="ring",
     quantize="",
+    band=None,
 ):
     """Shared core: pick quantized-score (int8 serving) vs seq-parallel
-    (ring or all-to-all) vs flash vs fused-softmax."""
+    (ring or all-to-all) vs flash vs fused-softmax.  ``band``: a
+    ``flash_attention.Band`` beside ``attn_bias`` (the flash kernels mask
+    it themselves; the fused-softmax path takes it as a mask made of
+    iotas); the int8 and seq-parallel routes do not take one."""
     bsz, num_heads, tgt_len, head_dim = q.shape
     src_len = k.shape[2]
+    if band is not None and (use_ring or quantize):
+        raise NotImplementedError(
+            "a band reaches the flash kernels and the fused softmax only"
+        )
 
     if key_padding_mask is not None and key_padding_mask.ndim == 0:
         key_padding_mask = None
@@ -526,12 +543,20 @@ def _attend(
         )
         o = _flash_data_parallel(
             q, k, v, bias_min, kmask, tgt_len, src_len,
-            dropout_rate=eff_dropout, dropout_seed=seed,
+            dropout_rate=eff_dropout, dropout_seed=seed, band=band,
         )
         return o, None, None
 
     # fused-softmax path (materializes the attention matrix)
     attn_weights = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    if band is not None:
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, (tgt_len, src_len), 0)
+                 - jax.lax.broadcasted_iota(jnp.int32, (tgt_len, src_len), 1))
+        seen = (ahead >= 0) & (ahead < band.width(src_len))
+        attn_weights = jnp.where(
+            seen, attn_weights,
+            jnp.asarray(jnp.finfo(attn_weights.dtype).min, attn_weights.dtype),
+        )
     if key_padding_mask is not None:
         # the most negative FINITE value of the scores' own dtype: fp32's
         # rounds to -inf in bf16, and a fully-masked row (a dummy padding
@@ -824,14 +849,23 @@ class GroupedQueryAttention(nn.Module):
     """Causal self-attention with ``num_heads`` query heads on
     ``num_kv_heads`` key/value heads (query head ``h`` reads KV head
     ``h // (num_heads / num_kv_heads)``), head size ``head_dim`` stated
-    (not ``embed_dim / num_heads``), no bias, no positional term: the
-    attention layer of ``nemotron_h``.  K and V are repeated to the query
-    heads and go through :func:`_attend` like every other attention here,
-    so the Mosaic kernels take them where ``_flash_route`` says so; the
-    causal mask is the dense additive triangle (no kernel skips blocks
-    yet: ROADMAP S6).  ``num_heads`` / ``num_kv_heads`` are the heads held
-    here: the shares of a tensor-parallel split each own whole KV groups,
-    and their ``out_proj`` outputs add up to the whole layer's."""
+    (not ``embed_dim / num_heads``), no bias.  K and V are repeated to the
+    query heads and go through :func:`_attend` like every other attention
+    here, so the Mosaic kernels take them where ``_flash_route`` says so.
+    ``num_heads`` / ``num_kv_heads`` are the heads held here: the shares of
+    a tensor-parallel split each own whole KV groups, and their
+    ``out_proj`` outputs add up to the whole layer's.
+
+    As ``nemotron_h`` has it (the defaults): no positional term, and the
+    causal mask is the dense additive triangle (:func:`causal_bias`).
+
+    ``banded``: the causal mask is a ``flash_attention.Band`` instead, over
+    the last ``window`` positions up to the query's own (0: all of them),
+    which the blockwise kernels make themselves in the blocks the band
+    cuts and skip where it hides a block: no ``(L, L)`` array exists.
+    ``rope``: a published ``rope_parameters`` group (``modules/rotary.
+    rope_table``); ``q`` and ``k`` are rotated over the whole head at
+    positions ``0 .. L - 1`` of the row, ``k`` before it is repeated."""
 
     embed_dim: int
     num_heads: int
@@ -839,6 +873,9 @@ class GroupedQueryAttention(nn.Module):
     head_dim: int
     dropout: float = 0.0
     use_flash: bool = True
+    banded: bool = False
+    window: int = 0
+    rope: Optional[dict] = None
 
     @nn.compact
     def __call__(self, x, key_padding_mask=None, train: bool = False):
@@ -846,7 +883,15 @@ class GroupedQueryAttention(nn.Module):
         H, KV, D = self.num_heads, self.num_kv_heads, self.head_dim
         if H % KV:
             raise ValueError(f"{H} query heads do not divide over {KV} KV heads")
-        bias = causal_bias(seq_len, x.dtype)
+        band = bias = None
+        if self.banded:
+            from unicore_tpu.ops.flash_attention import Band
+
+            band = Band(self.window or None)
+        elif self.window:
+            raise ValueError("a window needs the band (banded=True)")
+        else:
+            bias = causal_bias(seq_len, x.dtype)
         fused = _kernel_pins_layout(
             self, train, False, bias, bsz, seq_len, seq_len, D, x.dtype,
         )
@@ -858,11 +903,22 @@ class GroupedQueryAttention(nn.Module):
         (q,) = dense("q_proj", H * D, heads_out=(1, H))(x)
         (k,) = dense("k_proj", KV * D, heads_out=(1, KV))(x)
         (v,) = dense("v_proj", KV * D, heads_out=(1, KV))(x)
-        if H != KV:
-            k = jnp.repeat(k, H // KV, axis=1)
-            v = jnp.repeat(v, H // KV, axis=1)
-        o, _, _ = _attend(
-            self, q * D ** -0.5, k, v, key_padding_mask, bias,
-            self.dropout, train, False, self.use_flash,
-        )
+        if self.rope is not None:
+            from .rotary import apply_rotary, rope_table
+
+            table = rope_table(self.rope, D)
+            positions = jnp.arange(seq_len)
+            q = apply_rotary(q, positions, table=table)
+            k = apply_rotary(k, positions, table=table)
+        # the banded form's layout and kernels under a scope of their own
+        # (the default form's operations keep the paths they have)
+        with (jax.named_scope("band_attn") if self.banded
+              else contextlib.nullcontext()):
+            if H != KV:
+                k = jnp.repeat(k, H // KV, axis=1)
+                v = jnp.repeat(v, H // KV, axis=1)
+            o, _, _ = _attend(
+                self, q * D ** -0.5, k, v, key_padding_mask, bias,
+                self.dropout, train, False, self.use_flash, band=band,
+            )
         return dense("out_proj", self.embed_dim, heads_in=H)(o)

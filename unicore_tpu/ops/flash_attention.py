@@ -36,6 +36,13 @@ Capabilities (superset of the reference kernel's semantics):
   throughout and each query's first visited block holds a key it sees,
   outputs and gradients are bit for bit the unmapped call's
   (docs/performance.md, "The block map")
+- a BAND (:class:`Band`): a causal mask, with or without a sliding window,
+  stated by static numbers and no operand.  The kernels visit the blocks
+  the band leaves visible only (the block map of :func:`band_visible`,
+  made from the same numbers and the call's own block sizes); a block it
+  leaves wholly visible computes what an unmasked call computes, a partly
+  visible block makes its mask in VMEM from iotas of its own query and key
+  positions.  No ``(Lq, Lk)`` array exists, forward or backward
 
 Softmax statistics are fp32 regardless of input dtype; the p @ v matmul runs
 in the input dtype on the MXU with fp32 accumulation.
@@ -131,6 +138,7 @@ def block_map(visible) -> BlockMap:
     query block ``iq`` of group ``g`` holds a query that may see a key of
     key block ``ik``.  Host-side numpy: the map is a property of the mask,
     not of the data, so a jitted caller hands the kernels a constant."""
+    # lint: host-sync-in-jit; the mask's blocks are static numpy, not data
     visible = np.asarray(visible, bool)
     if visible.ndim != 3:
         raise KernelGeometryError(
@@ -160,6 +168,61 @@ def block_map(visible) -> BlockMap:
         return packed.astype(np.int32), counts.astype(np.int32)
 
     return BlockMap(*items(visible), *items(visible.transpose(0, 2, 1)))
+
+
+class Band(NamedTuple):
+    """A causal mask stated by numbers: query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window`` (positions counted from the row's start; the
+    query's own position counts among the ``window``); ``window`` None is
+    causal alone."""
+
+    window: Optional[int] = None
+
+    def width(self, length):
+        """The band's width in a row of ``length`` keys."""
+        return length if self.window is None else min(self.window, length)
+
+
+def _band_whole(lo, block_q, block_k, width):
+    """Whether the band leaves a ``(block_q, block_k)`` block wholly
+    visible, ``lo`` the difference of the block's first query and key
+    positions: ``i - j`` over the block runs from ``lo - (block_k - 1)`` to
+    ``lo + block_q - 1``, and both ends lie in ``[0, width)`` (``width``
+    None: no upper end).  On numpy blocks and on a kernel's scalars alike."""
+    whole = lo - (block_k - 1) >= 0
+    if width is not None:
+        whole &= lo + (block_q - 1) < width
+    return whole
+
+
+def band_visible(band, nq, nk, block_q, block_k):
+    """``(1, nq, nk)`` bool: the blocks of ``(block_q, block_k)`` in which
+    ``band`` leaves some (query, key) pair visible."""
+    width = band.width(nk * block_k)
+    lo = (np.arange(nq) * block_q)[:, None] - (np.arange(nk) * block_k)[None, :]
+    return ((lo + (block_q - 1) >= 0) & (lo - (block_k - 1) < width))[None]
+
+
+def _band_partly(band, iq, ik, shape):
+    """Inside a kernel: whether block ``(iq, ik)`` of ``shape = (BQ, BK)``
+    is only PARTLY visible under the band (a scalar: a wholly visible block
+    needs no mask, a wholly hidden one is not visited)."""
+    BQ, BK = shape
+    return jnp.logical_not(
+        _band_whole(iq * BQ - ik * BK, BQ, BK, band.window))
+
+
+def _band_mask(band, iq, ik, shape):
+    """Inside a kernel: which (query, key) pairs of block ``(iq, ik)`` the
+    band leaves visible, from iotas of the block's own positions."""
+    BQ, BK = shape
+    diff = (iq * BQ - ik * BK) + (
+        jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    visible = diff >= 0
+    if band.window is not None:
+        visible &= diff < band.window
+    return visible
 
 
 class _Walk(NamedTuple):
@@ -259,13 +322,22 @@ def _index_maps(B, at, kv_major):
     return qi, ki, maski, biasi
 
 
-def _step(walk, t, pre, body):
-    """Run a grid step's ``body``: always without a map, with one unless
-    the step is a row's dead item."""
-    if walk.live is None:
-        body()
-    else:
-        pl.when(walk.live(t, pre))(body)
+def _step(walk, t, pre, body, band=None, iq=None, ik=None, shape=None):
+    """Run a grid step's ``body(visible)``: always without a map, with one
+    unless the step is a row's dead item.  Under a ``band`` the body is
+    traced twice and one of the two runs: with the band's mask of the
+    block (``visible``) where the block is partly visible, without
+    (``visible`` None) where it is wholly visible."""
+    if band is None:
+        if walk.live is None:
+            body(None)
+        else:
+            pl.when(walk.live(t, pre))(lambda: body(None))
+        return
+    live = walk.live(t, pre)
+    partly = _band_partly(band, iq, ik, shape)
+    pl.when(live & partly)(lambda: body(_band_mask(band, iq, ik, shape)))
+    pl.when(live & jnp.logical_not(partly))(lambda: body(None))
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +349,7 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, bias_ref, mask_ref,
     o_ref, lse_ref,
     m_s, l_s, acc_s,
-    *, sm_scale, dropout_rate, walk, has_bias, has_mask,
+    *, sm_scale, dropout_rate, walk, has_bias, has_mask, band=None,
 ):
     b, h, iq, t = (pl.program_id(i) for i in range(4))
     b, iq, ik = walk.at(b, iq, t, pre)
@@ -288,7 +360,7 @@ def _fwd_kernel(
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def _visit():
+    def _visit(visible):
         q = q_ref[0, 0]  # (BQ, D)
         k = k_ref[0, 0]  # (BK, D)
         v = v_ref[0, 0]  # (BK, D)
@@ -299,6 +371,11 @@ def _fwd_kernel(
         s = s * sm_scale
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
+        if visible is not None:
+            # every query sees its own position, so no row of the band is
+            # empty over the blocks it visits: the hidden pairs' weights
+            # underflow to exact zeros once a visible key has been scored
+            s = jnp.where(visible, s, NEG_INF)
         if has_mask:
             kv_mask = mask_ref[0] != 0  # (1, BK) True = masked out
             s = jnp.where(kv_mask, NEG_INF, s)
@@ -328,7 +405,8 @@ def _fwd_kernel(
         m_s[...] = jnp.broadcast_to(m_next, m_s.shape)
         l_s[...] = jnp.broadcast_to(l_next, l_s.shape)
 
-    _step(walk, t, pre, _visit)
+    _step(walk, t, pre, _visit, band, iq, ik,
+          (q_ref.shape[2], k_ref.shape[2]))
 
     # a query block with no visit at all writes zeros, as a row whose keys
     # are all padding does
@@ -342,7 +420,7 @@ def _fwd_kernel(
 
 
 def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
-         block_k, block_map=None):
+         block_k, block_map=None, band=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     BQ, BK = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
@@ -395,6 +473,7 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
         walk=walk,
         has_bias=has_bias,
         has_mask=has_mask,
+        band=band,
     )
 
     def wrapped(*refs):
@@ -441,7 +520,7 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
 # ---------------------------------------------------------------------------
 
 def _recompute_p(q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale,
-                 has_bias, has_mask):
+                 has_bias, has_mask, visible=None):
     q = q_ref[0, 0]
     k = k_ref[0, 0]
     s = jax.lax.dot_general(
@@ -450,6 +529,8 @@ def _recompute_p(q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale,
     s = s * sm_scale
     if has_bias:
         s = s + bias_ref[0, 0].astype(jnp.float32)
+    if visible is not None:  # a band's partly visible block
+        s = jnp.where(visible, s, NEG_INF)
     kv_mask = None
     if has_mask:
         kv_mask = mask_ref[0] != 0  # (1, BK)
@@ -485,7 +566,7 @@ def _dq_kernel(
     q_ref, k_ref, v_ref, bias_ref, mask_ref, lse_ref, di_ref, do_ref,
     dq_ref,
     dq_s,
-    *, sm_scale, dropout_rate, walk, has_bias, has_mask,
+    *, sm_scale, dropout_rate, walk, has_bias, has_mask, band=None,
 ):
     b, h, iq, t = (pl.program_id(i) for i in range(4))
     b, iq, ik = walk.at(b, iq, t, pre)
@@ -494,10 +575,10 @@ def _dq_kernel(
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    def _visit():
+    def _visit(visible):
         p, kv_mask = _recompute_p(
             q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale, has_bias,
-            has_mask
+            has_mask, visible
         )
         ds = _ds_block(
             pre[0], p, kv_mask, do_ref, v_ref, di_ref, dropout_rate,
@@ -509,7 +590,8 @@ def _dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    _step(walk, t, pre, _visit)
+    _step(walk, t, pre, _visit, band, iq, ik,
+          (q_ref.shape[2], k_ref.shape[2]))
 
     @pl.when(walk.last(t, pre))
     def _finish():
@@ -521,7 +603,7 @@ def _dkv_kernel(
     q_ref, k_ref, v_ref, bias_ref, mask_ref, lse_ref, di_ref, do_ref,
     dk_ref, dv_ref,
     dk_s, dv_s,
-    *, sm_scale, dropout_rate, walk, has_bias, has_mask,
+    *, sm_scale, dropout_rate, walk, has_bias, has_mask, band=None,
 ):
     b, h, ik, t = (pl.program_id(i) for i in range(4))
     b, ik, iq = walk.at(b, ik, t, pre)
@@ -531,10 +613,10 @@ def _dkv_kernel(
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    def _visit():
+    def _visit(visible):
         p, kv_mask = _recompute_p(
             q_ref, k_ref, bias_ref, mask_ref, lse_ref, sm_scale, has_bias,
-            has_mask
+            has_mask, visible
         )
 
         # dv += dropout(p)^T @ do
@@ -560,7 +642,8 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    _step(walk, t, pre, _visit)
+    _step(walk, t, pre, _visit, band, iq, ik,
+          (q_ref.shape[2], k_ref.shape[2]))
 
     # a key block no query block visits still writes: zeros, not what the
     # output buffer held
@@ -651,7 +734,7 @@ def _make_ref_unpacker(has_bias, has_mask, n_outs, n_scratch):
 
 
 def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
-         block_k, out, lse, do, block_map=None):
+         block_k, out, lse, do, block_map=None, band=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     BQ, BK = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
@@ -710,7 +793,7 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
             kernel(
                 pre, *in_refs, *outs, *scratch,
                 sm_scale=sm_scale, dropout_rate=dropout_rate, walk=walk,
-                has_bias=has_bias, has_mask=has_mask,
+                has_bias=has_bias, has_mask=has_mask, band=band,
             )
 
         def outi(b, h, row, t, *pre):
@@ -855,30 +938,31 @@ def _bwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
 # public op with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def _flash(q, k, v, bias, kv_mask, seed, block_map, sm_scale, dropout_rate,
-           blocks):
+           blocks, band=None):
     out, _ = _fwd(
         q, k, v, bias, kv_mask, seed,
-        sm_scale, dropout_rate, blocks[0], blocks[1], block_map,
+        sm_scale, dropout_rate, blocks[0], blocks[1], block_map, band,
     )
     return out
 
 
 def _flash_fwd(q, k, v, bias, kv_mask, seed, block_map, sm_scale,
-               dropout_rate, blocks):
+               dropout_rate, blocks, band=None):
     out, lse = _fwd(
         q, k, v, bias, kv_mask, seed,
-        sm_scale, dropout_rate, blocks[0], blocks[1], block_map,
+        sm_scale, dropout_rate, blocks[0], blocks[1], block_map, band,
     )
     return out, (q, k, v, bias, kv_mask, seed, block_map, out, lse)
 
 
-def _flash_bwd(sm_scale, dropout_rate, blocks, residuals, do):
+def _flash_bwd(sm_scale, dropout_rate, blocks, band, residuals, do):
     q, k, v, bias, kv_mask, seed, block_map, out, lse = residuals
     dq, dk, dv, dbias = _bwd(
         q, k, v, bias, kv_mask, seed,
         sm_scale, dropout_rate, blocks[0], blocks[1], out, lse, do, block_map,
+        band,
     )
     return dq, dk, dv, dbias, None, None, None
 
@@ -916,6 +1000,7 @@ def flash_attention(
     block_q: int = 256,
     block_k: int = 512,
     block_map: Optional[BlockMap] = None,
+    band: Optional[Band] = None,
 ) -> jnp.ndarray:
     """Blockwise-online attention: softmax(q k^T * scale + bias, mask) v.
 
@@ -943,6 +1028,11 @@ def flash_attention(
             so the map must not drop a block that holds a visible key.  The
             bias is then a constant (no bias gradient: asking for one
             raises ``KernelGeometryError``).
+        band: a :class:`Band`: query ``i`` sees key ``j`` iff ``0 <= i - j
+            < window``, beside whatever bias and padding mask say.  The
+            call makes the band's own block map (:func:`band_block_map`),
+            so it takes no ``block_map`` beside it; a bias is a constant,
+            as under any map.
     """
     if bias is not None:
         if bias.ndim == 3:
@@ -962,6 +1052,14 @@ def flash_attention(
             raise KernelGeometryError(
                 f"bias heads {bias.shape[1]} must be 1 or {q.shape[1]}"
             )
+    if band is not None:
+        if block_map is not None:
+            raise KernelGeometryError(
+                "flash_attention takes a band or a block_map, not both: "
+                "the band brings the map of its own visible blocks"
+            )
+        block_map = band_block_map(
+            band, q.shape[2], k.shape[2], block_q, block_k)
     if block_map is not None:
         nq = q.shape[2] // _pick_block(q.shape[2], block_q)
         nk = k.shape[2] // _pick_block(k.shape[2], block_k)
@@ -984,8 +1082,28 @@ def flash_attention(
     return _flash(
         q, k, v, bias, kv_padding_mask, seed, block_map,
         # lint: host-sync-in-jit; dropout_rate is a static hyperparameter
-        sm_scale, float(dropout_rate), (block_q, block_k),
+        sm_scale, float(dropout_rate), (block_q, block_k), band,
     )
+
+
+@functools.lru_cache(maxsize=64)
+def band_block_map(band, q_len, k_len, block_q=256, block_k=512) -> BlockMap:
+    """The :class:`BlockMap` of ``band`` over ``(q_len, k_len)`` positions
+    at the block sizes a call with these arguments picks: the blocks in
+    which the band leaves some pair visible (:func:`band_visible`)."""
+    BQ, BK = _pick_block(q_len, block_q), _pick_block(k_len, block_k)
+    return block_map(band_visible(band, q_len // BQ, k_len // BK, BQ, BK))
+
+
+def band_counts(band, q_len, k_len, block_q=256, block_k=512):
+    """``(computed, visible)``: the (query, key) pairs one head of one row
+    scores under ``band`` (every pair of a block that the map a call with
+    these arguments builds, :func:`band_block_map`, visits) and the pairs
+    the band leaves visible."""
+    BQ, BK = _pick_block(q_len, block_q), _pick_block(k_len, block_k)
+    visits = band_block_map(band, q_len, k_len, block_q, block_k).kv_counts
+    seen = np.minimum(np.arange(q_len) + 1, band.width(k_len))
+    return int(visits.sum()) * BQ * BK, int(seen.sum())
 
 
 def mha_reference(q, k, v, bias=None, kv_padding_mask=None, sm_scale=1.0):
