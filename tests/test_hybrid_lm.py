@@ -386,6 +386,112 @@ def test_routed_experts_are_the_plain_sum_over_pairs(monkeypatch, tile, n, loads
         assert not any(a.any() for a in g_got)
 
 
+WIDE_LOADS = {
+    # W = 32 rows a wide trip, tiles of 8: an expert at 0, W - 1, W, W + 1,
+    # 2 W + TILE + 3, one a pair into its fourth tile, one that holds every
+    # token
+    "about-w": (0, 31, 32, 33, 75, 25, 200),
+    "whole-trips": (64, 0, 96, 32),      # no tile is left to the narrow loop
+    "none-fills-a-trip": (24, 8, 0, 17),  # two loops built, the wide one idle
+}
+
+
+@pytest.mark.parametrize("loads", list(WIDE_LOADS.values()), ids=list(WIDE_LOADS))
+@pytest.mark.parametrize("act", ["relu2", "silu_gate"])
+def test_wide_trips_are_the_loop_over_the_tiles(monkeypatch, act, loads):
+    """``routed_experts`` with wide trips against the loop over the tiles
+    alone: the value and all four cotangents (float32 sums in another
+    order), for both expert bodies.  Expert ``e``'s tiles go ``W / TILE`` at
+    a time as far as they fill whole wide trips, the rest one by one; the
+    layout's rows and tiles are what they were."""
+    from unicore_tpu.modules import latent_moe
+
+    tile, wide, n = 8, 32, 200
+    monkeypatch.setattr(latent_moe, "TILE", tile)
+    lat, f, Eh, top_k = 16, 24, len(loads), 4
+    ks = jax.random.split(jax.random.key(7), 5)
+    rng = np.random.default_rng(3)
+    pair = np.zeros((n, Eh), bool)
+    for e, l in enumerate(loads):
+        pair[rng.choice(n, l, replace=False), e] = True
+    pair = jnp.asarray(pair)
+    latent = jax.random.normal(ks[0], (n, lat))
+    w1 = 0.3 * jax.random.normal(
+        ks[1], (Eh, lat, 2 * f if act == "silu_gate" else f))
+    w2 = 0.3 * jax.random.normal(ks[2], (Eh, f, lat))
+    w_held = jnp.where(pair, jax.random.uniform(ks[3], (n, Eh), minval=0.1), 0.0)
+    g = jax.random.normal(ks[4], (n, lat))
+    rows = latent_moe.buffer_rows(n, top_k, Eh)
+    args = (latent, w_held, w1, w2)
+
+    def value_and_cotangents(wide):
+        routed = lambda *a: latent_moe.routed_experts(*a, rows, pair, act, wide)
+        return (routed(*args),) + jax.grad(
+            lambda *a: jnp.sum(routed(*a) * g), (0, 1, 2, 3))(*args)
+
+    for a, b in zip(value_and_cotangents(wide), value_and_cotangents(0)):
+        np.testing.assert_allclose(a, b, atol=2e-5 * max(1.0, float(jnp.abs(b).max())))
+
+    lay = latent_moe.buffer_layout(pair, w_held, rows, wide)
+    narrow = latent_moe.buffer_layout(pair, w_held, rows)
+    assert set(lay) - set(narrow) == {
+        "wide_start", "wide_expert", "wide_trips", "narrow_tile", "narrow_trips"}
+    for k, v in narrow.items():
+        np.testing.assert_array_equal(lay[k], v)
+    tiles = [-(-l // tile) for l in loads]
+    trips = [t // (wide // tile) for t in tiles]
+    assert int(lay["wide_trips"]) == sum(trips) <= rows // wide
+    assert int(lay["tiles_used"]) == sum(tiles)
+    assert int(lay["narrow_trips"]) == sum(tiles) - sum(trips) * (wide // tile)
+    # a wide trip's rows are whole tiles of the trip's expert, every tile in
+    # use is walked by exactly one of the two loops, and no tile out of use
+    seen, pairs_wide = [], 0
+    for j in range(sum(trips)):
+        first, e = int(lay["wide_start"][j]), int(lay["wide_expert"][j])
+        assert first % tile == 0
+        assert (lay["tile_expert"][first // tile:(first + wide) // tile] == e).all()
+        seen += range(first // tile, (first + wide) // tile)
+        pairs_wide += int(lay["valid"][first:first + wide].sum())
+    seen += [int(t) for t in lay["narrow_tile"][:int(lay["narrow_trips"])]]
+    assert sorted(seen) == list(range(sum(tiles)))
+
+    stats = dict(zip(STATS, latent_moe.route_stats(pair.sum(axis=0), wide)))
+    assert stats["rows_wide"] == pairs_wide == sum(
+        min(l, t * wide) for l, t in zip(loads, trips))
+    assert stats["tiles_used"] == sum(tiles) and stats["pairs_here"] == sum(loads)
+    assert dict(zip(STATS, latent_moe.route_stats(pair.sum(axis=0), 0)))[
+        "rows_wide"] == 0
+
+
+@pytest.mark.parametrize("layer", ["latent", "gated"])
+@pytest.mark.parametrize("n,loops", [(124, 1), (128, 2)], ids=["below", "at"])
+def test_the_even_load_decides_whether_wide_loops_are_built(
+        monkeypatch, layer, n, loops):
+    """``n`` tokens choosing 4 of 16 experts at ``WIDE`` = 32: an even load
+    of 31 traces the one loop over the tiles forward and one backward, of
+    32 a wide and a narrow loop each way, whatever the routing turns out
+    to be; the stats count the wide rows only where they are built."""
+    from unicore_tpu.modules import gated_moe, latent_moe
+
+    monkeypatch.setattr(latent_moe, "TILE", 8)
+    monkeypatch.setattr(latent_moe, "WIDE", 32)
+    assert latent_moe.wide_rows(n, 4, 16) == (32 if loops == 2 else 0)
+    if layer == "latent":
+        module = LatentMoE(32, n_held=4, first_held=4, **MOE)
+    else:
+        module = gated_moe.GatedMoE(32, expert_dim=24, n_routed=16, top_k=4,
+                                    n_held=4, first_held=4)
+    h = jax.random.normal(jax.random.key(2), (1, n, 32))
+    params = module.init(jax.random.key(1), h)
+    fwd = lambda p, h: module.apply(p, h)
+    both = jax.value_and_grad(lambda p, h: jnp.sum(fwd(p, h)[0] ** 2), (0, 1))
+    count = lambda fn: str(jax.make_jaxpr(fn)(params, h)).count("while[")
+    assert count(fwd) == loops and count(both) == 2 * loops
+    stats = dict(zip(STATS, fwd(params, h)[1]))
+    assert (stats["rows_wide"] > 0) == (loops == 2 and stats["load_max"] >= 32)
+    assert stats["rows_wide"] <= stats["pairs_here"] <= 4 * n
+
+
 def test_a_row_without_a_pair_reads_nothing_of_its_token(monkeypatch):
     """A tile's rows beyond its expert's load hold some token's index; what
     that token's row holds (here: not finite) reaches neither the output
@@ -501,10 +607,10 @@ def test_the_loss_states_what_a_capture_is_told_of_an_update():
 
     sums = {"loss": 9.0, "_n": 1.0, "moe_layers": 5.0, "moe_pairs_here": 2422.0,
             "moe_load_max": 1702.0, "moe_load_mean": 302.75,
-            "moe_tiles_used": 41.0}
+            "moe_tiles_used": 41.0, "moe_rows_wide": 1536.0}
     assert LMCrossEntropyLoss.trace_marks(sums) == {"moe_route": {
-        "pairs_here": 2422, "tiles_used": 41, "load_max": 340.4,
-        "load_mean": 60.55}}
+        "pairs_here": 2422, "tiles_used": 41, "rows_wide": 1536,
+        "load_max": 340.4, "load_mean": 60.55}}
     assert LMCrossEntropyLoss.trace_marks({"loss": 9.0, "_n": 1.0}) == {}
 
 
@@ -577,6 +683,7 @@ def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
     assert sums[-1]["moe_layers"] == 6 * 2
     assert sums[-1]["moe_pairs_here"] > 0
     assert sums[-1]["moe_tiles_used"] >= sums[-1]["moe_pairs_here"] / 128
+    assert sums[-1]["moe_rows_wide"] == 0
 
     # inside a profiler capture the loss's marks reach the trace: one
     # ``unicore:moe_route`` per update, three updates late, from that
@@ -598,8 +705,10 @@ def test_tiny_hybrid_trains_through_task_and_trainer(tmp_path):
              for s in spans if s[2] == "unicore:moe_route"]
     assert [m["update"] for m in marks] == [7, 8]
     for m in marks:  # 8 x 64 tokens, 2 expert layers, 8 of 16 experts held
-        assert set(m) == {"update", "pairs_here", "tiles_used", "load_max",
-                          "load_mean"}
+        assert set(m) == {"update", "pairs_here", "tiles_used", "rows_wide",
+                          "load_max", "load_mean"}
+        # an even load of 8 x 64 x 4 / 16 = 128 fills no wide trip: none built
+        assert m["rows_wide"] == 0
         assert 0 < m["pairs_here"] <= 2 * 8 * 64 * 4
         # a tile holds 128 rows of one expert: 16 (layer, expert) columns
         assert m["pairs_here"] / 128 <= m["tiles_used"] < m["pairs_here"] / 128 + 16

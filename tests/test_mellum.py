@@ -402,18 +402,27 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layers():
     np.testing.assert_allclose(x, want, atol=5e-5)
 
 
+@pytest.mark.parametrize("wide", [0, 32], ids=["tiles", "wide-trips"])
 @pytest.mark.parametrize("balancing", ["none", "batch_bias"])
-def test_tiny_model_is_the_plain_reference_loss_and_gradients(balancing):
+def test_tiny_model_is_the_plain_reference_loss_and_gradients(
+        monkeypatch, balancing, wide):
     """The tiny preset (two sliding layers and a full one) on seeded
     weights at ``L`` = 96, past the window (16) and the YaRN table's
     original context (32): loss and every gradient against
     ``benchmark/reference/mellum2_12b.py``, with the published choice of
-    experts and with the batch's bias."""
+    experts and with the batch's bias; through the loop over the tiles
+    (192 tokens on 2 of 8 experts are an even load of 48, under ``WIDE``)
+    and, with the tile at 8 and the wide trip at 32 rows, through the wide
+    and the narrow loop the benchmark's cell runs."""
     sys.path.insert(0, ROOT)
     from benchmark import weights
     from benchmark.reference import mellum2_12b as ref
     from unicore_tpu.losses.lm_cross_entropy import LMCrossEntropyLoss
+    from unicore_tpu.modules import latent_moe
 
+    if wide:
+        monkeypatch.setattr(latent_moe, "TILE", 8)
+        monkeypatch.setattr(latent_moe, "WIDE", wide)
     args, model = tiny_model(num_experts_held=4, first_expert_held=2,
                              router_balancing=balancing)
     assert model.pattern == "SRSRGR" and set(model.pattern) <= set(KINDS)
@@ -433,7 +442,14 @@ def test_tiny_model_is_the_plain_reference_loss_and_gradients(balancing):
     assert (jax.tree_util.tree_structure(shapes)
             == jax.tree_util.tree_structure(want_shapes))
     loss = LMCrossEntropyLoss(_Task())
-    got = jax.value_and_grad(lambda p: loss.forward(model, p, sample)[0])(params)
+    (value, log), grads = jax.value_and_grad(
+        lambda p: loss.forward(model, p, sample)[::2], has_aux=True)(params)
+    got = (value, grads)
+    # three expert layers: under the batch's bias every held expert makes a
+    # wide trip of its 48 pairs or so; with no rule at least one does
+    assert (log["moe_rows_wide"] > 0) == bool(wide)
+    if wide and balancing == "batch_bias":
+        assert log["moe_rows_wide"] == 3 * 4 * wide < log["moe_pairs_here"]
     with jax.default_matmul_precision("highest"):
         want = jax.value_and_grad(
             lambda p: ref.loss_sum(p, cfg, sample, 0))(params)

@@ -151,8 +151,10 @@ class LMCrossEntropyLoss(UnicoreLoss):
         if layers > 0:
             # an expert layer's routing, per layer and update
             # (modules/latent_moe.py): how uneven the held experts' loads
-            # are, and how many tiles of rows they fill
-            for key in ("moe_load_max", "moe_load_mean", "moe_tiles_used"):
+            # are, how many tiles of rows they fill, and how many of the
+            # pairs went through the loop's wide trips
+            for key in ("moe_load_max", "moe_load_mean", "moe_tiles_used",
+                        "moe_rows_wide"):
                 total = sum(log.get(key, 0) for log in logging_outputs)
                 metrics.log_scalar(key, total / layers, 1, round=2)
 
@@ -163,10 +165,13 @@ class LMCrossEntropyLoss(UnicoreLoss):
         model with routed experts one ``unicore:moe_route`` mark with the
         (token, held expert) pairs of all its expert layers, the tiles of
         ``latent_moe.TILE`` rows they filled (what dispatch and combine
-        moved, each way) and the most loaded held expert's and the mean
-        load, per layer; for a model with window-plus-summary attention
-        one ``unicore:eva_keys`` mark with the keys its kernel form scored
-        and the keys its queries could see; for a model whose attention
+        moved, each way), the pairs among them that went ``latent_moe.WIDE``
+        rows a trip (``rows_wide / pairs_here``: how often the wide loop
+        engages; 0 where the even load builds none) and the most loaded
+        held expert's and the mean load, per layer; for a model with
+        window-plus-summary attention one ``unicore:eva_keys`` mark with
+        the keys its kernel form scored and the keys its queries could see;
+        for a model whose attention
         runs under a band the kernels mask themselves one
         ``unicore:attn_band`` mark with, for its sliding-window and its
         full layers apart (two maps: none of its stats is named
@@ -179,6 +184,7 @@ class LMCrossEntropyLoss(UnicoreLoss):
             marks["moe_route"] = dict(
                 pairs_here=int(sums["moe_pairs_here"]),
                 tiles_used=int(sums["moe_tiles_used"]),
+                rows_wide=int(sums["moe_rows_wide"]),
                 load_max=sums["moe_load_max"] / layers,
                 load_mean=sums["moe_load_mean"] / layers,
             )

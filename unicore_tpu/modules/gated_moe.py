@@ -15,7 +15,15 @@ use and its written-out backward walks them again
 (:func:`~.latent_moe.routed_experts` with ``act="silu_gate"``:
 ``experts_fc1`` holds ``[W_gate | W_up]`` side by side, the gate's
 columns first, ``experts_fc2`` is ``W_down``); dropless, no capacity
-factor.  The layer holds experts ``first_held .. first_held + n_held - 1``,
+factor.  Where the even load ``n * top_k / n_routed`` fills them
+(:func:`~.latent_moe.wide_rows`, from shapes as the layer is traced) an
+expert's tiles go ``latent_moe.WIDE`` rows at a time as far as they fill
+whole wide trips, and only the tiles that leaves go ``TILE`` rows a trip:
+an expert's kernels (12 MB at the published widths) are read, and its
+float32 ``dw`` rewritten, once a wide trip.  What is sized by the worst
+case stays the layout's index arrays alone.
+
+The layer holds experts ``first_held .. first_held + n_held - 1``,
 routes over all ``n_routed`` and computes its own experts' part; what the
 absent experts would add is left out, as on one chip of an expert-parallel
 deployment before the exchange, so the shares' results add up to the uncut
@@ -50,7 +58,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from .latent_moe import (
-    STATS, buffer_rows, routed_experts, tiles_of, top_k_set,
+    STATS, buffer_rows, route_stats, routed_experts, top_k_set, wide_rows,
 )
 
 _init = nn.initializers.normal(0.02)
@@ -169,13 +177,11 @@ class GatedMoE(nn.Module):
             w2 = self.param("experts_fc2", _init,
                             (Eh, self.expert_dim, d),
                             jnp.float32).astype(dtype)
+            wide = wide_rows(n, self.top_k, E)
             routed = routed_experts(
                 tokens, w_held, w1, w2, buffer_rows(n, self.top_k, Eh), pair,
-                "silu_gate",
+                "silu_gate", wide,
             )
-            stats = jnp.stack([
-                load.sum(), load.max(), load.astype(f32).mean(), 1,
-                tiles_of(load).sum(),
-            ]).astype(f32)
+            stats = route_stats(load, wide)
             y = checkpoint_name(routed.astype(dtype), "moe_routed_sum")
         return y.reshape(B, S, d), stats

@@ -48,15 +48,40 @@ tile's expert and adds the weighted result at their tokens; the backward
 ``custom_vjp``) walks the same tiles with the cotangents.  So the products
 AND the rows moved follow the pairs there are, however unevenly they fall.
 
+A trip reads its expert's kernels whole (forward once, backward twice more)
+and, backward, reads and rewrites the expert's float32 ``dw`` accumulators,
+whatever rows it carries: at ``TILE`` rows that traffic, not the products,
+is a trip's time once an expert's kernels are megabytes (PERF.md, PR 41).
+So where an expert's load fills them the loops take **wide trips** of
+:data:`WIDE` rows, a whole multiple of ``TILE``, all of one expert: expert
+``e``'s tiles go ``WIDE / TILE`` at a time as far as they fill whole wide
+trips, and the tiles that leaves it (fewer than ``WIDE / TILE``) go through
+the loop over the tiles as before.  A wide trip's rows are whole tiles of
+the layout, so no row is padded beyond its expert's last tile, which
+``TILE`` alone would pad as well: an expert a few pairs short of a whole
+multiple of ``WIDE`` still goes wide to its last row.  The layout is the
+same; it hands the loops two more small index arrays and two trip counts.
+The body is the same at another row count (bfloat16 operands, float32
+accumulators and activation, rows without a pair zeroed by their flag; a
+wide trip's ``x.T @ dpre`` sums its rows in one product where the tiles'
+were added one by one: float32 rounding in another order).  Whether the
+wide loops are built is decided when the layer is traced, from shapes
+alone (:func:`wide_rows`): where the EVEN load ``n * top_k / n_routed``,
+which a balanced router hands every expert, is at least ``WIDE``.  Below
+it the function traces the one loop over the tiles, forward and backward.
+``rows_wide`` of :data:`STATS` says how many pairs went wide.
+
 What is sized by the worst case (:func:`buffer_rows`: every token on every
 held expert it can choose, which no routing can exceed) is the layout's
-index arrays alone: a token, a weight and a flag per row.  That is why the
+index arrays alone: a token, a weight and a flag per row, an expert per
+tile, and with wide trips a row and an expert per ``WIDE`` rows and a tile
+per tile.  That is why the
 layer is dropless by construction and has no bound to set.  No array of
 that many rows of activations, and none of (tokens x held experts x
 latent), is built, forward or backward (``tests/test_hybrid_lm.py``
 searches the compiled step for one).
 
-The layout, the tile loop and its written-out backward are shared with
+The layout, the loops and their written-out backward are shared with
 ``modules/gated_moe.py``: :func:`routed_experts` takes the expert's
 activation by name (:data:`ACTS`; ``relu2`` here, ``silu_gate`` for a
 gated three-matrix expert whose first kernel holds ``[gate | up]``), and
@@ -81,11 +106,23 @@ from unicore_tpu.quant.dense import QuantDense
 _init = nn.initializers.normal(0.02)
 
 #: what :meth:`LatentMoE.__call__` returns beside ``y``, in this order
-STATS = ("pairs_here", "load_max", "load_mean", "layers", "tiles_used")
+#: (:func:`route_stats`): the (token, held expert) pairs, the most loaded
+#: held expert's and the mean load, 1 (so that sums over layers count them),
+#: the layout's tiles of ``TILE`` rows in use (wide trips or not), and the
+#: pairs in the rows of wide trips (0 where no wide loop is built)
+STATS = ("pairs_here", "load_max", "load_mean", "layers", "tiles_used",
+         "rows_wide")
 
 
 #: rows per tile of the grouped products (the MXU's 128 rows)
 TILE = 128
+
+#: rows per wide trip of the grouped products, a whole multiple of ``TILE``:
+#: what an expert's kernels are read, and its ``dw`` accumulators rewritten,
+#: once for (:func:`wide_rows` says where the loops are built).  Chosen on the
+#: chip among 256, 512 and 1,024 by the rate of the cell that fills them
+#: (PERF.md, PR 41: 41,343 / 44,878 / 46,896 tokens/s against 35,066)
+WIDE = 1024
 
 #: the arrays :meth:`LatentMoE.__call__` names (``checkpoint_name``) for a
 #: rematerializing caller to keep across the forward pass: cheap to hold,
@@ -145,6 +182,19 @@ def tiles_of(load):
     return (load + TILE - 1) // TILE
 
 
+def route_stats(load, wide):
+    """:data:`STATS` of one layer whose held experts got ``load`` (Eh,)
+    pairs, its loops built with wide trips of ``wide`` rows (or 0)."""
+    tiles = tiles_of(load)
+    # the pairs in an expert's wide trips: all of them, or its trips' rows
+    rows_wide = jnp.minimum(
+        load, tiles // (wide // TILE) * wide).sum() if wide else 0
+    return jnp.stack([
+        load.sum(), load.max(), load.astype(jnp.float32).mean(), 1,
+        tiles.sum(), rows_wide,
+    ]).astype(jnp.float32)
+
+
 def buffer_rows(n, top_k, n_held):
     """Rows the held experts share for ``n`` tokens, in whole tiles: every
     token on every held expert it can choose, and each expert's last tile
@@ -167,18 +217,37 @@ def top_k_set(x, k):
     return idx, (x > kth) | ((x == kth) & (along <= last))
 
 
-def buffer_layout(pair, w_held, rows):
+def wide_rows(n, top_k, n_routed):
+    """Rows of a wide trip for ``n`` tokens that choose ``top_k`` of
+    ``n_routed`` experts each: :data:`WIDE` where the even load ``n *
+    top_k / n_routed`` fills one, else 0 (no wide loop is built).  Static:
+    from shapes alone, the load a balanced router hands every expert."""
+    return WIDE if n * top_k >= WIDE * n_routed else 0
+
+
+def buffer_layout(pair, w_held, rows, wide=0):
     """Where each pair sits.  ``pair`` (n, Eh) bool; ``w_held`` (n, Eh) the
     pairs' weights; ``rows`` the layout's length (:func:`buffer_rows`, the
-    worst case: these index arrays are all that is sized by it).  Expert
-    ``e``'s pairs take rows ``start_e .. start_e + load_e - 1`` in token
-    order, ``start_e`` the tile-aligned end of expert ``e - 1``'s.  Returns
+    worst case: these index arrays are all that is sized by it); ``wide``
+    the rows of a wide trip (:func:`wide_rows`; a whole multiple of
+    ``TILE``, or 0).  Expert ``e``'s pairs take rows ``start_e .. start_e +
+    load_e - 1`` in token order, ``start_e`` the tile-aligned end of expert
+    ``e - 1``'s.  Returns
 
     * ``token_of_row`` (rows,), ``weight_of_row`` (rows,), ``valid`` (rows,):
       a row of a tile in use that holds no pair has ``valid`` false, weight
       zero and some token's index that is in bounds;
     * ``tile_expert`` (rows / TILE,), ``tiles_used`` (scalar): the rows the
-      loops read are those of the first ``tiles_used`` tiles.
+      loops read are those of the first ``tiles_used`` tiles;
+    * with ``wide``, which of those tiles go ``wide`` rows at a time: expert
+      ``e`` makes ``tiles_e // (wide / TILE)`` wide trips over its first
+      tiles (its last tile, partly empty, may be among them: ``valid``
+      says) and the tiles that leaves it, fewer than ``wide / TILE``, go
+      through the narrow loop.  ``wide_start`` and ``wide_expert``
+      (rows / wide,): the first row and the expert of each wide trip, and
+      ``wide_trips`` (scalar) how many there are; ``narrow_tile``
+      (rows / TILE,) and ``narrow_trips``: the tiles left, in order.
+      Cumulative sums over the ``Eh`` loads.
 
     No gather and no search: each expert's tokens come out of one stable
     sort of its column (chosen tokens first, in token order, their weights
@@ -187,13 +256,12 @@ def buffer_layout(pair, w_held, rows):
     (the arrays are ``n`` rows longer than ``rows`` while they are
     written, so the last expert's column always fits)."""
     n, Eh = pair.shape
-    tiles = tiles_of(pair.sum(axis=0, dtype=jnp.int32))
+    load = pair.sum(axis=0, dtype=jnp.int32)
+    tiles = tiles_of(load)
     ends = jnp.cumsum(tiles)                                 # in tiles
     start = (ends - tiles) * TILE                            # in rows
     n_tiles = rows // TILE
-    tile_expert = jnp.minimum(
-        jnp.sum(jnp.arange(n_tiles)[:, None] >= ends[None, :], axis=1), Eh - 1
-    ).astype(jnp.int32)
+    tile_expert = _trip_expert(n_tiles, ends)
     unchosen, tokens, weights = jax.lax.sort(
         ((~pair).astype(jnp.int32),
          jax.lax.broadcasted_iota(jnp.int32, (n, Eh), 0),
@@ -210,65 +278,119 @@ def buffer_layout(pair, w_held, rows):
         weight_of_row = jax.lax.dynamic_update_slice(
             weight_of_row, weights[:, e], at)
         valid = jax.lax.dynamic_update_slice(valid, unchosen[:, e] == 0, at)
-    return dict(
+    lay = dict(
         token_of_row=token_of_row[:rows], weight_of_row=weight_of_row[:rows],
         valid=valid[:rows], tile_expert=tile_expert,
         tiles_used=ends[-1].astype(jnp.int32),
     )
+    if wide:
+        per = wide // TILE
+        trips = tiles // per
+        lay["wide_start"], lay["wide_expert"], lay["wide_trips"] = _places(
+            trips, start, wide, rows // wide)
+        lay["narrow_tile"], _, lay["narrow_trips"] = _places(
+            tiles - trips * per, start // TILE + trips * per, 1, n_tiles)
+    return lay
 
 
-def _tile_of(lay, t):
-    """Tile ``t``'s ``TILE`` entries of the layout's row arrays."""
-    cut = lambda a: jax.lax.dynamic_slice(a, (t * TILE,), (TILE,))
+def _trip_expert(n_trips, ends):
+    """The expert of each of ``n_trips`` trips, where expert ``e``'s end
+    before trip ``ends[e]``; the last expert's beyond the trips in use."""
+    return jnp.minimum(
+        jnp.sum(jnp.arange(n_trips)[:, None] >= ends[None, :], axis=1),
+        ends.shape[0] - 1,
+    ).astype(jnp.int32)
+
+
+def _places(count, first, stride, n_trips):
+    """Expert ``e`` makes ``count[e]`` trips, at ``first[e]``, ``first[e] +
+    stride``, ...; the experts' trips in a row.  Of each of ``n_trips``
+    trips (the worst case) its place and its expert, and how many are in
+    use; what lies beyond those is not read.  A sum under a mask over the
+    experts, no gather."""
+    ends = jnp.cumsum(count)
+    expert = _trip_expert(n_trips, ends)
+    # trip j of expert e is the expert's (j - the trips before e)-th
+    base = first - (ends - count) * stride
+    held = jnp.arange(count.shape[0], dtype=jnp.int32)
+    place = jnp.sum(jnp.where(expert[:, None] == held[None, :],
+                              base[None, :], 0), axis=1, dtype=jnp.int32)
+    return (place + jnp.arange(n_trips, dtype=jnp.int32) * stride, expert,
+            ends[-1])
+
+
+def _rows_at(lay, first, size):
+    """``size`` entries of the layout's row arrays from row ``first``."""
+    cut = lambda a: jax.lax.dynamic_slice(a, (first,), (size,))
     return (cut(lay["token_of_row"]), cut(lay["weight_of_row"]),
             cut(lay["valid"]))
 
 
-def _tile_rows(table, token, valid):
-    """``table``'s rows at a tile's tokens; zeros where the row holds no
+def _trip_rows(table, token, valid):
+    """``table``'s rows at a trip's tokens; zeros where the row holds no
     pair, whatever the token's row holds (never a ``0 x inf``)."""
     return jnp.where(valid[:, None], table[token], 0)
 
 
-def _grouped_ffn(latent, w1, w2, lay, act="relu2"):
+def _trips(lay, wide, trip, carry):
+    """``trip(e, first, size, carry)`` over the rows of the first
+    ``tiles_used`` tiles of ``lay``: one loop over the tiles, or with
+    ``wide`` one over the wide trips and one over the tiles they leave
+    (under scopes of their own, so that a trace tells them apart).  Trip
+    counts from the data."""
+    tile = lambda t, c: trip(lay["tile_expert"][t], t * TILE, TILE, c)
+    if not wide:
+        return jax.lax.fori_loop(0, lay["tiles_used"], tile, carry)
+    with jax.named_scope("wide_trips"):
+        carry = jax.lax.fori_loop(
+            0, lay["wide_trips"],
+            lambda j, c: trip(lay["wide_expert"][j], lay["wide_start"][j],
+                              wide, c),
+            carry)
+    with jax.named_scope("narrow_trips"):
+        return jax.lax.fori_loop(
+            0, lay["narrow_trips"],
+            lambda j, c: tile(lay["narrow_tile"][j], c), carry)
+
+
+def _grouped_ffn(latent, w1, w2, lay, act="relu2", wide=0):
     """``sum_e weight * act(latent W1_e) W2_e`` over the pairs of the
     first ``tiles_used`` tiles of ``lay`` (:func:`buffer_layout`), (n, lat)
-    float32.  Each trip gathers its tile's ``TILE`` rows of ``latent``,
-    runs them through its expert and adds the weighted result at their
-    tokens.  A loop with a trip count from the data: only forward (the
-    backward is :func:`_grouped_ffn_bwd`)."""
+    float32.  Each trip gathers its ``TILE`` (or ``wide``) rows of
+    ``latent``, runs them through its expert and adds the weighted result
+    at their tokens.  Loops with trip counts from the data
+    (:func:`_trips`): only forward (the backward is
+    :func:`_grouped_ffn_bwd`)."""
     f32 = jnp.float32
     act_fn = ACTS[act][0]
 
-    def body(t, out):
-        e = lay["tile_expert"][t]
-        token, weight, valid = _tile_of(lay, t)
-        x_t = _tile_rows(latent, token, valid)
+    def trip(e, first, size, out):
+        token, weight, valid = _rows_at(lay, first, size)
+        x_t = _trip_rows(latent, token, valid)
         h = act_fn(jnp.dot(x_t, w1[e], preferred_element_type=f32))
         y_t = jnp.dot(h.astype(latent.dtype), w2[e],
                       preferred_element_type=f32)
         # a row without a pair adds an exact zero (x_t and its weight are)
         return out.at[token].add(weight[:, None] * y_t)
 
-    return jax.lax.fori_loop(
-        0, lay["tiles_used"], body, jnp.zeros(latent.shape, f32)
-    )
+    return _trips(lay, wide, trip, jnp.zeros(latent.shape, f32))
 
 
-def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2"):
+def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2", wide=0):
     """Cotangents of :func:`_grouped_ffn` for ``d_out`` (n, lat) float32:
     ``d_latent`` (n, lat), the pairs' weights' (Eh, n), ``dw1``, ``dw2``,
-    all float32.  The hidden states are computed again tile by tile."""
+    all float32.  The hidden states are computed again trip by trip; a
+    wide trip reads its expert's kernels, and adds to the expert's
+    ``dw1``, ``dw2``, once for ``wide`` rows."""
     dtype = latent.dtype
     f32 = jnp.float32
     act_vjp = ACTS[act][1]
 
-    def body(t, carry):
+    def trip(e, first, size, carry):
         dx, dweight, dw1, dw2 = carry
-        e = lay["tile_expert"][t]
-        token, weight, valid = _tile_of(lay, t)
-        x_t = _tile_rows(latent, token, valid)
-        d_t = _tile_rows(d_out, token, valid)
+        token, weight, valid = _rows_at(lay, first, size)
+        x_t = _trip_rows(latent, token, valid)
+        d_t = _trip_rows(d_out, token, valid)
         h, act_bwd = act_vjp(
             jnp.dot(x_t, w1[e], preferred_element_type=f32))
         h = h.astype(dtype)
@@ -284,36 +406,36 @@ def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2"):
         dweight = dweight.at[e, token].add(jnp.sum(y_t * d_t, axis=-1))
         return dx.at[token].add(dx_t), dweight, dw1, dw2
 
-    return jax.lax.fori_loop(
-        0, lay["tiles_used"], body,
-        (jnp.zeros(latent.shape, f32),
-         jnp.zeros((w1.shape[0], latent.shape[0]), f32),
-         jnp.zeros(w1.shape, f32), jnp.zeros(w2.shape, f32)),
-    )
+    return _trips(lay, wide, trip, (
+        jnp.zeros(latent.shape, f32),
+        jnp.zeros((w1.shape[0], latent.shape[0]), f32),
+        jnp.zeros(w1.shape, f32), jnp.zeros(w2.shape, f32)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6))
-def routed_experts(latent, w_held, w1, w2, rows, pair, act="relu2"):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6, 7))
+def routed_experts(latent, w_held, w1, w2, rows, pair, act="relu2", wide=0):
     """``sum_e w_held[n, e] * W2_e act(W1_e latent[n])`` over the pairs
     ``pair`` marks.  ``latent`` (n, lat); ``w_held`` (n, Eh) float32, zero
     off the pairs; ``w1`` (Eh, lat, f), ``w2`` (Eh, f, lat) (``act``
     ``silu_gate``: ``w1`` (Eh, lat, 2 f), :data:`ACTS`); ``rows`` static,
     the layout's length (:func:`buffer_rows`); ``pair`` (n, Eh) bool, not
-    differentiated.  Returns (n, lat) float32."""
-    return _routed_fwd(latent, w_held, w1, w2, rows, pair, act)[0]
+    differentiated; ``wide`` static, the rows of a wide trip
+    (:func:`wide_rows`) or 0 for the loop over the tiles alone.  Returns
+    (n, lat) float32."""
+    return _routed_fwd(latent, w_held, w1, w2, rows, pair, act, wide)[0]
 
 
-def _routed_fwd(latent, w_held, w1, w2, rows, pair, act="relu2"):
+def _routed_fwd(latent, w_held, w1, w2, rows, pair, act="relu2", wide=0):
     lay = {k: checkpoint_name(v, "moe_layout")
-           for k, v in buffer_layout(pair, w_held, rows).items()}
-    return _grouped_ffn(latent, w1, w2, lay, act), (latent, w1, w2, lay)
+           for k, v in buffer_layout(pair, w_held, rows, wide).items()}
+    return _grouped_ffn(latent, w1, w2, lay, act, wide), (latent, w1, w2, lay)
 
 
-def _routed_bwd(rows, act, residuals, d_out):
+def _routed_bwd(rows, act, wide, residuals, d_out):
     latent, w1, w2, lay = residuals
     d_out = d_out.astype(jnp.float32)
     d_latent, dweight, dw1, dw2 = _grouped_ffn_bwd(
-        latent, d_out, w1, w2, lay, act)
+        latent, d_out, w1, w2, lay, act, wide)
     return (d_latent.astype(latent.dtype), dweight.T, dw1.astype(w1.dtype),
             dw2.astype(w2.dtype), None)
 
@@ -395,11 +517,10 @@ class LatentMoE(nn.Module):
             w2 = self.param("experts_fc2", _init,
                             (Eh, self.expert_dim, self.latent_dim),
                             jnp.float32).astype(dtype)
-            routed = routed_experts(latent, w_held, w1, w2, rows, pair)
-            stats = jnp.stack([
-                load.sum(), load.max(), load.astype(f32).mean(), 1,
-                tiles_of(load).sum(),
-            ]).astype(f32)
+            wide = wide_rows(n, self.top_k, E)
+            routed = routed_experts(latent, w_held, w1, w2, rows, pair,
+                                    "relu2", wide)
+            stats = route_stats(load, wide)
             routed = checkpoint_name(routed.astype(dtype), "moe_routed_sum")
 
         with jax.named_scope("moe_latent"):
