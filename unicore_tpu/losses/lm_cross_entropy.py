@@ -177,7 +177,10 @@ class LMCrossEntropyLoss(UnicoreLoss):
         full layers apart (two maps: none of its stats is named
         ``keys_computed``, which a reader takes for the pairs of ONE mapped
         call), the pairs the kernels scored and the pairs a query could
-        see, per row and head, summed over the layers of each kind."""
+        see, per row and head, summed over the layers of each kind; and,
+        where the model states them (its two kinds of layer hold different
+        numbers of query heads: ``models/laguna.py``), ``window_heads`` and
+        ``full_heads``, the query heads held on a layer of each kind."""
         marks = {}
         layers = sums.get("moe_layers", 0)
         if layers:
@@ -204,7 +207,9 @@ class LMCrossEntropyLoss(UnicoreLoss):
             marks["attn_band"] = {
                 f"{kind}_{stat}": int(sums[f"band_{kind}_{stat}"] / rows)
                 for kind in ("window", "full")
-                for stat in ("keys_computed", "keys_visible", "layers")
+                for stat in ("keys_computed", "keys_visible", "layers",
+                             "heads")
+                if f"band_{kind}_{stat}" in sums
             }
         return marks
 

@@ -1,11 +1,13 @@
 """Routed gated experts, as a layer that is TOLD which experts it holds:
 a softmax router over all ``n_routed`` experts, ``top_k`` a token with the
 chosen scores renormalised, each expert a gated three-matrix feed-forward
-layer at the model's width; no latent, no shared expert.
+layer at the model's width; no latent; a shared expert where
+``shared_dim`` states one.
 
     p = softmax(h W_r)                      float32, over ALL n_routed
-    chosen = the top_k largest of p;   w_e = p_e / sum_{chosen} p
+    chosen = the top_k largest of p;   w_e = routed_scale p_e / sum_{chosen} p
     y = sum_{e chosen and held} w_e W_down,e (silu(W_gate,e h) * (W_up,e h))
+        + W_down,s (silu(W_gate,s h) * (W_up,s h))       where shared_dim > 0
 
 It is ``modules/latent_moe.py``'s machinery with another body: the chosen
 scores are read where they lie (:func:`~.latent_moe.top_k_set`, no gather),
@@ -22,6 +24,12 @@ whole wide trips, and only the tiles that leaves go ``TILE`` rows a trip:
 an expert's kernels (12 MB at the published widths) are read, and its
 float32 ``dw`` rewritten, once a wide trip.  What is sized by the worst
 case stays the layout's index arrays alone.
+
+The shared expert is the same gated body at width ``shared_dim``, every
+token through it, unweighted: ``shared_fc1`` holds ``[W_gate | W_up]``,
+``shared_fc2`` ``W_down``, under the scope ``moe_shared`` with its result
+named ``moe_shared_out``, as ``latent_moe.py`` has them.  Every share of a
+layer computes it alike: summed over the shares it counts ONCE.
 
 The layer holds experts ``first_held .. first_held + n_held - 1``,
 routes over all ``n_routed`` and computes its own experts' part; what the
@@ -48,8 +56,8 @@ on seeded weights and while training draws the hidden states together.
 
 It returns the same ``STATS`` and names the same arrays for a
 rematerializing caller (``latent_moe.KEPT``: the router's product,
-``top_k``'s indices and set, the layout, and the routed sum, here the
-layer's own result).
+``top_k``'s indices and set, the layout, the routed sum and the shared
+expert's result).
 """
 
 import flax.linen as nn
@@ -57,8 +65,10 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from .gated_mlp import _Kernel
 from .latent_moe import (
-    STATS, buffer_rows, route_stats, routed_experts, top_k_set, wide_rows,
+    STATS, buffer_rows, route_stats, routed_experts, silu_gate, top_k_set,
+    wide_rows,
 )
 
 _init = nn.initializers.normal(0.02)
@@ -99,18 +109,26 @@ def balanced_scores(logits, k, rounds=BIAS_ROUNDS, gain=BIAS_GAIN):
     scores lie about the k-th place, and with ``u``'s spread never under
     ``NOISE`` the gain stays under one, whether the logits are spread like
     a seeded router's or, a few updates later, nearly the same for most
-    tokens (without it the rounds swing between all and nothing there)."""
+    tokens (without it the rounds swing between all and nothing there).
+
+    The rounds are ONE traced body (``lax.fori_loop``), so a step program
+    has one ``top_k`` a layer for them and not ``rounds``: at 256 experts
+    each copy costs the TPU's compiler about a second a layer (``laguna``'s
+    step compiles in 60 s with the loop and 100 s with the copies, for a
+    described v5e)."""
     n, E = logits.shape
     share = n * k / E
     mean = jnp.mean(logits, axis=0)
     spread = jnp.sqrt(jnp.mean(jnp.square(logits - mean), axis=0))
     u = (logits - mean) / (spread + 1e-6) + NOISE * noise_table(n, E)
-    b = jnp.zeros((E,), logits.dtype)
-    for _ in range(rounds):
+
+    def round_(_, b):
         _, sel = top_k_set(u + b, k)
         c = jnp.sum(sel, axis=0).astype(logits.dtype)
-        b = b - gain * jnp.log((c + 1.0) / (share + 1.0))
-    return u + b
+        return b - gain * jnp.log((c + 1.0) / (share + 1.0))
+
+    return u + jax.lax.fori_loop(
+        0, rounds, round_, jnp.zeros((E,), logits.dtype))
 
 
 class GatedMoE(nn.Module):
@@ -122,6 +140,8 @@ class GatedMoE(nn.Module):
     first_held: int = 0
     norm_topk_prob: bool = True
     balancing: str = "none"   # of BALANCINGS
+    routed_scale: float = 1.0  # on the weights, after they are renormalised
+    shared_dim: int = 0       # 0: no shared expert
 
     @nn.compact
     def __call__(self, h):
@@ -168,6 +188,8 @@ class GatedMoE(nn.Module):
             if self.norm_topk_prob:
                 w_held = w_held / jnp.sum(
                     jnp.where(sel, p, 0.0), axis=-1, keepdims=True)
+            if self.routed_scale != 1.0:
+                w_held = w_held * self.routed_scale
             load = pair.sum(axis=0)                                 # (Eh,)
 
         with jax.named_scope("moe_routed"):
@@ -184,4 +206,17 @@ class GatedMoE(nn.Module):
             )
             stats = route_stats(load, wide)
             y = checkpoint_name(routed.astype(dtype), "moe_routed_sum")
+
+        if self.shared_dim:
+            with jax.named_scope("moe_shared"):
+                s1 = _Kernel((d, 2 * self.shared_dim), name="shared_fc1")()
+                s2 = _Kernel((self.shared_dim, d), name="shared_fc2")()
+                # the gate's activation and its product with ``up`` in
+                # float32, rounded once (as modules/gated_mlp.py)
+                with jax.named_scope("shared_fc1"):
+                    mid = silu_gate(jnp.dot(
+                        tokens, s1.astype(dtype)).astype(f32)).astype(dtype)
+                with jax.named_scope("shared_fc2"):
+                    y = y + checkpoint_name(
+                        jnp.dot(mid, s2.astype(dtype)), "moe_shared_out")
         return y.reshape(B, S, d), stats

@@ -33,6 +33,8 @@ from unicore_tpu.ops.softmax_dropout import softmax_dropout
 from unicore_tpu.platform_utils import on_tpu
 from unicore_tpu.quant.dense import QuantDense
 
+from .gated_mlp import _Kernel
+
 logger = logging.getLogger(__name__)
 
 _warned_fallbacks = set()
@@ -845,6 +847,18 @@ def causal_bias(length, dtype):
     return jnp.where(col > row, -1e30, 0.0).astype(dtype)
 
 
+def head_gates(x, w_g):
+    """``sigmoid(x W_g)``: ``x`` (B, L, E), ``w_g`` (E, H) float32; one gate
+    a head and token, (B, L, H) float32, as a router's scores are
+    (``modules/gated_moe.py``): bfloat16 operands multiply exactly into the
+    float32 accumulator, float32 ones take the full-precision product."""
+    return jax.nn.sigmoid(jnp.dot(
+        x, w_g.astype(x.dtype), preferred_element_type=jnp.float32,
+        precision=None if x.dtype == jnp.bfloat16
+        else jax.lax.Precision.HIGHEST,
+    ))
+
+
 class GroupedQueryAttention(nn.Module):
     """Causal self-attention with ``num_heads`` query heads on
     ``num_kv_heads`` key/value heads (query head ``h`` reads KV head
@@ -864,8 +878,14 @@ class GroupedQueryAttention(nn.Module):
     which the blockwise kernels make themselves in the blocks the band
     cuts and skip where it hides a block: no ``(L, L)`` array exists.
     ``rope``: a published ``rope_parameters`` group (``modules/rotary.
-    rope_table``); ``q`` and ``k`` are rotated over the whole head at
-    positions ``0 .. L - 1`` of the row, ``k`` before it is repeated."""
+    rope_table``); ``q`` and ``k`` are rotated at positions ``0 .. L - 1``
+    of the row, ``k`` before it is repeated: over the whole head, or over
+    its first channels where the group states a ``partial_rotary_factor``.
+    ``gate``: each head's weighted sum is scaled, before ``out_proj``, by a
+    number of its own for every token, ``sigmoid(x W_g)`` in float32 with
+    ``W_g`` ``(embed_dim, num_heads)`` and no bias (the head-wise gate of
+    Qiu et al., "Gated Attention for Large Language Models",
+    arXiv:2505.06708), under the scope ``attn_gate``."""
 
     embed_dim: int
     num_heads: int
@@ -876,6 +896,7 @@ class GroupedQueryAttention(nn.Module):
     banded: bool = False
     window: int = 0
     rope: Optional[dict] = None
+    gate: bool = False
 
     @nn.compact
     def __call__(self, x, key_padding_mask=None, train: bool = False):
@@ -921,4 +942,9 @@ class GroupedQueryAttention(nn.Module):
                 self, q * D ** -0.5, k, v, key_padding_mask, bias,
                 self.dropout, train, False, self.use_flash, band=band,
             )
+        if self.gate:
+            with jax.named_scope("attn_gate"):
+                w_g = _Kernel((self.embed_dim, H), name="gate_proj")()
+                g = head_gates(x, w_g).transpose(0, 2, 1)[..., None]
+                o = (o.astype(jnp.float32) * g).astype(o.dtype)
         return dense("out_proj", self.embed_dim, heads_in=H)(o)

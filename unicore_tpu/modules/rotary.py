@@ -7,7 +7,13 @@ A model that scales its frequencies states them as a table
 (:func:`rope_table`, from a published ``rope_parameters`` group): the
 pairs' frequencies and the factor ``c`` that scales both cosine and sine,
 and with them ``q`` and ``k`` (YaRN's attention factor; Peng et al.,
-arXiv:2309.00071)."""
+arXiv:2309.00071).
+
+A model may rotate part of a head only (``partial_rotary_factor``): the
+first ``rotary_dim`` channels are paired and turned as a head of that size
+would be (channel ``i`` with ``i + rotary_dim / 2``, the table made for
+``rotary_dim`` in the head size's place), and the channels after them pass
+through, neither turned nor scaled."""
 
 import math
 
@@ -19,7 +25,11 @@ import numpy as np
 def rope_table(rope_parameters, head_dim):
     """``(inv_freq, c)`` of one ``rope_parameters`` group: the ``head_dim /
     2`` pair frequencies (float32, made on the host in float64) and the
-    factor on cosine and sine.
+    factor on cosine and sine.  A group that states a
+    ``partial_rotary_factor`` rotates the head's first ``rotary_dim =
+    head_dim x factor`` channels: everything below is then of
+    ``rotary_dim`` in ``head_dim``'s place (as Hugging Face reads it), and
+    the table holds ``rotary_dim / 2`` pairs.
 
     ``rope_type`` ``default``: ``inv_freq_i = theta ^ (-2 i / D)``, ``c`` 1.
     ``yarn`` (as Hugging Face's ``_compute_yarn_parameters`` with
@@ -34,6 +44,7 @@ def rope_table(rope_parameters, head_dim):
     rp = dict(rope_parameters)
     kind = rp.get("rope_type", "default")
     theta = float(rp["rope_theta"])
+    head_dim = int(head_dim * float(rp.get("partial_rotary_factor", 1.0)))
     half = head_dim // 2
     i = np.arange(half, dtype=np.float64)
     plain = theta ** (-2.0 * i / head_dim)
@@ -65,14 +76,19 @@ def apply_rotary(x, positions, theta=None, table=None):
     """``x`` (..., L, D) with ``D`` even, ``positions`` (L,) (or anything
     that broadcasts against ``x``'s leading axes, ending in ``L``).  The
     frequencies are ``theta``'s, or a ``table``'s (:func:`rope_table`:
-    ``(inv_freq, c)``, cosine and sine both scaled by ``c``).  The angles,
-    sines and the rotation itself are float32; the result is rounded to
-    ``x``'s dtype."""
+    ``(inv_freq, c)``, cosine and sine both scaled by ``c``).  A table of
+    fewer than ``D / 2`` pairs rotates the first ``2 len(inv_freq)``
+    channels and passes the rest through.  The angles, sines and the
+    rotation itself are float32; the result is rounded to ``x``'s dtype."""
     with jax.named_scope("rotary"):
-        half = x.shape[-1] // 2
+        turned = x.shape[-1] if table is None else 2 * len(table[0])
+        passed = None
+        if turned < x.shape[-1]:
+            x, passed = x[..., :turned], x[..., turned:]
+        half = turned // 2
         if table is None:
             inv_freq = theta ** (
-                -jnp.arange(half, dtype=jnp.float32) * 2.0 / x.shape[-1]
+                -jnp.arange(half, dtype=jnp.float32) * 2.0 / turned
             )
         else:
             inv_freq = jnp.asarray(table[0], jnp.float32)
@@ -84,5 +100,7 @@ def apply_rotary(x, positions, theta=None, table=None):
         x1, x2 = xf[..., :half], xf[..., half:]
         out = jnp.concatenate(
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-        )
-        return out.astype(x.dtype)
+        ).astype(x.dtype)
+        if passed is not None:
+            out = jnp.concatenate([out, passed], axis=-1)
+        return out
