@@ -3,12 +3,15 @@ _remat``: one policy, the arrays the layer kinds name).  At sizes a CPU
 holds: the values are those of no rematerialization and of the bare
 ``nn.remat`` the decoder had before, bit for bit; the backward pass of an
 expert layer runs no second router product, ``top_k``, ``latent_down``,
-routed forward loop or ``shared_fc2``; a pattern whose kinds name nothing
-(``AFAF``) traces to the bare form's program; no name is dead on either
-side."""
+routed forward loop, ``shared_fc1`` or ``shared_fc2``, and a Mamba layer's
+no second ``in_proj``; a pattern whose kinds name nothing (``AFAF``) traces
+to the bare form's program, and one whose kinds give neither of the two
+names newest on the list (``SRGR`` with no shared expert) to the program it
+traced before they were listed; no name is dead on either side."""
 
 import collections
 import functools
+import math
 
 import flax.linen as nn
 import jax
@@ -26,25 +29,35 @@ SIZES = dict(
     mamba=dict(num_heads=4, head_dim=8, n_groups=2, state_size=20,
                conv_kernel=4, chunk_size=8),
     attention=dict(num_heads=4, num_kv_heads=2, head_dim=8),
-    moe=dict(latent_dim=16, expert_dim=28, shared_dim=40, n_routed=12,
+    moe=dict(latent_dim=24, expert_dim=28, shared_dim=40, n_routed=12,
              top_k=3, n_held=4, first_held=4, routed_scale=2.5),
     eva=dict(num_heads=2, head_dim=16, window_size=8, chunk_size=4,
              rope_theta=1e4),
     mlp=dict(ffn_dim=48, row_chunk=16),
+    window_attention=dict(num_heads=4, num_kv_heads=2, head_dim=8, window=8,
+                          rope=dict(rope_theta=1e4)),
+    full_attention=dict(num_heads=4, num_kv_heads=2, head_dim=8,
+                        rope=dict(rope_theta=1e4)),
+    gated_moe=dict(expert_dim=20, n_routed=10, top_k=3, n_held=5,
+                   first_held=5, routed_scale=2.5, shared_dim=44),
 )
-#: the right-hand shapes of an ``E`` layer's forward products over its tokens
-PRODUCTS = dict(router=(D, 12), latent_down=(D, 16), latent_up=(16, D),
-                shared_fc1=(D, 40), shared_fc2=(40, D))
+#: the right-hand shapes of an ``E`` layer's forward products over its
+#: tokens, and of the Mamba layer's ``in_proj`` (2 x 32 + 2 x 40 + 4 wide)
+PRODUCTS = dict(router=(D, 12), latent_down=(D, 24), latent_up=(24, D),
+                shared_fc1=(D, 40), shared_fc2=(40, D), in_proj=(D, 148))
 
 
-def decoder(pattern, remat=True):
-    return HybridDecoder(pattern=pattern, remat=remat, **SIZES)
+def decoder(pattern, remat=True, shared=True):
+    """``shared`` false: ``R`` without its shared expert (``mellum``'s)."""
+    sizes = SIZES if shared else dict(
+        SIZES, gated_moe=dict(SIZES["gated_moe"], shared_dim=0))
+    return HybridDecoder(pattern=pattern, remat=remat, **sizes)
 
 
 @functools.lru_cache
-def inputs(pattern, dtype):
+def inputs(pattern, dtype, shared=True):
     x = jax.random.normal(jax.random.key(0), (B, S, D), dtype)
-    params = decoder(pattern).init(jax.random.key(1), x)
+    params = decoder(pattern, shared=shared).init(jax.random.key(1), x)
     # off the initial point: a router that spreads its choices, norms off 1
     params = jax.tree_util.tree_map(
         lambda a: (a + 0.3 * jax.random.normal(jax.random.key(2), a.shape)
@@ -65,16 +78,24 @@ def make_bare(monkeypatch):
 
 
 def run(pattern, dtype, remat):
+    """Compiled to round wherever the program says so.  Left its default
+    licence (``xla_allow_excess_precision``), XLA on the CPU makes a
+    bfloat16 product in float32 and skips the rounding where the result is
+    widened again at once (``R``'s shared expert: its gate is evaluated in
+    float32), in the program that consumes the product where it is made and
+    not in the one that reads it back: the compiler's choice, which no form
+    of rematerialization states."""
     params, x = inputs(pattern, dtype)
     (value, stats), grads = jax.jit(jax.value_and_grad(
         loss_of(decoder(pattern, remat)), argnums=(0, 1), has_aux=True,
-    ))(params, x)
+    )).lower(params, x).compile(
+        compiler_options={"xla_allow_excess_precision": False})(params, x)
     return jax.tree_util.tree_leaves((value, stats, grads))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("pattern", ["*EMEM", "*EE", "AFAF"])
+@pytest.mark.parametrize("pattern", ["*EMEM", "*EE", "AFAF", "GRSRSR"])
 @pytest.mark.parametrize("other", ["no_remat", "bare_remat"])
 def test_keeping_named_arrays_changes_no_bit(pattern, dtype, other,
                                              monkeypatch):
@@ -96,7 +117,7 @@ def test_keeping_named_arrays_changes_no_bit(pattern, dtype, other,
         else:
             assert jnp.allclose(g, w, rtol=1e-4, atol=1e-4)
     assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g in got)
-    if "E" in pattern:
+    if "E" in pattern or "R" in pattern:
         assert float(got[1][latent_moe.STATS.index("pairs_here")]) > 0
 
 
@@ -112,9 +133,10 @@ def equations(jaxpr, inside=()):
                     yield from equations(sub, inside + (eqn.primitive.name,))
 
 
-def backward(pattern):
-    params, x = inputs(pattern, jnp.float32)
-    grad = jax.grad(lambda p, x: loss_of(decoder(pattern))(p, x)[0], (0, 1))
+def backward(pattern, shared=True):
+    params, x = inputs(pattern, jnp.float32, shared)
+    model = decoder(pattern, shared=shared)
+    grad = jax.grad(lambda p, x: loss_of(model)(p, x)[0], (0, 1))
     return jax.make_jaxpr(grad)(params, x).jaxpr
 
 
@@ -126,8 +148,8 @@ def remat_primitive():
 def rematerialized(jaxpr):
     """What the backward pass runs inside its rematerializing equations
     (the layers' forward made again, and their transposes): forward
-    products over the tokens by right-hand shape, and other primitives by
-    name."""
+    products over the tokens, ``(N, D)`` or ``(B, S, D)``, by right-hand
+    shape, and other primitives by name."""
     found = collections.Counter()
     remat = remat_primitive()
     for eqn, inside in equations(jaxpr):
@@ -137,7 +159,8 @@ def rematerialized(jaxpr):
         if name == "dot_general":
             lhs, rhs = (v.aval.shape for v in eqn.invars)
             (lc, rc), _ = eqn.params["dimension_numbers"]
-            if lhs[0] == N and len(lhs) == 2 and (lc, rc) == ((1,), (0,)):
+            if (math.prod(lhs[:-1]) == N and len(rhs) == 2
+                    and (lc, rc) == ((len(lhs) - 1,), (0,))):
                 name = rhs
         found[name] += 1
     return found
@@ -145,45 +168,47 @@ def rematerialized(jaxpr):
 
 def test_the_backward_pass_makes_no_kept_array_again(monkeypatch):
     """``*EMEM`` is ``*`` and one scanned ``EM`` body, so each count is one
-    ``E`` layer's.  Against the bare form the rematerialized part loses
-    the router's product and ``top_k``, ``latent_down``, ``shared_fc2``,
-    the routed experts' forward loop and the layout's sort; ``shared_fc1``
-    and ``latent_up`` are still made again, once each."""
+    ``E`` layer's and one ``M`` layer's.  Against the bare form the
+    rematerialized part loses the router's product and ``top_k``,
+    ``latent_down``, ``shared_fc1``, ``shared_fc2``, the routed experts'
+    forward loop, the layout's sort and the Mamba layer's ``in_proj``;
+    ``latent_up`` is still made again, once."""
     kept = rematerialized(backward("*EMEM"))
     make_bare(monkeypatch)
     bare = rematerialized(backward("*EMEM"))
-    for name in ("router", "latent_down", "shared_fc2"):
+    named = ("router", "latent_down", "shared_fc1", "shared_fc2", "in_proj")
+    for name in named:
         assert (bare[PRODUCTS[name]], kept[PRODUCTS[name]]) == (1, 0), name
-    # left on purpose: the big one (its result is not named) and the small
-    # one that makes the next layer's input (latent_moe.KEPT says why)
-    for name in ("shared_fc1", "latent_up"):
-        assert (bare[PRODUCTS[name]], kept[PRODUCTS[name]]) == (1, 1), name
+    # left on purpose: the small one that makes the next layer's input
+    # (latent_moe.KEPT says why)
+    assert (bare[PRODUCTS["latent_up"]], kept[PRODUCTS["latent_up"]]) == (1, 1)
     assert (bare["top_k"], kept["top_k"]) == (1, 0)
     # the layout's stable sort of the pairs
     assert (bare["sort"], kept["sort"]) == (1, 0)
     # the loops over the tiles in use: the forward one goes, the backward
     # one stays
     assert (bare["while"], kept["while"]) == (2, 1)
-    # nothing else is kept (the Mamba layer's and the attention layer's
-    # products are made again as they were) and nothing is added
+    # nothing else is kept (the Mamba layer's other products and the
+    # attention layer's are made again as they were) and nothing is added
     gone = bare - kept
     assert {k for k in gone if isinstance(k, tuple)} == {
-        PRODUCTS[name] for name in ("router", "latent_down", "shared_fc2")}
+        PRODUCTS[name] for name in named}
     assert gone["dot_general"] == 2      # the forward loop's two, per tile
     assert not kept - bare
+
+
+def listing(jaxpr):
+    return [
+        (eqn.primitive.name, inside,
+         tuple(str(v.aval) for v in eqn.invars),
+         tuple(str(v.aval) for v in eqn.outvars))
+        for eqn, inside in equations(jaxpr)
+    ]
 
 
 def test_a_pattern_that_names_nothing_traces_to_the_bare_program(monkeypatch):
     """``AFAF`` (``evabyte``'s kinds): with the policy the backward pass is
     the bare ``nn.remat``'s, equation for equation."""
-    def listing(jaxpr):
-        return [
-            (eqn.primitive.name, inside,
-             tuple(str(v.aval) for v in eqn.invars),
-             tuple(str(v.aval) for v in eqn.outvars))
-            for eqn, inside in equations(jaxpr)
-        ]
-
     kept = listing(backward("AFAF"))
     make_bare(monkeypatch)
     bare = listing(backward("AFAF"))
@@ -199,7 +224,27 @@ def names_in(jaxpr):
 def test_no_name_is_dead_on_either_side():
     """Every name the decoder's policy lists is given to an array by some
     layer kind, and every array a layer names is on the policy's list."""
-    params, x = inputs("*EMAF", jnp.float32)
-    produced = names_in(jax.make_jaxpr(decoder("*EMAF").apply)(params, x).jaxpr)
+    # ``R`` with its shared expert beside ``E``: the gated form's names too
+    params, x = inputs("*EMAFR", jnp.float32)
+    produced = names_in(
+        jax.make_jaxpr(decoder("*EMAFR").apply)(params, x).jaxpr)
     assert produced == set(hybrid_decoder.KEPT)
     assert len(set(hybrid_decoder.KEPT)) == len(hybrid_decoder.KEPT)
+
+
+def test_kinds_that_give_neither_new_name_trace_as_before(monkeypatch):
+    """``SRGR`` with no shared expert (``mellum``'s kinds): ``R`` names its
+    router's arrays, but neither ``shared_fc1``'s product nor ``in_proj``'s,
+    so the backward pass is, equation for equation, what the policy gave
+    before it listed those two."""
+    new = {"moe_shared_fc1", "mamba_in_proj"}
+    assert new < set(hybrid_decoder.KEPT)
+    now = backward("SRGR", shared=False)
+    given = names_in(now)
+    assert given and not new & given
+    monkeypatch.setattr(
+        hybrid_decoder, "KEPT",
+        tuple(k for k in hybrid_decoder.KEPT if k not in new))
+    before = listing(backward("SRGR", shared=False))
+    assert len(before) > 100 and remat_primitive() in {e[0] for e in before}
+    assert listing(now) == before

@@ -56,8 +56,8 @@ on seeded weights and while training draws the hidden states together.
 
 It returns the same ``STATS`` and names the same arrays for a
 rematerializing caller (``latent_moe.KEPT``: the router's product,
-``top_k``'s indices and set, the layout, the routed sum and the shared
-expert's result).
+``top_k``'s indices and set, the layout, the routed sum, the shared
+expert's result and its ``shared_fc1`` product before the gate).
 """
 
 import flax.linen as nn
@@ -214,8 +214,11 @@ class GatedMoE(nn.Module):
                 # the gate's activation and its product with ``up`` in
                 # float32, rounded once (as modules/gated_mlp.py)
                 with jax.named_scope("shared_fc1"):
-                    mid = silu_gate(jnp.dot(
-                        tokens, s1.astype(dtype)).astype(f32)).astype(dtype)
+                    # named before the gate, whose backward reads both
+                    # halves of it
+                    mid = checkpoint_name(
+                        jnp.dot(tokens, s1.astype(dtype)), "moe_shared_fc1")
+                    mid = silu_gate(mid.astype(f32)).astype(dtype)
                 with jax.named_scope("shared_fc2"):
                     y = y + checkpoint_name(
                         jnp.dot(mid, s2.astype(dtype)), "moe_shared_out")

@@ -26,13 +26,20 @@ stream and the arrays a layer kind NAMES (``jax.ad_checkpoint.
 checkpoint_name``) because they are cheap to hold and dear to make again:
 one policy for every pattern (:func:`_remat`), and what it keeps in a layer
 is that layer kind's to say, which knows its shapes.  ``E`` names the
-router's scores, ``top_k``'s choice, both latent arrays, the layout and
-the shared expert's result (``latent_moe.KEPT``: 123 MB a layer at 8,192
-tokens, for which the backward pass runs no second router product,
-``top_k``, ``latent_down``, routed forward loop or ``shared_fc2``); ``R``
-names the same router, ``top_k`` and layout arrays and its routed sum
-(``gated_moe.py``); ``M``, ``*``, ``A``, ``F``, ``S`` and ``G`` name nothing
-and are made again whole.
+router's scores, ``top_k``'s choice, both latent arrays, the layout, the
+shared expert's result and its ``shared_fc1`` product (``latent_moe.KEPT``:
+211 MB a layer at 8,192 tokens, for which the backward pass runs no second
+router product, ``top_k``, ``latent_down``, routed forward loop,
+``shared_fc1`` or ``shared_fc2``); ``R`` names the same router, ``top_k``
+and layout arrays, its routed sum and, with a shared expert, that expert's
+two products (``gated_moe.py``); ``M`` names ``in_proj``'s result
+(``mamba2.KEPT``: 38 MB a layer, no second ``in_proj``; convolution, scan
+and gated norm are made again from it); ``*``, ``A``, ``F``, ``S`` and
+``G`` name nothing and are made again whole.  What a name is worth is the
+chip's to say: with ``in_proj``'s result kept the compiler lays the scanned
+backward loop out against the forward loop's and copies two saved arrays an
+iteration, its own cycle estimate ranks that form under ``shared_fc1``'s
+name alone, and the chip ranks it above (PERF.md, PR 43).
 """
 
 from typing import Optional, Tuple
@@ -41,21 +48,26 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from . import latent_moe, mamba2
 from .eva_attention import EvaAttention
 from .gated_mlp import GatedMLP
 from .gated_moe import GatedMoE
-from .latent_moe import KEPT, STATS, LatentMoE
+from .latent_moe import STATS, LatentMoE
 from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
 from .multihead_attention import GroupedQueryAttention
 
 KINDS = "M*EAFSGR"
 
+#: every name a layer kind gives an array it wants kept across the forward
+#: pass: each kind's own tuple (``E`` and ``R`` share ``latent_moe``'s)
+KEPT = latent_moe.KEPT + mamba2.KEPT
+
 
 def _remat(cls):
     """``cls`` rematerialized in the backward pass, but for what its
-    layers name: ``KEPT``, every name a layer kind gives an array it wants
-    kept (``E``'s alone so far; a kind that names one adds its tuple)."""
+    layers name: :data:`KEPT` (a name no layer of a pattern gives keeps
+    nothing; a kind that names an array adds its tuple there)."""
     return nn.remat(
         cls, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 

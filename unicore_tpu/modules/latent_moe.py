@@ -133,15 +133,20 @@ WIDE = 1024
 #: experts' sum after its cast, ``(n, latent_dim)`` each (no second
 #: ``latent_down``, no second forward loop over the tiles); the layout's
 #: index arrays (no second sort of the pairs); the shared expert's result
-#: ``(n, embed_dim)`` (no second ``shared_fc2``).  At the benchmark's 8,192
-#: tokens 123 MB a layer.  Not among them, each for a measured reason
-#: (PERF.md, PR 36): ``shared_fc1``'s result (88 MB more), and the layer's
-#: own result in the shared expert's place: ``latent_up``'s small second
-#: forward is left in the backward pass on purpose, because the array that
-#: the NEXT layer's second forward starts from is then made by a product,
-#: and that keeps the backward loop in the forward loop's layout.
+#: ``(n, embed_dim)`` (no second ``shared_fc2``); ``shared_fc1``'s product
+#: ``(n, shared_dim)`` before its activation, which the activation's
+#: backward reads (no second ``shared_fc1``, the layer's largest product;
+#: the activation is made again from it as an epilogue).  At the
+#: benchmark's 8,192 tokens 211 MB a layer, 88 MB of it ``shared_fc1``'s:
+#: as wide as the work that makes it, so a trade against the step's peak,
+#: taken since the compiled step has the room (PERF.md, PR 43).  Not among
+#: them, for a measured reason (PERF.md, PR 36): the layer's own result in
+#: the shared expert's place.  ``latent_up``'s small second forward is left
+#: in the backward pass on purpose, because the array that the NEXT layer's
+#: second forward starts from is then made by a product, and that keeps the
+#: backward loop in the forward loop's layout.
 KEPT = ("moe_logits", "moe_top_k_idx", "moe_top_k_sel", "moe_latent_down",
-        "moe_routed_sum", "moe_layout", "moe_shared_out")
+        "moe_routed_sum", "moe_layout", "moe_shared_out", "moe_shared_fc1")
 
 
 def relu2(x):
@@ -527,7 +532,11 @@ class LatentMoE(nn.Module):
             y = dense("latent_up", d)(routed)
 
         with jax.named_scope("moe_shared"):
-            y = y + checkpoint_name(dense("shared_fc2", d)(
-                relu2(dense("shared_fc1", self.shared_dim)(tokens))
-            ), "moe_shared_out")
+            # named BEFORE the activation: relu2's backward reads what went
+            # in, and relu2 of a kept array is an elementwise epilogue
+            mid = checkpoint_name(
+                dense("shared_fc1", self.shared_dim)(tokens),
+                "moe_shared_fc1")
+            y = y + checkpoint_name(
+                dense("shared_fc2", d)(relu2(mid)), "moe_shared_out")
         return y.reshape(B, S, d), stats

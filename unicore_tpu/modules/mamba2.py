@@ -16,6 +16,10 @@ as 16 heads and 1 group each: a group's ``B, C`` and its slice of the gated
 norm (``d_inner / n_groups`` wide) belong to that group's heads alone, so
 each rank's ``out_proj`` output is its exact part of the whole mixer's and
 the parts add up (``tests/test_hybrid_lm.py`` holds it to that).
+
+For a rematerializing caller the mixer names (``checkpoint_name``) one
+array, :data:`KEPT`; a name changes no value and no dtype, and without
+such a caller it does nothing.
 """
 
 import math
@@ -23,11 +27,22 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from unicore_tpu.ops.ssd_scan import ssd_scan
 from unicore_tpu.quant.dense import QuantDense
 
 _init = nn.initializers.normal(0.02)
+
+#: the array :meth:`Mamba2Mixer.__call__` names for a rematerializing caller
+#: (``modules/hybrid_decoder.py``) to keep across the forward pass:
+#: ``in_proj``'s result ``[z | x B C | dt]``, the mixer's largest product
+#: (38 MB a layer at the benchmark's 8,192 tokens x 2,320).  The
+#: convolution, the scan and the gated norm are made again from its slices
+#: (they are memory-bound), and the mixer's own result is not kept: it is
+#: what the NEXT layer's second forward starts from (``latent_moe.KEPT``
+#: says why)
+KEPT = ("mamba_in_proj",)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -102,7 +117,8 @@ class Mamba2Mixer(nn.Module):
             features, use_bias=False, name=name, kernel_init=_init,
             dtype=u.dtype, param_dtype=jnp.float32,
         )
-        zxbcdt = dense("in_proj", 2 * d_inner + 2 * bc + H)(u)
+        zxbcdt = checkpoint_name(
+            dense("in_proj", 2 * d_inner + 2 * bc + H)(u), "mamba_in_proj")
         z, xBC, dt = jnp.split(
             zxbcdt, [d_inner, 2 * d_inner + 2 * bc], axis=-1
         )
