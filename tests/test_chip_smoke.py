@@ -31,7 +31,8 @@ TINY = dict(
     batch=4, updates=4, warmup_updates=4, lm_arch="transformer_lm_tiny",
     lm_updates=3, max_new_tokens=4,
     kernel_sizes=dict(B=2, H=2, L=128, D=32, dims=(128, 256),
-                      matmul_shapes=((32, 128, 128),)),
+                      matmul_shapes=((32, 128, 128),),
+                      rows_add_shapes=((96, 48, 256),)),
     serve_extra=("--serve-batch-size", "2", "--serve-buckets", "2",
                  "--decode-batch-size", "2", "--cache-pages", "32"),
 )

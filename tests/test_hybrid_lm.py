@@ -513,6 +513,164 @@ def test_a_row_without_a_pair_reads_nothing_of_its_token(monkeypatch):
     assert not grads[0][3:].any() and grads[0][:3].any()
 
 
+TRIP_LOADS = {
+    # n = 64 tokens, tiles of 8, wide trips of 32: an expert at 0, 1, TILE,
+    # n - 1 and n pairs (its last tile then reaches the end of its column of
+    # n entries, or past it)
+    "none": (0, 0, 0),
+    "one": (1, 0, 1),
+    "a-tile": (8, 8, 1),
+    "n-less-one": (63, 63, 1),
+    "every-token": (64, 64, 64),
+    "even-fills-wide-trips": (32, 32, 32, 32),
+    "lumped": (64, 0, 3, 37, 9),
+}
+
+
+@pytest.mark.parametrize("loads", list(TRIP_LOADS.values()), ids=list(TRIP_LOADS))
+def test_no_index_occurs_twice_among_the_rows_of_a_trip(monkeypatch, loads):
+    """What the loops rely on since they tell the add that no row meets
+    another: within every trip, wide or narrow, rows without a pair
+    included, the indices are distinct and ascending; a pair's row holds
+    its token, a row without a pair an index beyond the tokens that no
+    other row of the layout holds."""
+    from unicore_tpu.modules import latent_moe
+
+    tile, wide, n = 8, 32, 64
+    monkeypatch.setattr(latent_moe, "TILE", tile)
+    pair = _loads(n, loads)
+    Eh = len(loads)
+    w_held = jnp.where(pair, 0.5, 0.0)
+    rows = latent_moe.buffer_rows(n, Eh, Eh)
+    lay = {k: np.asarray(v) for k, v in
+           latent_moe.buffer_layout(pair, w_held, rows, wide).items()}
+    token, valid = lay["token_of_row"], lay["valid"]
+    trips = [(int(first), wide) for first in lay["wide_start"][:lay["wide_trips"]]]
+    trips += [(int(t) * tile, tile) for t in lay["narrow_tile"][:lay["narrow_trips"]]]
+    every_tile = [(t * tile, tile) for t in range(int(lay["tiles_used"]))]
+    assert sum(size for _, size in trips) == len(every_tile) * tile
+    for first, size in trips + every_tile:   # with wide trips, and without
+        at, pairs = token[first:first + size], valid[first:first + size]
+        assert (np.diff(at) > 0).all(), (first, size, at)
+        assert (at[pairs] < n).all() and (at[~pairs] >= n).all()
+        assert not pairs[np.argmin(pairs):].any() or pairs.all()
+    # all of an expert's pairs, in token order, and nothing else
+    used = int(lay["tiles_used"]) * tile
+    for e in range(Eh):
+        mine = np.repeat(lay["tile_expert"], tile)[:used] == e
+        np.testing.assert_array_equal(
+            token[:used][mine & valid[:used]], np.flatnonzero(np.asarray(pair[:, e])))
+    assert int(valid[:used].sum()) == sum(loads) and not valid[used:].any()
+    beyond = token[~valid]
+    assert (beyond >= n).all() and len(set(beyond.tolist())) == len(beyond)
+
+
+def _parent_routed(latent, g, w1, w2, lay, act, wide, tile):
+    """The loops as they stood before the add was told anything (PR 43's
+    trip bodies: ``out.at[token].add``, no flag, no kernel), on the same
+    layout: the value of ``routed_experts`` and its four cotangents for
+    the output's cotangent ``g``."""
+    from unicore_tpu.modules.latent_moe import ACTS, _rows_at
+
+    f32 = jnp.float32
+    act_fn, act_vjp = ACTS[act]
+    rows_of = lambda table, token, valid: jnp.where(
+        valid[:, None], table.at[token].get(mode="clip"), 0)
+
+    def trips(trip, carry):
+        one = lambda t, c: trip(lay["tile_expert"][t], t * tile, tile, c)
+        if not wide:
+            return jax.lax.fori_loop(0, lay["tiles_used"], one, carry)
+        carry = jax.lax.fori_loop(
+            0, lay["wide_trips"],
+            lambda j, c: trip(lay["wide_expert"][j], lay["wide_start"][j],
+                              wide, c), carry)
+        return jax.lax.fori_loop(
+            0, lay["narrow_trips"], lambda j, c: one(lay["narrow_tile"][j], c),
+            carry)
+
+    def fwd(e, first, size, out):
+        token, weight, valid = _rows_at(lay, first, size)
+        x_t = rows_of(latent, token, valid)
+        h = act_fn(jnp.dot(x_t, w1[e], preferred_element_type=f32))
+        y_t = jnp.dot(h.astype(latent.dtype), w2[e], preferred_element_type=f32)
+        return out.at[token].add(weight[:, None] * y_t)
+
+    def bwd(e, first, size, carry):
+        dx, dweight, dw1, dw2 = carry
+        token, weight, valid = _rows_at(lay, first, size)
+        x_t, d_t = rows_of(latent, token, valid), rows_of(g, token, valid)
+        h, act_bwd = act_vjp(jnp.dot(x_t, w1[e], preferred_element_type=f32))
+        y_t = jnp.dot(h, w2[e], preferred_element_type=f32)
+        dy_t = d_t * weight[:, None]
+        dpre = act_bwd(jnp.dot(dy_t, w2[e].T, preferred_element_type=f32))
+        dx_t = jnp.dot(dpre, w1[e].T, preferred_element_type=f32)
+        dw1 = dw1.at[e].add(jnp.dot(x_t.T, dpre, preferred_element_type=f32))
+        dw2 = dw2.at[e].add(jnp.dot(h.T, dy_t, preferred_element_type=f32))
+        dweight = dweight.at[e, token].add(jnp.sum(y_t * d_t, axis=-1))
+        return dx.at[token].add(dx_t), dweight, dw1, dw2
+
+    n, Eh = latent.shape[0], w1.shape[0]
+    out = trips(fwd, jnp.zeros(latent.shape, f32))
+    dx, dweight, dw1, dw2 = trips(bwd, (
+        jnp.zeros(latent.shape, f32), jnp.zeros((Eh, n), f32),
+        jnp.zeros(w1.shape, f32), jnp.zeros(w2.shape, f32)))
+    return out, dx, dweight.T, dw1, dw2
+
+
+@pytest.mark.parametrize("lat", [16, 128], ids=["scatter", "kernel"])
+@pytest.mark.parametrize("wide", [0, 32], ids=["tiles", "wide-trips"])
+@pytest.mark.parametrize("act", ["relu2", "silu_gate"])
+def test_told_that_no_row_meets_another_the_adds_are_the_parents(
+        monkeypatch, act, wide, lat):
+    """``routed_experts`` and all four cotangents against the parent's trip
+    bodies on the same trips, in float32: the same adds in the same order
+    for every token, a row without a pair (which the parent added as an
+    exact zero) dropped.  Bit for bit where XLA's scatter adds them (rows
+    of 16; rows of 128 under the one loop over the tiles).  Where the
+    kernel adds the rows (rows of 128 with wide loops built, both loops,
+    interpreted here) to the last place or two: XLA's CPU backend
+    contracts the multiply that makes a trip's rows into the interpreted
+    kernel's add, one rounding where the scatter, and the chip, make two
+    (handed the rows as an argument the kernel is exact:
+    ``tests/test_rows_add.py``; PERF.md, PR 44, has the chip's answer)."""
+    from unicore_tpu.modules import latent_moe
+    from unicore_tpu.ops import rows_add
+
+    tile, n, loads = 8, 200, (0, 31, 32, 33, 75, 25, 200)
+    monkeypatch.setattr(latent_moe, "TILE", tile)
+    f, Eh, top_k = 24, len(loads), 4
+    ks = jax.random.split(jax.random.key(11), 5)
+    rng = np.random.default_rng(5)
+    pair = np.zeros((n, Eh), bool)
+    for e, l in enumerate(loads):
+        pair[rng.choice(n, l, replace=False), e] = True
+    pair = jnp.asarray(pair)
+    latent = jax.random.normal(ks[0], (n, lat))
+    w1 = 0.3 * jax.random.normal(
+        ks[1], (Eh, lat, 2 * f if act == "silu_gate" else f))
+    w2 = 0.3 * jax.random.normal(ks[2], (Eh, f, lat))
+    w_held = jnp.where(pair, jax.random.uniform(ks[3], (n, Eh), minval=0.1), 0.0)
+    g = jax.random.normal(ks[4], (n, lat))
+    rows = latent_moe.buffer_rows(n, top_k, Eh)
+    assert rows_add.kernel_takes(latent) == (lat == 128)
+
+    routed = lambda *a: latent_moe.routed_experts(*a, rows, pair, act, wide)
+    args = (latent, w_held, w1, w2)
+    got = (routed(*args),) + jax.grad(
+        lambda *a: jnp.sum(routed(*a) * g), (0, 1, 2, 3))(*args)
+    lay = latent_moe.buffer_layout(pair, w_held, rows, wide)
+    want = jax.jit(_parent_routed, static_argnums=(5, 6, 7))(
+        latent, g, w1, w2, lay, act, wide, tile)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.float32 and a.any()
+        if wide and lat == 128:
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=2e-6 * float(jnp.abs(b).max()))
+        else:
+            np.testing.assert_array_equal(a, b)
+
+
 # -- the loss and the data ----------------------------------------------------------
 
 def test_chunked_loss_is_the_whole_loss_with_its_gradients():
@@ -720,7 +878,11 @@ def test_the_step_holds_no_worst_case_buffer_of_rows(tmp_path):
     """Loss and gradient of the tiny model, compiled: no array in the
     program has ``rows x latent_dim`` elements (tokens laid out in the
     worst case's rows) or ``n x n_held x latent_dim`` (every token's row
-    from every held expert).  Dispatch and combine move tiles."""
+    from every held expert).  Dispatch and combine move tiles; what the
+    trips add their rows to holds ``n`` rows in whichever layout (``(n,
+    lat / 128, 128)`` under the kernel of ``ops/rows_add.py``, where wide
+    loops are built: ``n x latent_dim`` elements either way), never the
+    layout's ``rows``."""
     from unicore_tpu.modules.latent_moe import TILE, buffer_rows
 
     _, task, model, loss = tiny_task(tmp_path, held=5)

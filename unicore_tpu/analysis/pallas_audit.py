@@ -445,6 +445,8 @@ def run_audit_cases(kernel_paths: Set[str]):
                 uses.append(_block_use("out", i, spec, tuple(sd.shape),
                                        sd.dtype, prefetch))
             for i, s in enumerate(scratch):
+                if "sem" in str(getattr(s, "dtype", "sem")):
+                    continue  # a semaphore takes no VMEM
                 shape = tuple(int(d) for d in s.shape)
                 uses.append(BlockUse("scratch", i, shape, s.dtype, shape))
             if site_path is not None:
@@ -459,6 +461,12 @@ def run_audit_cases(kernel_paths: Set[str]):
 
         return runner
 
+    def _left_in_hbm(spec):
+        """An operand the spec leaves where it lies (``pl.ANY``, HBM)."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        return getattr(spec, "memory_space", None) in (pl_mod.ANY, pltpu.HBM)
+
     def _concrete(prefetch):
         """The scalar-prefetch operands as numpy arrays, so an index map
         that READS one (a block map: ``ids_ref[...]``) is evaluated on the
@@ -471,6 +479,9 @@ def run_audit_cases(kernel_paths: Set[str]):
         return tuple(np.asarray(x) for x in prefetch)
 
     def _block_use(kind, index, spec, array_shape, dtype, prefetch):
+        if _left_in_hbm(spec):
+            # the kernel copies what it needs itself: no block is resident
+            return BlockUse(kind, index, (), dtype, array_shape, None)
         if spec is None or getattr(spec, "block_shape", None) is None:
             return BlockUse(kind, index, array_shape, dtype, array_shape,
                             None)

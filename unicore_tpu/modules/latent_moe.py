@@ -71,6 +71,30 @@ which a balanced router hands every expert, is at least ``WIDE``.  Below
 it the function traces the one loop over the tiles, forward and backward.
 ``rows_wide`` of :data:`STATS` says how many pairs went wide.
 
+How a trip's rows reach their tokens.  A trip adds ``size`` rows of
+``lat`` float32 into an ``(n, lat)`` accumulator, forward (``out``) and
+backward (``dx``), at indices XLA's scatter-add must assume may repeat:
+it finishes each row's read-add-write before the next, 0.27 us a row of
+9 KB on a v5e, a tenth of the memory's bandwidth, and that was over half
+of the layer's time once the products ran wide (PERF.md, PR 44).  The
+layout knows more, and since PR 44 GUARANTEES it (:func:`buffer_layout`):
+all rows of a trip are one expert's and a token pairs with an expert at
+most once, so a trip's pairs hold distinct, ascending tokens, and its rows
+without a pair hold ``n + row``: out of bounds, distinct, ascending too.
+No two rows of a trip ever meet.  Where wide loops are built the
+accumulators live in the layout of ``ops/rows_add.py``'s kernel for both
+loops (:func:`_trips`), and a trip's rows go a group at a time: read into
+VMEM, added, written back, the next group's reads in flight meanwhile,
+which is safe BECAUSE no row occurs twice.  The loops depend on the
+guarantee: a repeated index could lose an update.  Rows out of bounds are
+skipped there and dropped by the scatter; the gathers clip them and mask
+the row by ``valid`` as before.  Same float32 adds in the same order for
+every token (experts in trip order), so the values are the scatter's, bit
+for bit on the chip.  With the one loop over the tiles the accumulator
+stays ``(n, lat)`` under XLA's scatter-add, which is told nothing: the
+chip said what the flags buy (``unique_indices`` nothing,
+``indices_are_sorted`` a form twelve times slower).
+
 What is sized by the worst case (:func:`buffer_rows`: every token on every
 held expert it can choose, which no routing can exceed) is the layout's
 index arrays alone: a token, a weight and a flag per row, an expert per
@@ -101,6 +125,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from unicore_tpu.ops import rows_add
 from unicore_tpu.quant.dense import QuantDense
 
 _init = nn.initializers.normal(0.02)
@@ -240,8 +265,16 @@ def buffer_layout(pair, w_held, rows, wide=0):
     ``e - 1``'s.  Returns
 
     * ``token_of_row`` (rows,), ``weight_of_row`` (rows,), ``valid`` (rows,):
-      a row of a tile in use that holds no pair has ``valid`` false, weight
-      zero and some token's index that is in bounds;
+      a row that holds no pair has ``valid`` false, weight zero and the
+      index ``n + row``, which is out of bounds and no other row's.  So
+      **no index occurs twice among the rows of one expert**, and a trip's
+      rows are all one expert's: its pairs' tokens are distinct (a token
+      pairs with an expert at most once) and ascending, and the rows
+      after them ascend beyond ``n``.  The loops DEPEND on this
+      (:func:`_add_rows`: the kernel that adds a trip's rows moves many
+      at once, which only rows that never meet allow); what reads a table
+      at these indices clips them and masks the row by ``valid``
+      (:func:`_trip_rows`), what adds at them drops them;
     * ``tile_expert`` (rows / TILE,), ``tiles_used`` (scalar): the rows the
       loops read are those of the first ``tiles_used`` tiles;
     * with ``wide``, which of those tiles go ``wide`` rows at a time: expert
@@ -283,9 +316,15 @@ def buffer_layout(pair, w_held, rows, wide=0):
         weight_of_row = jax.lax.dynamic_update_slice(
             weight_of_row, weights[:, e], at)
         valid = jax.lax.dynamic_update_slice(valid, unchosen[:, e] == 0, at)
+    valid = valid[:rows]
+    # what a column holds beyond its load is tokens the expert did not
+    # choose, then leftovers and zeros: any of them may be a pair's token
+    # too, so a row without a pair gets an index of its own, out of bounds
+    token_of_row = jnp.where(valid, token_of_row[:rows],
+                             n + jnp.arange(rows, dtype=jnp.int32))
     lay = dict(
-        token_of_row=token_of_row[:rows], weight_of_row=weight_of_row[:rows],
-        valid=valid[:rows], tile_expert=tile_expert,
+        token_of_row=token_of_row, weight_of_row=weight_of_row[:rows],
+        valid=valid, tile_expert=tile_expert,
         tiles_used=ends[-1].astype(jnp.int32),
     )
     if wide:
@@ -333,29 +372,52 @@ def _rows_at(lay, first, size):
 
 def _trip_rows(table, token, valid):
     """``table``'s rows at a trip's tokens; zeros where the row holds no
-    pair, whatever the token's row holds (never a ``0 x inf``)."""
-    return jnp.where(valid[:, None], table[token], 0)
+    pair (its index lies beyond the table and is clipped: whatever row
+    that reads is dropped here, never a ``0 x inf``)."""
+    return jnp.where(valid[:, None], table.at[token].get(mode="clip"), 0)
 
 
-def _trips(lay, wide, trip, carry):
-    """``trip(e, first, size, carry)`` over the rows of the first
+def _add_rows(acc, token, rows):
+    """``acc`` with a trip's ``rows`` added at their tokens, under a scope
+    of its own (forward and backward, so that a trace splits a trip into
+    products, gathers and adds).  The trip's indices are distinct and
+    ascending (:func:`buffer_layout`), which is what lets the kernel
+    (``ops/rows_add.py``) move a group of rows at a time; a row without a
+    pair lies out of bounds and is dropped."""
+    with jax.named_scope("row_adds"):
+        return rows_add.add_rows_at(acc, token, rows)
+
+
+def _trips(lay, wide, trip, acc, *carry):
+    """``trip(e, first, size, acc, *carry)`` over the rows of the first
     ``tiles_used`` tiles of ``lay``: one loop over the tiles, or with
     ``wide`` one over the wide trips and one over the tiles they leave
     (under scopes of their own, so that a trace tells them apart).  Trip
-    counts from the data."""
-    tile = lambda t, c: trip(lay["tile_expert"][t], t * TILE, TILE, c)
+    counts from the data.  ``acc`` (n, lat) float32 is what the trips add
+    their rows to (:func:`_add_rows`).  Where wide loops are built, both
+    loops carry it in the layout of the kernel that adds a trip's rows,
+    where there is one (``ops/rows_add.py``): opened before the first
+    loop and closed after the second, two relayouts of ``acc`` that an
+    even load of ``WIDE`` rows an expert pays for many times over.  With
+    the one loop over the tiles (an even load under ``WIDE``: few trips)
+    they would cost what the kernel saves, and ``acc`` stays as it is,
+    under XLA's scatter-add (PERF.md, PR 44)."""
+    tile = lambda t, c: trip(lay["tile_expert"][t], t * TILE, TILE, *c)
     if not wide:
-        return jax.lax.fori_loop(0, lay["tiles_used"], tile, carry)
+        return jax.lax.fori_loop(0, lay["tiles_used"], tile, (acc,) + carry)
+    opened = rows_add.kernel_takes(acc)
+    state = (rows_add.open_rows(acc) if opened else acc,) + carry
     with jax.named_scope("wide_trips"):
-        carry = jax.lax.fori_loop(
+        state = jax.lax.fori_loop(
             0, lay["wide_trips"],
             lambda j, c: trip(lay["wide_expert"][j], lay["wide_start"][j],
-                              wide, c),
-            carry)
+                              wide, *c),
+            state)
     with jax.named_scope("narrow_trips"):
-        return jax.lax.fori_loop(
+        acc, *carry = jax.lax.fori_loop(
             0, lay["narrow_trips"],
-            lambda j, c: tile(lay["narrow_tile"][j], c), carry)
+            lambda j, c: tile(lay["narrow_tile"][j], c), state)
+    return (rows_add.close_rows(acc) if opened else acc, *carry)
 
 
 def _grouped_ffn(latent, w1, w2, lay, act="relu2", wide=0):
@@ -375,10 +437,9 @@ def _grouped_ffn(latent, w1, w2, lay, act="relu2", wide=0):
         h = act_fn(jnp.dot(x_t, w1[e], preferred_element_type=f32))
         y_t = jnp.dot(h.astype(latent.dtype), w2[e],
                       preferred_element_type=f32)
-        # a row without a pair adds an exact zero (x_t and its weight are)
-        return out.at[token].add(weight[:, None] * y_t)
+        return (_add_rows(out, token, weight[:, None] * y_t),)
 
-    return _trips(lay, wide, trip, jnp.zeros(latent.shape, f32))
+    return _trips(lay, wide, trip, jnp.zeros(latent.shape, f32))[0]
 
 
 def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2", wide=0):
@@ -391,8 +452,7 @@ def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2", wide=0):
     f32 = jnp.float32
     act_vjp = ACTS[act][1]
 
-    def trip(e, first, size, carry):
-        dx, dweight, dw1, dw2 = carry
+    def trip(e, first, size, dx, dweight, dw1, dw2):
         token, weight, valid = _rows_at(lay, first, size)
         x_t = _trip_rows(latent, token, valid)
         d_t = _trip_rows(d_out, token, valid)
@@ -406,15 +466,16 @@ def _grouped_ffn_bwd(latent, d_out, w1, w2, lay, act="relu2", wide=0):
         dx_t = jnp.dot(dpre, w1[e].T, preferred_element_type=f32)
         dw1 = dw1.at[e].add(jnp.dot(x_t.T, dpre, preferred_element_type=f32))
         dw2 = dw2.at[e].add(jnp.dot(h.T, dy_t, preferred_element_type=f32))
-        # a row without a pair adds exact zeros: x_t and d_t are, so
-        # dx_t, y_t and the weight's cotangent are
-        dweight = dweight.at[e, token].add(jnp.sum(y_t * d_t, axis=-1))
-        return dx.at[token].add(dx_t), dweight, dw1, dw2
+        # a row without a pair is dropped, here too (its x_t and d_t are
+        # zero, so its dx_t, y_t and weight's cotangent are)
+        dweight = dweight.at[e, token].add(
+            jnp.sum(y_t * d_t, axis=-1), mode="drop")
+        return _add_rows(dx, token, dx_t), dweight, dw1, dw2
 
-    return _trips(lay, wide, trip, (
-        jnp.zeros(latent.shape, f32),
+    return _trips(
+        lay, wide, trip, jnp.zeros(latent.shape, f32),
         jnp.zeros((w1.shape[0], latent.shape[0]), f32),
-        jnp.zeros(w1.shape, f32), jnp.zeros(w2.shape, f32)))
+        jnp.zeros(w1.shape, f32), jnp.zeros(w2.shape, f32))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 6, 7))

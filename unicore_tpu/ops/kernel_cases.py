@@ -61,6 +61,11 @@ def make_inputs(case: KernelCase, seed: int = 0):
             a = rng.uniform(0.5, 1.5, size=shape) * 3.0 / (73.0 ** 2 * k ** 0.5)
         elif fill == "affine":
             a = 1.0 + 0.1 * rng.standard_normal(shape)
+        elif fill == "row_index":
+            # distinct ascending rows of the first operand, three beyond it
+            n = int(case.specs[0][0][0])
+            a = np.sort(rng.choice(n, size=shape, replace=False))
+            a[-3:] = n + np.arange(3)
         else:
             raise ValueError(f"unknown fill {fill!r}")
         out.append(jnp.asarray(a, dtype=dtype))
@@ -303,16 +308,38 @@ def _quant_matmul_cases(shapes):
     return cases
 
 
+def _rows_add_cases(shapes):
+    from unicore_tpu.ops.rows_add import add_rows_at, close_rows, open_rows
+
+    def kernel(acc, index, rows):
+        return close_rows(add_rows_at(open_rows(acc), index, rows))
+
+    def oracle(acc, index, rows):
+        return acc.at[index].add(rows, mode="drop")
+
+    return [
+        KernelCase(
+            f"moe-rows-add-{count}x{width}",
+            (((n, width), _F32, "normal"), ((count,), _I32, "row_index"),
+             ((count, width), _F32, "normal")),
+            kernel, oracle, 1e-6)
+        for n, count, width in shapes
+    ]
+
+
 def kernel_cases(B=8, H=12, L=512, D=64, dims=(768, 1024),
-                 matmul_shapes=((512, 768, 3072), (512, 4096, 4096))):
+                 matmul_shapes=((512, 768, 3072), (512, 4096, 4096)),
+                 rows_add_shapes=((8192, 1024, 2304), (8192, 1024, 3072))):
     """The table.  Defaults are the real widths: BERT-base attention (bf16,
     batch 8, 12 heads, seq 512, head 64), norm dims 768/1024, the BERT FFN
-    768 -> 3072 and the 4096^2 serving audit shape.  The CPU rehearsal of
-    ``chip_smoke.py`` passes small ones."""
+    768 -> 3072 and the 4096^2 serving audit shape, a wide trip's 1,024
+    rows of the gated experts at Mellum2's and Laguna's widths.  The CPU
+    rehearsal of ``chip_smoke.py`` passes small ones."""
     return (
         _attention_cases(B, H, L, D)
         + _softmax_cases(B, H, L)
         + _norm_cases(B, L, dims)
         + _decode_cases(B, H, L, D)
         + _quant_matmul_cases(matmul_shapes)
+        + _rows_add_cases(rows_add_shapes)
     )
