@@ -87,33 +87,8 @@ _FAST_FILES = {
 }
 
 
-#: a test whose file this tree's PR may not edit (the benchmark's own files
-#: are a `benchmark` PR's), expected to fail until that PR repairs it; strict,
-#: so the entry has to go the day the test passes again
-_AWAITING_A_BENCHMARK_PR = {
-    "tests/benchmark/test_evabyte.py::"
-    "test_the_cell_and_its_metrics_are_in_the_manifest":
-        "line 81 pins EvaByte's five per_layer entries as the manifest's LAST "
-        "five; PR 37 appended eight (entries may only go at the end). "
-        "tests/benchmark/test_scope_work.py runs the same body on the "
-        "manifest less those eight (ROADMAP D17)",
-    "tests/benchmark/test_compile_v5e.py::test_cell_step_compiles_for_v5e"
-    "[bert_base.train_mlm512-512-24-6500000000.0-8500000000.0]":
-        "holds total_bytes (arguments + outputs + temporaries - aliases, no "
-        "limit of the chip's) under 8.5e9, the bytes read when the batch was "
-        "chosen plus 1e9; PR 38 keeps two (32, 512, 3072) bfloat16 arrays a "
-        "layer and the sum reads 10,268,964,864 (7,744,550,400 before), the "
-        "peak 10,031,237,120 of 16,911,433,728. tests/test_tpu_compile.py::"
-        "test_bert_cell_step_fits_the_chip holds the same step to the peak "
-        "with 1 GB of room (PERF.md section 7, the benchmark row)",
-}
-
-
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        why = _AWAITING_A_BENCHMARK_PR.get(item.nodeid)
-        if why:
-            item.add_marker(pytest.mark.xfail(reason=why, strict=True))
         if os.path.basename(str(item.fspath)) in _FAST_FILES:
             # slow-marked items in an otherwise-fast file (test_serve's
             # subprocess e2e) stay out of the quick smoke subset

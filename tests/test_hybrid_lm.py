@@ -928,3 +928,287 @@ def test_the_router_holds_no_gather_and_no_scatter():
     router = re.findall(r"op_name=\"([^\"]*moe_router[^\"]*)\"", text)
     assert any("transpose(" in path for path in router)
     assert any("transpose(" not in path for path in router)
+
+
+# -- the seam: one skeleton, one table of kinds, a loss that names no stat ---------
+
+def _seam_sf():
+    """A decoder no program file knows: sliding-window attention, then a
+    gated MLP, ``--num-hidden-layers`` times."""
+    import flax.linen as nn
+
+    from unicore_tpu.models import register_model
+    from unicore_tpu.models.hybrid_lm import (
+        HybridLM, held_attention, register_architecture)
+
+    @register_model("seam_sf")
+    class SeamSF(HybridLM):
+        hidden_size: int = 32
+        num_hidden_layers: int = 2
+        num_attention_heads: int = 4
+        sliding_window: int = 16
+        intermediate_size: int = 48
+        attention_shares: int = 1
+        loss_chunk: int = 32
+
+        def check(self):
+            if self.num_attention_heads % self.attention_shares:
+                raise ValueError("--attention-shares does not divide the heads")
+
+        @property
+        def pattern(self):
+            return "SF" * self.num_hidden_layers
+
+        def layers(self):
+            return dict(norm_eps=1e-6, sizes={
+                "S": held_attention(
+                    self.num_attention_heads, 2, self.attention_shares,
+                    head_dim=8, window=self.sliding_window,
+                    rope=dict(rope_theta=100.0)),
+                "F": dict(ffn_dim=self.intermediate_size)})
+
+        @nn.nowrap
+        def logged(self, stats, rows, length):
+            return self.band_counts(rows, length)
+
+    register_architecture("seam_sf", "seam_sf")
+    return SeamSF
+
+
+SEAM_SF = _seam_sf()
+
+
+def test_a_decoder_defined_here_trains_and_logs_its_stats(tmp_path):
+    """A subclass of the skeleton with a pattern of kinds the table has,
+    defined in this file: its fields are its arguments, ``build_model``
+    builds it from the command line, what it asks for and is not built is
+    refused, and one ``lm_cross_entropy`` update through ``Trainer`` logs
+    the band's counts and yields their mark, with no file under
+    ``unicore_tpu/`` knowing its name."""
+    import inspect
+
+    from unicore_tpu import options, tasks
+    from unicore_tpu.data.indexed_dataset import make_builder
+    from unicore_tpu.losses import LOSS_REGISTRY
+    from unicore_tpu.models import build_model
+    from unicore_tpu.ops.flash_attention import Band, band_counts
+    from unicore_tpu.trainer import Trainer
+
+    assert len(inspect.getsource(SEAM_SF).splitlines()) < 40
+    words = [f"w{a}" for a in "abcdefgh"]
+    (tmp_path / "dict.txt").write_text(
+        "\n".join(["[PAD]", "[UNK]", "[CLS]", "[SEP]"] + words) + "\n")
+    rng = np.random.default_rng(0)
+    builder = make_builder(str(tmp_path / "train"))
+    for n in rng.integers(20, 200, 40):
+        builder.add_item(" ".join(rng.choice(words, n)))
+    builder.finalize()
+    argv = [str(tmp_path), "--task", "causal_lm", "--loss", "lm_cross_entropy",
+            "--arch", "seam_sf", "--tokens-per-sample", "64",
+            "--sliding-window", "8", "--optimizer", "adam",
+            "--lr-scheduler", "fixed", "--lr", "3e-3", "--batch-size", "1",
+            "--max-update", "2", "--seed", "1"]
+    args = options.parse_args_and_arch(options.get_training_parser(), argv)
+    task = tasks.setup_task(args)
+    task.load_dataset("train")
+    model = build_model(args, task)
+    assert isinstance(model, SEAM_SF) and model.pattern == "SFSF"
+    assert (model.sliding_window, model.vocab_size) == (8, len(task.dictionary))
+    with pytest.raises(ValueError, match="attention-shares"):
+        build_model(options.parse_args_and_arch(
+            options.get_training_parser(), argv + ["--attention-shares", "3"]),
+            task)
+
+    loss = LOSS_REGISTRY[args.loss](task)
+    trainer = Trainer(args, task, model, loss)
+    batch = next(task.get_batch_iterator(
+        task.datasets["train"], batch_size=4, seed=1, epoch=1,
+    ).next_epoch_itr(shuffle=False))
+    trainer.train_step([batch])
+    sums = {k: float(v) for k, v in jax.device_get(trainer._macc).items()}
+    assert np.isfinite(sums["loss"]) and sums["band_rows"] == 4
+    computed, visible = band_counts(Band(8), 128, 128)
+    assert sums["band_window_keys_visible"] == 4 * 2 * visible
+    assert "band_window_heads" not in sums and "moe_layers" not in sums
+    assert loss.trace_marks(sums) == {"attn_band": {
+        "window_keys_computed": 2 * computed, "window_keys_visible": 2 * visible,
+        "window_layers": 2, "full_keys_computed": 0, "full_keys_visible": 0,
+        "full_layers": 0}}
+    params = trainer.state["params"]["params"]
+    assert set(params) == {"embed_tokens", "decoder", "lm_head"}
+    assert set(params["decoder"]["units"]["layer_0"]) == {"norm", "self_attn"}
+    assert set(params["decoder"]["units"]["layer_1"]) == {"norm", "mlp"}
+
+
+def _leaves(*groups):
+    """``{path: shape}`` from ``(layers, {leaf: shape})`` groups: every
+    layer named in ``layers`` (blank-separated) has the group's leaves."""
+    return {f"{layer}/{leaf}".lstrip("/"): shape for layers, leaves in groups
+            for layer in (layers.split() or [""]) for leaf, shape in leaves.items()}
+
+
+_ATTN_4 = {"norm/weight": (64,), "self_attn/k_proj/kernel": (64, 32),
+           "self_attn/out_proj/kernel": (64, 64),
+           "self_attn/q_proj/kernel": (64, 64),
+           "self_attn/v_proj/kernel": (64, 32)}
+_GATED_EXPERTS = {"norm/weight": (64,), "moe/experts_fc1": (8, 64, 96),
+                  "moe/experts_fc2": (8, 48, 64), "moe/router": (64, 8)}
+_ENDS = {"decoder/final_norm/weight": (64,), "embed_tokens/embedding": (384, 64),
+         "lm_head": (64, 384)}
+#: the four tiny architectures' parameter trees over a dictionary of 384
+#: entries, written down at the commit before ``models/hybrid_lm.py`` (PR 44):
+#: every leaf float32
+TINY_TREES = {
+    "nemotron_h_tiny": _leaves(
+        ("", _ENDS), ("decoder/layers_0", _ATTN_4),
+        ("decoder/units/layer_0", {
+            "norm/weight": (2, 64), "moe/correction": (2, 16),
+            "moe/experts_fc1": (2, 16, 32, 48), "moe/experts_fc2": (2, 16, 48, 32),
+            "moe/latent_down/kernel": (2, 64, 32),
+            "moe/latent_up/kernel": (2, 32, 64), "moe/router": (2, 64, 16),
+            "moe/shared_fc1/kernel": (2, 64, 96),
+            "moe/shared_fc2/kernel": (2, 96, 64)}),
+        ("decoder/units/layer_1", {
+            "norm/weight": (2, 64), "mamba/A_log": (2, 4), "mamba/D_skip": (2, 4),
+            "mamba/conv_bias": (2, 96), "mamba/conv_kernel": (2, 4, 96),
+            "mamba/dt_bias": (2, 4), "mamba/in_proj/kernel": (2, 64, 132),
+            "mamba/norm/weight": (2, 32), "mamba/out_proj/kernel": (2, 32, 64)})),
+    "evabyte_tiny": _leaves(
+        ("", {"decoder/final_norm/offset": (64,),
+              "embed_tokens/embedding": (384, 64), "lm_head": (64, 3 * 384)}),
+        ("decoder/units/layer_0", {
+            "norm/offset": (3, 64), "self_attn/adaptive_mu_k": (3, 4, 16),
+            "self_attn/adaptive_phi": (3, 4, 16),
+            "self_attn/k_proj/kernel": (3, 64, 64),
+            "self_attn/out_proj/kernel": (3, 64, 64),
+            "self_attn/q_proj/kernel": (3, 64, 64),
+            "self_attn/v_proj/kernel": (3, 64, 64)}),
+        ("decoder/units/layer_1", {
+            "norm/offset": (3, 64), "mlp/fc1/kernel": (3, 64, 192),
+            "mlp/fc2/kernel": (3, 96, 64)})),
+    "mellum_tiny": _leaves(
+        ("", _ENDS),
+        ("decoder/layers_0 decoder/layers_2 decoder/layers_4", _ATTN_4),
+        ("decoder/layers_1 decoder/layers_3 decoder/layers_5", _GATED_EXPERTS)),
+    "laguna_tiny": _leaves(
+        ("", _ENDS),
+        ("decoder/layers_0 decoder/layers_6",
+         dict(_ATTN_4, **{"self_attn/gate_proj/kernel": (64, 4)})),
+        ("decoder/layers_2 decoder/layers_4", {
+            "norm/weight": (64,), "self_attn/gate_proj/kernel": (64, 6),
+            "self_attn/k_proj/kernel": (64, 32),
+            "self_attn/out_proj/kernel": (96, 64),
+            "self_attn/q_proj/kernel": (64, 96),
+            "self_attn/v_proj/kernel": (64, 32)}),
+        ("decoder/layers_1", {"norm/weight": (64,), "mlp/fc1/kernel": (64, 192),
+                              "mlp/fc2/kernel": (96, 64)}),
+        ("decoder/layers_3 decoder/layers_5 decoder/layers_7",
+         dict(_GATED_EXPERTS, **{"moe/shared_fc1/kernel": (64, 80),
+                                 "moe/shared_fc2/kernel": (40, 64)}))),
+}
+#: the options each model registers itself
+OPTIONS = {"nemotron_h_tiny": 24, "evabyte_tiny": 16, "mellum_tiny": 28,
+           "laguna_tiny": 35}
+
+
+@pytest.mark.parametrize("arch", list(TINY_TREES))
+def test_the_tiny_trees_are_the_parents_path_for_path(arch):
+    from argparse import ArgumentParser, Namespace
+
+    from unicore_tpu.models import ARCH_CONFIG_REGISTRY, ARCH_MODEL_REGISTRY
+
+    class Dictionary:
+        pad = staticmethod(lambda: 0)
+        __len__ = lambda self: 384
+
+    class task:
+        dictionary = Dictionary()
+
+    cls = ARCH_MODEL_REGISTRY[arch]
+    args = Namespace()
+    ARCH_CONFIG_REGISTRY[arch](args)
+    model = cls.build_model(args, task)
+    tok = np.zeros((2, 64), np.int32)
+    got = jax.eval_shape(lambda: model.init_params(
+        jax.random.key(0), {"net_input": {"src_tokens": tok}}))
+    flat = {"/".join(k.key for k in path): (leaf.shape, leaf.dtype)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(got["params"])[0]}
+    assert flat == {k: (v, jnp.float32) for k, v in TINY_TREES[arch].items()}
+    # every field but the two the task states is an argument, and no other
+    parser = ArgumentParser()
+    cls.add_args(parser)
+    options = {s for a in parser._actions for s in a.option_strings} - {"-h", "--help"}
+    assert options == {
+        "--" + f.replace("_", "-") for f in cls.__dataclass_fields__
+        if f not in ("name", "parent", "vocab_size", "padding_idx")}
+    assert len(options) == OPTIONS[arch]
+
+
+_KIND_SIZES = {
+    "M": dict(num_heads=4, head_dim=8, n_groups=2, state_size=16,
+              conv_kernel=4, chunk_size=8),
+    "*": dict(num_heads=4, num_kv_heads=2, head_dim=8),
+    "E": dict(latent_dim=16, expert_dim=24, shared_dim=40, n_routed=8, top_k=2),
+    "A": dict(num_heads=2, head_dim=16, window_size=8, chunk_size=4,
+              rope_theta=1e4),
+    "F": dict(ffn_dim=48),
+    "S": dict(num_heads=4, num_kv_heads=2, head_dim=8, window=8,
+              rope=dict(rope_theta=1e4)),
+    "G": dict(num_heads=4, num_kv_heads=2, head_dim=8, rope=dict(rope_theta=1e4)),
+    "R": dict(expert_dim=24, n_routed=8, top_k=2),
+}
+
+
+@pytest.mark.parametrize("kind", list("M*EAFSGR") + ["X"])
+def test_every_kind_of_the_table_builds_alone(kind):
+    """One layer of each kind under ``HybridDecoder``, with that kind's
+    sizes and no other's: the mixer under the name its row states, stats
+    from the kinds whose rows say so; a character the table lacks raises."""
+    from unicore_tpu.modules import hybrid_decoder
+    from unicore_tpu.modules.hybrid_decoder import KINDS, TABLE, HybridDecoder
+
+    assert KINDS == "M*EAFSGR" == "".join(_KIND_SIZES)
+    x = jax.random.normal(jax.random.key(0), (2, 24, 32))
+    decoder = HybridDecoder(pattern=kind, embed_dim=32, norm_eps=1e-5,
+                            sizes={kind: _KIND_SIZES.get(kind, {})})
+    if kind not in TABLE:
+        with pytest.raises(ValueError, match=re.escape(
+                "layer kind 'X' is not one of 'M*EAFSGR'")):
+            decoder.init(jax.random.key(1), x)
+        return
+    row = TABLE[kind]
+    params = decoder.init(jax.random.key(1), x)["params"]
+    assert set(params) == {"layers_0", "final_norm"}
+    assert set(params["layers_0"]) == {"norm", row.name}
+    y, stats = decoder.apply({"params": params}, x)
+    assert y.shape == x.shape and stats.shape == (len(STATS),)
+    assert bool(stats.any()) == row.stats
+    assert set(row.kept) <= set(hybrid_decoder.KEPT)
+    assert set(row.marks) <= set(hybrid_decoder.MARKS)
+    assert set(row.logs) <= set(hybrid_decoder.LOGS)
+
+
+def test_the_loss_names_no_stat_and_passes_over_one_no_owner_knows():
+    from unicore_tpu.losses import lm_cross_entropy
+    from unicore_tpu.losses.lm_cross_entropy import LMCrossEntropyLoss
+    from unicore_tpu.modules import hybrid_decoder
+
+    with open(lm_cross_entropy.__file__) as f:
+        source = f.read()
+    for prefix in ("moe_", "eva_", "band_"):
+        assert prefix not in source, prefix
+    assert [f.__name__ for f in hybrid_decoder.MARKS] == [
+        "route_mark", "keys_mark", "band_mark"]
+    sums = {"loss": 9.0, "_n": 1.0, "moe_layers": 2.0, "moe_pairs_here": 600.0,
+            "moe_load_max": 400.0, "moe_load_mean": 150.0, "moe_tiles_used": 9.0,
+            "moe_rows_wide": 0.0, "eva_rows": 2.0, "eva_keys_computed": 4096.0,
+            "eva_keys_visible": 2100.0, "eva_windows": 4.0, "eva_chunks": 32.0,
+            "band_rows": 2.0, "band_full_layers": 6.0, "band_full_keys_computed": 64.0,
+            "band_full_keys_visible": 32.0}
+    marks = LMCrossEntropyLoss.trace_marks(sums)
+    assert list(marks) == ["moe_route", "eva_keys", "attn_band"]
+    assert marks["attn_band"] == {
+        "full_keys_computed": 32, "full_keys_visible": 16, "full_layers": 3}
+    unknown = dict(sums, spectral_gap=3.0, band_width_guess=7.0, moe_mood=1.0)
+    assert LMCrossEntropyLoss.trace_marks(unknown) == marks
+    assert LMCrossEntropyLoss.trace_marks({"spectral_gap": 3.0}) == {}

@@ -24,23 +24,22 @@ from unicore_tpu.modules.hybrid_decoder import HybridDecoder
 # every width differs from every other, so a product is known by its shapes
 D, B, S = 32, 2, 24
 N = B * S
-SIZES = dict(
-    embed_dim=D, norm_eps=1e-5,
-    mamba=dict(num_heads=4, head_dim=8, n_groups=2, state_size=20,
-               conv_kernel=4, chunk_size=8),
-    attention=dict(num_heads=4, num_kv_heads=2, head_dim=8),
-    moe=dict(latent_dim=24, expert_dim=28, shared_dim=40, n_routed=12,
-             top_k=3, n_held=4, first_held=4, routed_scale=2.5),
-    eva=dict(num_heads=2, head_dim=16, window_size=8, chunk_size=4,
-             rope_theta=1e4),
-    mlp=dict(ffn_dim=48, row_chunk=16),
-    window_attention=dict(num_heads=4, num_kv_heads=2, head_dim=8, window=8,
-                          rope=dict(rope_theta=1e4)),
-    full_attention=dict(num_heads=4, num_kv_heads=2, head_dim=8,
-                        rope=dict(rope_theta=1e4)),
-    gated_moe=dict(expert_dim=20, n_routed=10, top_k=3, n_held=5,
-                   first_held=5, routed_scale=2.5, shared_dim=44),
-)
+SIZES = dict(embed_dim=D, norm_eps=1e-5, sizes={
+    "M": dict(num_heads=4, head_dim=8, n_groups=2, state_size=20,
+              conv_kernel=4, chunk_size=8),
+    "*": dict(num_heads=4, num_kv_heads=2, head_dim=8),
+    "E": dict(latent_dim=24, expert_dim=28, shared_dim=40, n_routed=12,
+              top_k=3, n_held=4, first_held=4, routed_scale=2.5),
+    "A": dict(num_heads=2, head_dim=16, window_size=8, chunk_size=4,
+              rope_theta=1e4),
+    "F": dict(ffn_dim=48, row_chunk=16),
+    "S": dict(num_heads=4, num_kv_heads=2, head_dim=8, window=8,
+              rope=dict(rope_theta=1e4)),
+    "G": dict(num_heads=4, num_kv_heads=2, head_dim=8,
+              rope=dict(rope_theta=1e4)),
+    "R": dict(expert_dim=20, n_routed=10, top_k=3, n_held=5,
+              first_held=5, routed_scale=2.5, shared_dim=44),
+})
 #: the right-hand shapes of an ``E`` layer's forward products over its
 #: tokens, and of the Mamba layer's ``in_proj`` (2 x 32 + 2 x 40 + 4 wide)
 PRODUCTS = dict(router=(D, 12), latent_down=(D, 24), latent_up=(24, D),
@@ -49,8 +48,8 @@ PRODUCTS = dict(router=(D, 12), latent_down=(D, 24), latent_up=(24, D),
 
 def decoder(pattern, remat=True, shared=True):
     """``shared`` false: ``R`` without its shared expert (``mellum``'s)."""
-    sizes = SIZES if shared else dict(
-        SIZES, gated_moe=dict(SIZES["gated_moe"], shared_dim=0))
+    sizes = SIZES if shared else dict(SIZES, sizes=dict(
+        SIZES["sizes"], R=dict(SIZES["sizes"]["R"], shared_dim=0)))
     return HybridDecoder(pattern=pattern, remat=remat, **sizes)
 
 

@@ -188,16 +188,16 @@ def test_no_gate_no_shared_expert_and_scale_one_are_mellums_layer_bit_for_bit():
     heads = dict(num_heads=4, num_kv_heads=2, head_dim=16)
     moe = dict(expert_dim=24, n_routed=8, top_k=2, balancing="batch_bias")
     old = HybridDecoder(
-        pattern="SRGR", embed_dim=32, norm_eps=1e-6, remat=False,
-        window_attention=dict(heads, window=16, rope=rope),
-        full_attention=dict(heads, rope=TINY_YARN), gated_moe=moe)
+        pattern="SRGR", embed_dim=32, norm_eps=1e-6, remat=False, sizes={
+            "S": dict(heads, window=16, rope=rope),
+            "G": dict(heads, rope=TINY_YARN), "R": moe})
     new = HybridDecoder(
-        pattern="SRGR", embed_dim=32, norm_eps=1e-6, remat=False,
-        window_attention=dict(heads, window=16, gate=False,
-                              rope=dict(rope, partial_rotary_factor=1)),
-        full_attention=dict(heads, gate=False,
-                            rope=dict(TINY_YARN, partial_rotary_factor=1)),
-        gated_moe=dict(moe, routed_scale=1.0, shared_dim=0))
+        pattern="SRGR", embed_dim=32, norm_eps=1e-6, remat=False, sizes={
+            "S": dict(heads, window=16, gate=False,
+                      rope=dict(rope, partial_rotary_factor=1)),
+            "G": dict(heads, gate=False,
+                      rope=dict(TINY_YARN, partial_rotary_factor=1)),
+            "R": dict(moe, routed_scale=1.0, shared_dim=0)})
     x = jax.random.normal(jax.random.key(3), (2, 48, 32), jnp.bfloat16)
     params = old.init(jax.random.key(4), x)
     assert (jax.tree_util.tree_structure(params)

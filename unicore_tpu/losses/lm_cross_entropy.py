@@ -5,7 +5,7 @@ The model predicts position t+1 from positions <= t, so the loss pairs
 causal-LM counterpart of losses/cross_entropy.py, matching the
 tasks/causal_lm.py contract (target == input token stream).
 
-A model that states ``loss_chunk`` (models/nemotron_h.py) is asked for its
+A model that states ``loss_chunk`` (models/hybrid_lm.py) is asked for its
 final hidden states instead of its logits, and the output head and the
 loss run over ``loss_chunk`` tokens at a time (:func:`chunked_lm_nll`): at
 8,192 x 16,384 the float32 logits alone would be 512 MB, and as much again
@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from unicore_tpu.logging import metrics
+from unicore_tpu.modules.hybrid_decoder import LOGS, MARKS
 from . import register_loss
 from .unicore_loss import UnicoreLoss
 
@@ -147,70 +148,21 @@ class LMCrossEntropyLoss(UnicoreLoss):
         metrics.log_scalar(
             "loss", loss_sum / sample_size / jnp.log(2), sample_size, round=3
         )
-        layers = sum(log.get("moe_layers", 0) for log in logging_outputs)
-        if layers > 0:
-            # an expert layer's routing, per layer and update
-            # (modules/latent_moe.py): how uneven the held experts' loads
-            # are, how many tiles of rows they fill, and how many of the
-            # pairs went through the loop's wide trips
-            for key in ("moe_load_max", "moe_load_mean", "moe_tiles_used",
-                        "moe_rows_wide"):
-                total = sum(log.get(key, 0) for log in logging_outputs)
-                metrics.log_scalar(key, total / layers, 1, round=2)
+        for log_stats in LOGS:
+            log_stats(logging_outputs)
 
     @staticmethod
     def trace_marks(sums):
         """What a profiler capture is told of one update, from that
-        update's summed logging output (``Trainer._mark_update``): for a
-        model with routed experts one ``unicore:moe_route`` mark with the
-        (token, held expert) pairs of all its expert layers, the tiles of
-        ``latent_moe.TILE`` rows they filled (what dispatch and combine
-        moved, each way), the pairs among them that went ``latent_moe.WIDE``
-        rows a trip (``rows_wide / pairs_here``: how often the wide loop
-        engages; 0 where the even load builds none) and the most loaded
-        held expert's and the mean load, per layer; for a model with
-        window-plus-summary attention one ``unicore:eva_keys`` mark with
-        the keys its kernel form scored and the keys its queries could see;
-        for a model whose attention
-        runs under a band the kernels mask themselves one
-        ``unicore:attn_band`` mark with, for its sliding-window and its
-        full layers apart (two maps: none of its stats is named
-        ``keys_computed``, which a reader takes for the pairs of ONE mapped
-        call), the pairs the kernels scored and the pairs a query could
-        see, per row and head, summed over the layers of each kind; and,
-        where the model states them (its two kinds of layer hold different
-        numbers of query heads: ``models/laguna.py``), ``window_heads`` and
-        ``full_heads``, the query heads held on a layer of each kind."""
+        update's summed logging output (``Trainer._mark_update``):
+        ``{name: stats}``, each entry one ``unicore:<name>`` mark.  Which
+        marks, from which of the stats a model logs, is for the makers of
+        those stats to say, each beside the function that counts them; the
+        layer kinds' table lists them (``modules/hybrid_decoder.MARKS``),
+        and a stat none of them knows is in no mark."""
         marks = {}
-        layers = sums.get("moe_layers", 0)
-        if layers:
-            marks["moe_route"] = dict(
-                pairs_here=int(sums["moe_pairs_here"]),
-                tiles_used=int(sums["moe_tiles_used"]),
-                rows_wide=int(sums["moe_rows_wide"]),
-                load_max=sums["moe_load_max"] / layers,
-                load_mean=sums["moe_load_mean"] / layers,
-            )
-        if sums.get("eva_rows", 0):
-            # per layer and head, summed over the update's queries
-            # (models/evabyte.py, ops/eva_attention.key_counts)
-            marks["eva_keys"] = dict(
-                keys_computed=int(sums["eva_keys_computed"]),
-                keys_visible=int(sums["eva_keys_visible"]),
-                windows=int(sums["eva_windows"]),
-                chunks=int(sums["eva_chunks"]),
-            )
-        rows = sums.get("band_rows", 0)
-        if rows:
-            # models/mellum.py: ops/flash_attention.band_counts of the maps
-            # the kernels are handed
-            marks["attn_band"] = {
-                f"{kind}_{stat}": int(sums[f"band_{kind}_{stat}"] / rows)
-                for kind in ("window", "full")
-                for stat in ("keys_computed", "keys_visible", "layers",
-                             "heads")
-                if f"band_{kind}_{stat}" in sums
-            }
+        for mark_of in MARKS:
+            marks.update(mark_of(sums))
         return marks
 
     @staticmethod

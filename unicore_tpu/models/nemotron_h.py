@@ -12,25 +12,20 @@ says how a share maps to one): ``--pattern-held`` (the layers),
 ``--mixer-shares`` (the mixers' heads divided that many ways) and
 ``--n-routed-experts-held``.
 
-The loss does not need all logits at once: ``features_only=True`` returns
-the final hidden states and the routing stats, and ``lm_cross_entropy``
-runs head and loss over ``--loss-chunk`` tokens at a time.
+Embedding, head, building and the memory arguments are ``models/
+hybrid_lm.py``'s; the model logs its expert layers' routing stats.
 """
 
 import flax.linen as nn
-import jax
-import jax.numpy as jnp
 
-from unicore_tpu import utils
-from unicore_tpu.models import register_model, register_model_architecture
-from unicore_tpu.models.unicore_model import (
-    BaseUnicoreModel,
-    strip_diagnostic_collections,
+from unicore_tpu.models import register_model
+from unicore_tpu.models.hybrid_lm import (
+    HybridLM,
+    held_attention,
+    register_architecture,
 )
-from unicore_tpu.modules.hybrid_decoder import KINDS, HybridDecoder
-from unicore_tpu.modules.latent_moe import STATS
-
-_init = nn.initializers.normal(0.02)
+from unicore_tpu.modules.hybrid_decoder import KINDS
+from unicore_tpu.modules.latent_moe import route_log
 
 #: NVIDIA-Nemotron-3-Super-120B-A12B's 88 layers
 SUPER_120B_PATTERN = (
@@ -40,9 +35,8 @@ SUPER_120B_PATTERN = (
 
 
 @register_model("nemotron_h")
-class NemotronHModel(BaseUnicoreModel):
+class NemotronHModel(HybridLM):
     vocab_size: int = 131072
-    padding_idx: int = 0
     hidden_size: int = 4096
     hybrid_override_pattern: str = SUPER_120B_PATTERN
     pattern_held: str = ""
@@ -68,100 +62,59 @@ class NemotronHModel(BaseUnicoreModel):
     moe_intermediate_size: int = 2688
     moe_shared_expert_intermediate_size: int = 5376
     routed_scaling_factor: float = 5.0
-    # memory
-    remat: bool = True
-    loss_chunk: int = 1024
 
-    @classmethod
-    def add_args(cls, parser):
-        add = parser.add_argument
-        add("--hidden-size", type=int)
-        add("--hybrid-override-pattern", type=str,
-            help="one character per layer: M Mamba-2, * attention, "
-                 "E LatentMoE")
-        add("--pattern-held", type=str,
-            help="the layers held here, in the pattern's characters (a "
-                 "stretch of --hybrid-override-pattern; empty: all of it)")
-        add("--mixer-shares", type=int,
-            help="the mixers' heads are divided this many ways and this "
-                 "process holds one share: 1/N of the Mamba-2 heads and "
-                 "B/C groups and of the query heads, with their KV heads "
-                 "(at least one)")
-        add("--layer-norm-epsilon", type=float)
-        add("--mamba-num-heads", type=int)
-        add("--mamba-head-dim", type=int)
-        add("--n-groups", type=int, help="Mamba-2 B/C groups")
-        add("--ssm-state-size", type=int)
-        add("--conv-kernel", type=int)
-        add("--chunk-size", type=int, help="tokens per chunk of the scan")
-        add("--num-attention-heads", type=int)
-        add("--num-key-value-heads", type=int)
-        add("--head-dim", type=int)
-        add("--n-routed-experts", type=int,
-            help="routed experts of the model (the router's width)")
-        add("--n-routed-experts-held", type=int,
-            help="routed experts held here (0: all): the layer routes over "
-                 "all of them and computes the held ones' part")
-        add("--first-routed-expert-held", type=int)
-        add("--num-experts-per-tok", type=int)
-        add("--moe-latent-size", type=int)
-        add("--moe-intermediate-size", type=int)
-        add("--moe-shared-expert-intermediate-size", type=int)
-        add("--routed-scaling-factor", type=float)
-        add("--remat", type=utils.str_to_bool,
-            help="rematerialize each layer in the backward pass")
-        add("--loss-chunk", type=int,
-            help="tokens per chunk of the output head and loss (0: all "
-                 "logits at once)")
+    HELP = dict(
+        hybrid_override_pattern="one character per layer: M Mamba-2, "
+                                "* attention, E LatentMoE",
+        pattern_held="the layers held here, in the pattern's characters (a "
+                     "stretch of --hybrid-override-pattern; empty: all of "
+                     "it)",
+        mixer_shares="the mixers' heads are divided this many ways and this "
+                     "process holds one share: 1/N of the Mamba-2 heads and "
+                     "B/C groups and of the query heads, with their KV "
+                     "heads (at least one)",
+        n_groups="Mamba-2 B/C groups",
+        chunk_size="tokens per chunk of the scan",
+        n_routed_experts="routed experts of the model (the router's width)",
+        n_routed_experts_held="routed experts held here (0: all): the layer "
+                              "routes over all of them and computes the "
+                              "held ones' part",
+    )
 
-    @classmethod
-    def build_model(cls, args, task):
-        nemotron_h_base_architecture(args)
-        bad = set(args.hybrid_override_pattern + args.pattern_held) - set(KINDS)
+    def check(self):
+        bad = set(self.hybrid_override_pattern + self.pattern_held) - set(KINDS)
         if bad:
             raise ValueError(
                 f"the layer pattern holds {sorted(bad)}; layer kinds are "
                 f"{KINDS!r}"
             )
-        n = args.mixer_shares
-        if (n < 1 or args.mamba_num_heads % n or args.n_groups % n
-                or args.num_attention_heads % n):
-            raise ValueError(
-                f"--mixer-shares {n} does not divide {args.mamba_num_heads} "
-                f"Mamba heads in {args.n_groups} groups and "
-                f"{args.num_attention_heads} query heads"
-            )
-        fields = {f: getattr(args, f) for f in cls.__dataclass_fields__
-                  if hasattr(args, f) and f not in ("name", "parent")}
-        fields.update(vocab_size=len(task.dictionary),
-                      padding_idx=task.dictionary.pad())
-        return cls(**fields)
-
-    def setup(self):
-        self.embed_tokens = nn.Embed(
-            self.vocab_size, self.hidden_size, embedding_init=_init,
-            name="embed_tokens", param_dtype=jnp.float32,
-        )
         n = self.mixer_shares
-        self.decoder = HybridDecoder(
-            pattern=self.pattern_held or self.hybrid_override_pattern,
-            embed_dim=self.hidden_size,
-            norm_eps=self.layer_norm_epsilon,
-            mamba=dict(
+        if (n < 1 or self.mamba_num_heads % n or self.n_groups % n
+                or self.num_attention_heads % n):
+            raise ValueError(
+                f"--mixer-shares {n} does not divide {self.mamba_num_heads} "
+                f"Mamba heads in {self.n_groups} groups and "
+                f"{self.num_attention_heads} query heads"
+            )
+
+    @property
+    def pattern(self):
+        return self.pattern_held or self.hybrid_override_pattern
+
+    def layers(self):
+        n = self.mixer_shares
+        return dict(norm_eps=self.layer_norm_epsilon, sizes={
+            "M": dict(
                 num_heads=self.mamba_num_heads // n,
                 head_dim=self.mamba_head_dim, n_groups=self.n_groups // n,
                 state_size=self.ssm_state_size,
                 conv_kernel=self.conv_kernel, chunk_size=self.chunk_size,
                 norm_eps=self.layer_norm_epsilon,
             ),
-            attention=dict(
-                num_heads=self.num_attention_heads // n,
-                # fewer KV heads than shares: the shares of one KV head's
-                # query heads each hold a copy of it
-                num_kv_heads=max(1, self.num_key_value_heads // n),
-                head_dim=self.head_dim,
-            ),
-            moe=dict(
+            "*": held_attention(
+                self.num_attention_heads, self.num_key_value_heads, n,
+                head_dim=self.head_dim),
+            "E": dict(
                 latent_dim=self.moe_latent_size,
                 expert_dim=self.moe_intermediate_size,
                 shared_dim=self.moe_shared_expert_intermediate_size,
@@ -171,51 +124,25 @@ class NemotronHModel(BaseUnicoreModel):
                 first_held=self.first_routed_expert_held,
                 routed_scale=self.routed_scaling_factor,
             ),
-            remat=self.remat,
-            name="decoder",
-        )
-        self.lm_head = self.param(
-            "lm_head", _init, (self.hidden_size, self.vocab_size), jnp.float32
-        )
+        })
 
-    def __call__(self, src_tokens, train: bool = False,
-                 features_only: bool = False, **kwargs):
-        x, stats = self.decoder(self.embed_tokens(src_tokens))
-        if features_only:
-            return x, {"moe_" + k: stats[i] for i, k in enumerate(STATS)}
-        with jax.named_scope("lm_head"):
-            return x @ self.lm_head.astype(x.dtype)
-
-    def init_params(self, rng, sample):
-        src_tokens = jnp.asarray(sample["net_input"]["src_tokens"])
-        return strip_diagnostic_collections(
-            self.init({"params": rng}, src_tokens, train=False)
-        )
+    @nn.nowrap
+    def logged(self, stats, rows, length):
+        return route_log(stats)
 
 
-@register_model_architecture("nemotron_h", "nemotron_h")
-def nemotron_h_base_architecture(args):
-    """Unset sizes default to NVIDIA-Nemotron-3-Super-120B-A12B's, whole."""
-    for field, default in NemotronHModel.__dataclass_fields__.items():
-        if field in ("name", "parent", "vocab_size", "padding_idx"):
-            continue
-        if getattr(args, field, None) is None:
-            setattr(args, field, default.default)
+#: unset sizes default to NVIDIA-Nemotron-3-Super-120B-A12B's, whole
+nemotron_h_base_architecture = register_architecture(
+    "nemotron_h", "nemotron_h")
 
-
-@register_model_architecture("nemotron_h", "nemotron_h_tiny")
-def nemotron_h_tiny_architecture(args):
-    """Every mechanism at a size a CPU test holds: attention, two repeats
-    of an ``EM`` unit, 16 experts of which any number may be held."""
-    tiny = dict(
+#: every mechanism at a size a CPU test holds: attention, two repeats of an
+#: ``EM`` unit, 16 experts of which any number may be held
+nemotron_h_tiny_architecture = register_architecture(
+    "nemotron_h", "nemotron_h_tiny", dict(
         hidden_size=64, hybrid_override_pattern="*EMEM",
         mamba_num_heads=4, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
         chunk_size=16, num_attention_heads=4, num_key_value_heads=2,
         head_dim=16, n_routed_experts=16, num_experts_per_tok=4,
         moe_latent_size=32, moe_intermediate_size=48,
         moe_shared_expert_intermediate_size=96, loss_chunk=32,
-    )
-    for field, value in tiny.items():
-        if getattr(args, field, None) is None:
-            setattr(args, field, value)
-    nemotron_h_base_architecture(args)
+    ))

@@ -12,7 +12,9 @@ router and no shared expert.  Every layer is
 
 with one mixer per layer, a final RMSNorm after the last, no biases (the
 convolution's apart) and no dropout.  A transformer layer of the usual
-kind is two of these: ``AF``.
+kind is two of these: ``AF``.  A kind is one row of :data:`TABLE`, and a
+model states the sizes of the kinds its pattern holds as one mapping,
+``sizes={"A": dict(...), "F": dict(...)}``.
 
 A pattern whose tail repeats (``*EMEMEMEMEM`` = ``*`` + 5 x ``EM``) runs
 the repeated unit as ONE traced body under ``nn.scan``, its parameters
@@ -42,12 +44,13 @@ iteration, its own cycle estimate ranks that form under ``shared_fc1``'s
 name alone, and the chip ranks it above (PERF.md, PR 43).
 """
 
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from unicore_tpu.ops import eva_attention, flash_attention
 from . import latent_moe, mamba2
 from .eva_attention import EvaAttention
 from .gated_mlp import GatedMLP
@@ -57,17 +60,67 @@ from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
 from .multihead_attention import GroupedQueryAttention
 
-KINDS = "M*EAFSGR"
+
+class Kind(NamedTuple):
+    """One layer kind: the class of its mixer, the name the mixer takes in
+    the parameter tree, what it is always built with (beside the sizes a
+    model states for the kind), whether it returns ``(y, stats)``
+    (``latent_moe.STATS``) and not ``y`` alone, the names it gives the
+    arrays it wants kept across the forward pass, and what the makers of
+    the stats a model logs of such layers say of them: ``logs`` to the
+    training log (each a function of an update's logging outputs) and
+    ``marks`` to a profiler capture (each from an update's summed logging
+    output to ``{mark: stats}``, empty where the sums hold none of its)."""
+
+    module: type
+    name: str
+    always: dict = {}
+    stats: bool = False
+    kept: Tuple[str, ...] = ()
+    logs: Tuple[Callable, ...] = ()
+    marks: Tuple[Callable, ...] = ()
+
+
+# E and R share one loop, S and G one band
+_EXPERTS = dict(stats=True, kept=latent_moe.KEPT,
+                logs=(latent_moe.route_scalars,),
+                marks=(latent_moe.route_mark,))
+_BANDED = dict(always=dict(banded=True), marks=(flash_attention.band_mark,))
+
+#: the layer kinds by their character in a pattern: a new mixer is its
+#: module and a row here
+TABLE = {
+    "M": Kind(Mamba2Mixer, "mamba", kept=mamba2.KEPT),
+    "*": Kind(GroupedQueryAttention, "self_attn"),
+    "E": Kind(LatentMoE, "moe", **_EXPERTS),
+    "A": Kind(EvaAttention, "self_attn", marks=(eva_attention.keys_mark,)),
+    "F": Kind(GatedMLP, "mlp"),
+    "S": Kind(GroupedQueryAttention, "self_attn", **_BANDED),
+    "G": Kind(GroupedQueryAttention, "self_attn", **_BANDED),
+    "R": Kind(GatedMoE, "moe", **_EXPERTS),
+}
+
+
+def _each_once(field):
+    return tuple(dict.fromkeys(
+        v for row in TABLE.values() for v in getattr(row, field)))
+
+
+KINDS = "".join(TABLE)
 
 #: every name a layer kind gives an array it wants kept across the forward
-#: pass: each kind's own tuple (``E`` and ``R`` share ``latent_moe``'s)
-KEPT = latent_moe.KEPT + mamba2.KEPT
+#: pass
+KEPT = _each_once("kept")
+
+#: what the kinds' stats tell the log and a profiler capture: a loss runs
+#: over them (``losses/lm_cross_entropy.py``) and names none
+LOGS, MARKS = _each_once("logs"), _each_once("marks")
 
 
 def _remat(cls):
     """``cls`` rematerialized in the backward pass, but for what its
     layers name: :data:`KEPT` (a name no layer of a pattern gives keeps
-    nothing; a kind that names an array adds its tuple there)."""
+    nothing; a kind that names an array states it in its row)."""
     return nn.remat(
         cls, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 
@@ -93,46 +146,23 @@ class HybridBlock(nn.Module):
     kind: str
     embed_dim: int
     norm_eps: float
-    mamba: Optional[dict] = None
-    attention: Optional[dict] = None
-    moe: Optional[dict] = None
-    eva: Optional[dict] = None
-    mlp: Optional[dict] = None
-    window_attention: Optional[dict] = None
-    full_attention: Optional[dict] = None
-    gated_moe: Optional[dict] = None
+    sizes: dict  # the pattern's kinds -> what each one's mixer is built with
     norm_unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        h = RMSNorm(self.embed_dim, eps=self.norm_eps, name="norm",
-                    unit_offset=self.norm_unit_offset)(x)
-        stats = jnp.zeros((len(STATS),), jnp.float32)
-        if self.kind == "M":
-            y = Mamba2Mixer(self.embed_dim, name="mamba", **self.mamba)(h)
-        elif self.kind == "*":
-            y = GroupedQueryAttention(
-                self.embed_dim, name="self_attn", **self.attention
-            )(h)
-        elif self.kind == "E":
-            y, stats = LatentMoE(self.embed_dim, name="moe", **self.moe)(h)
-        elif self.kind == "A":
-            y = EvaAttention(self.embed_dim, name="self_attn", **self.eva)(h)
-        elif self.kind == "F":
-            y = GatedMLP(self.embed_dim, name="mlp", **self.mlp)(h)
-        elif self.kind in "SG":
-            y = GroupedQueryAttention(
-                self.embed_dim, name="self_attn", banded=True,
-                **(self.window_attention if self.kind == "S"
-                   else self.full_attention),
-            )(h)
-        elif self.kind == "R":
-            y, stats = GatedMoE(
-                self.embed_dim, name="moe", **self.gated_moe)(h)
-        else:
+        row = TABLE.get(self.kind)
+        if row is None:
             raise ValueError(
                 f"layer kind {self.kind!r} is not one of {KINDS!r}"
             )
+        h = RMSNorm(self.embed_dim, eps=self.norm_eps, name="norm",
+                    unit_offset=self.norm_unit_offset)(x)
+        stats = jnp.zeros((len(STATS),), jnp.float32)
+        y = row.module(self.embed_dim, name=row.name, **row.always,
+                       **self.sizes[self.kind])(h)
+        if row.stats:
+            y, stats = y
         return x + y, stats
 
 
@@ -155,16 +185,9 @@ class HybridDecoder(nn.Module):
     pattern: str
     embed_dim: int
     norm_eps: float
-    # the sizes of each layer kind the pattern holds
-    mamba: Optional[dict] = None       # M: Mamba2Mixer's
-    attention: Optional[dict] = None   # *: GroupedQueryAttention's
-    moe: Optional[dict] = None         # E: LatentMoE's
-    eva: Optional[dict] = None         # A: EvaAttention's
-    mlp: Optional[dict] = None         # F: GatedMLP's
-    # S, G: GroupedQueryAttention's, banded (S states a window; each its rope)
-    window_attention: Optional[dict] = None
-    full_attention: Optional[dict] = None
-    gated_moe: Optional[dict] = None   # R: GatedMoE's
+    # for each kind the pattern holds (:data:`TABLE`), the sizes its mixer
+    # is built with: ``{"S": dict(num_heads=..., window=..., rope=...), ...}``
+    sizes: dict
     remat: bool = True
     norm_unit_offset: bool = False  # every norm's gain is 1 + its parameter
 
@@ -172,14 +195,9 @@ class HybridDecoder(nn.Module):
     def __call__(self, x):
         """``x`` (B, L, embed_dim) -> ``(x, stats)``: the final-normed
         stream and the expert layers' routing stats summed over layers
-        (``latent_moe.STATS``; all zero where no layer is ``E``)."""
+        (``latent_moe.STATS``; all zero where no layer returns any)."""
         block = dict(embed_dim=self.embed_dim, norm_eps=self.norm_eps,
-                     mamba=self.mamba, attention=self.attention, moe=self.moe,
-                     eva=self.eva, mlp=self.mlp,
-                     window_attention=self.window_attention,
-                     full_attention=self.full_attention,
-                     gated_moe=self.gated_moe,
-                     norm_unit_offset=self.norm_unit_offset)
+                     sizes=self.sizes, norm_unit_offset=self.norm_unit_offset)
         wrap = _remat if self.remat else (lambda cls: cls)
         head, unit, repeats = split_pattern(self.pattern)
         stats = jnp.zeros((len(STATS),), jnp.float32)

@@ -125,6 +125,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from unicore_tpu.logging import metrics
 from unicore_tpu.ops import rows_add
 from unicore_tpu.quant.dense import QuantDense
 
@@ -137,6 +138,46 @@ _init = nn.initializers.normal(0.02)
 #: pairs in the rows of wide trips (0 where no wide loop is built)
 STATS = ("pairs_here", "load_max", "load_mean", "layers", "tiles_used",
          "rows_wide")
+
+
+def route_log(stats):
+    """What a model logs of one update's expert layers: their summed
+    :data:`STATS`, under the names the two functions below read."""
+    return {"moe_" + k: stats[i] for i, k in enumerate(STATS)}
+
+
+def route_scalars(logging_outputs):
+    """The log's lines of an expert layer's routing, per layer and update:
+    how uneven the held experts' loads are, how many tiles of rows they
+    fill, and how many of the pairs went through the loop's wide trips."""
+    layers = sum(log.get("moe_layers", 0) for log in logging_outputs)
+    if layers > 0:
+        for key in ("moe_load_max", "moe_load_mean", "moe_tiles_used",
+                    "moe_rows_wide"):
+            total = sum(log.get(key, 0) for log in logging_outputs)
+            metrics.log_scalar(key, total / layers, 1, round=2)
+
+
+def route_mark(sums):
+    """What a profiler capture is told of one update of a model with routed
+    experts, from that update's summed logging output: one
+    ``unicore:moe_route`` mark with the (token, held expert) pairs of all
+    its expert layers, the tiles of :data:`TILE` rows they filled (what
+    dispatch and combine moved, each way), the pairs among them that went
+    :data:`WIDE` rows a trip (``rows_wide / pairs_here``: how often the wide
+    loop engages; 0 where the even load builds none) and the most loaded
+    held expert's and the mean load, per layer.  Nothing where no layer
+    routes."""
+    layers = sums.get("moe_layers", 0)
+    if not layers:
+        return {}
+    return {"moe_route": dict(
+        pairs_here=int(sums["moe_pairs_here"]),
+        tiles_used=int(sums["moe_tiles_used"]),
+        rows_wide=int(sums["moe_rows_wide"]),
+        load_max=sums["moe_load_max"] / layers,
+        load_mean=sums["moe_load_mean"] / layers,
+    )}
 
 
 #: rows per tile of the grouped products (the MXU's 128 rows)

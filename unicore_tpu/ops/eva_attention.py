@@ -209,3 +209,31 @@ def key_counts(length, window, chunk):
     visible = W * window * (window + 1) // 2 + window * cpw * W * (W - 1) // 2
     return {"computed": computed, "visible": visible, "windows": W,
             "chunks": length // chunk}
+
+
+def keys_log(rows, length, window, chunk):
+    """What a model with this attention logs of an update of ``rows`` rows,
+    from shapes: per layer and head, summed over the batch's queries,
+    :func:`key_counts`, with the batch's windows and chunks."""
+    counts = key_counts(length, window, chunk)
+    out = dict(eva_keys_computed=counts["computed"],
+               eva_keys_visible=counts["visible"],
+               eva_windows=counts["windows"], eva_chunks=counts["chunks"],
+               eva_rows=1)
+    return {k: jnp.asarray(rows * v, jnp.float32) for k, v in out.items()}
+
+
+def keys_mark(sums):
+    """What a profiler capture is told of one update of such a model, from
+    that update's summed logging output: one ``unicore:eva_keys`` mark with
+    the keys the kernel form scored and the keys its queries could see, per
+    layer and head, and the update's windows and chunks.  Nothing where no
+    row was logged."""
+    if not sums.get("eva_rows", 0):
+        return {}
+    return {"eva_keys": dict(
+        keys_computed=int(sums["eva_keys_computed"]),
+        keys_visible=int(sums["eva_keys_visible"]),
+        windows=int(sums["eva_windows"]),
+        chunks=int(sums["eva_chunks"]),
+    )}

@@ -1106,6 +1106,50 @@ def band_counts(band, q_len, k_len, block_q=256, block_k=512):
     return int(visits.sum()) * BQ * BK, int(seen.sum())
 
 
+def band_log(rows, length, window, layers, heads=None):
+    """What a model whose attention runs under bands logs of an update of
+    ``rows`` rows of ``length``: for its sliding-window layers (``window``
+    positions) and its full layers apart, :func:`band_counts` of the maps
+    the kernels are handed (the row padded to their 128 tile, their default
+    blocks), summed over the batch's rows and over the layers of each kind,
+    per head.  ``layers``: the layers of each kind, ``{"window": n, "full":
+    n}``.  ``heads``: the same keys, the query heads held on a layer of each
+    kind, for a model whose two kinds differ there (one that states none
+    logs none: a key more is an output more of its step)."""
+    padded = length + (-length) % 128
+    out = {"band_rows": 1}
+    for kind, band in (("window", Band(window)), ("full", Band(None))):
+        computed, visible = band_counts(band, padded, padded)
+        out.update({
+            f"band_{kind}_keys_computed": layers[kind] * computed,
+            f"band_{kind}_keys_visible": layers[kind] * visible,
+            f"band_{kind}_layers": layers[kind],
+        })
+        if heads is not None:
+            out[f"band_{kind}_heads"] = heads[kind]
+    return {k: jnp.asarray(rows * v, jnp.float32) for k, v in out.items()}
+
+
+def band_mark(sums):
+    """What a profiler capture is told of one update of such a model, from
+    that update's summed logging output: one ``unicore:attn_band`` mark
+    with, for its sliding-window and its full layers apart (two maps: none
+    of its stats is named ``keys_computed``, which a reader takes for the
+    pairs of ONE mapped call), the pairs the kernels scored and the pairs a
+    query could see, per row and head, summed over the layers of each kind;
+    and, where the model logs them, ``window_heads`` and ``full_heads``.
+    Nothing where no row was logged."""
+    rows = sums.get("band_rows", 0)
+    if not rows:
+        return {}
+    return {"attn_band": {
+        f"{kind}_{stat}": int(sums[f"band_{kind}_{stat}"] / rows)
+        for kind in ("window", "full")
+        for stat in ("keys_computed", "keys_visible", "layers", "heads")
+        if f"band_{kind}_{stat}" in sums
+    }}
+
+
 def mha_reference(q, k, v, bias=None, kv_padding_mask=None, sm_scale=1.0):
     """Pure-jnp reference for numerics tests."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
