@@ -1156,10 +1156,12 @@ _KIND_SIZES = {
               rope=dict(rope_theta=1e4)),
     "G": dict(num_heads=4, num_kv_heads=2, head_dim=8, rope=dict(rope_theta=1e4)),
     "R": dict(expert_dim=24, n_routed=8, top_k=2),
+    "C": dict(num_heads=4, num_kv_heads=2, head_dim=8, rope=dict(rope_theta=1e4)),
+    "Z": dict(expert_dim=24, n_routed=8, router_dim=12),
 }
 
 
-@pytest.mark.parametrize("kind", list("M*EAFSGR") + ["X"])
+@pytest.mark.parametrize("kind", list("M*EAFSGRCZ") + ["X"])
 def test_every_kind_of_the_table_builds_alone(kind):
     """One layer of each kind under ``HybridDecoder``, with that kind's
     sizes and no other's: the mixer under the name its row states, stats
@@ -1167,13 +1169,13 @@ def test_every_kind_of_the_table_builds_alone(kind):
     from unicore_tpu.modules import hybrid_decoder
     from unicore_tpu.modules.hybrid_decoder import KINDS, TABLE, HybridDecoder
 
-    assert KINDS == "M*EAFSGR" == "".join(_KIND_SIZES)
+    assert KINDS == "M*EAFSGRCZ" == "".join(_KIND_SIZES)
     x = jax.random.normal(jax.random.key(0), (2, 24, 32))
     decoder = HybridDecoder(pattern=kind, embed_dim=32, norm_eps=1e-5,
                             sizes={kind: _KIND_SIZES.get(kind, {})})
     if kind not in TABLE:
         with pytest.raises(ValueError, match=re.escape(
-                "layer kind 'X' is not one of 'M*EAFSGR'")):
+                "layer kind 'X' is not one of 'M*EAFSGRCZ'")):
             decoder.init(jax.random.key(1), x)
         return
     row = TABLE[kind]
@@ -1181,7 +1183,10 @@ def test_every_kind_of_the_table_builds_alone(kind):
     assert set(params) == {"layers_0", "final_norm"}
     assert set(params["layers_0"]) == {"norm", row.name}
     y, stats = decoder.apply({"params": params}, x)
-    assert y.shape == x.shape and stats.shape == (len(STATS),)
+    # a kind's own stats follow the six every expert layer returns
+    assert y.shape == x.shape and stats.shape == (
+        len(STATS) + len(row.more_stats),)
+    assert hybrid_decoder.stat_names(kind) == STATS + row.more_stats
     assert bool(stats.any()) == row.stats
     assert set(row.kept) <= set(hybrid_decoder.KEPT)
     assert set(row.marks) <= set(hybrid_decoder.MARKS)
@@ -1198,7 +1203,7 @@ def test_the_loss_names_no_stat_and_passes_over_one_no_owner_knows():
     for prefix in ("moe_", "eva_", "band_"):
         assert prefix not in source, prefix
     assert [f.__name__ for f in hybrid_decoder.MARKS] == [
-        "route_mark", "keys_mark", "band_mark"]
+        "route_mark", "keys_mark", "band_mark", "band_call_mark", "skip_mark"]
     sums = {"loss": 9.0, "_n": 1.0, "moe_layers": 2.0, "moe_pairs_here": 600.0,
             "moe_load_max": 400.0, "moe_load_mean": 150.0, "moe_tiles_used": 9.0,
             "moe_rows_wide": 0.0, "eva_rows": 2.0, "eva_keys_computed": 4096.0,
@@ -1206,9 +1211,14 @@ def test_the_loss_names_no_stat_and_passes_over_one_no_owner_knows():
             "band_rows": 2.0, "band_full_layers": 6.0, "band_full_keys_computed": 64.0,
             "band_full_keys_visible": 32.0}
     marks = LMCrossEntropyLoss.trace_marks(sums)
-    assert list(marks) == ["moe_route", "eva_keys", "attn_band"]
+    # full layers alone run under one map: the pairs of ONE call are stated
+    assert list(marks) == ["moe_route", "eva_keys", "attn_band",
+                           "attn_band_call"]
     assert marks["attn_band"] == {
         "full_keys_computed": 32, "full_keys_visible": 16, "full_layers": 3}
+    assert marks["attn_band_call"] == {"keys_computed": 21, "keys_visible": 10}
+    two_maps = dict(sums, band_window_layers=2.0)
+    assert "attn_band_call" not in LMCrossEntropyLoss.trace_marks(two_maps)
     unknown = dict(sums, spectral_gap=3.0, band_width_guess=7.0, moe_mood=1.0)
     assert LMCrossEntropyLoss.trace_marks(unknown) == marks
     assert LMCrossEntropyLoss.trace_marks({"spectral_gap": 3.0}) == {}

@@ -128,7 +128,7 @@ class LMCrossEntropyLoss(UnicoreLoss):
         target = target.reshape((B * L,) + target.shape[2:])
         valid = target != self.padding_idx
         loss = chunked_lm_nll(
-            x.reshape(B * L, d), params["params"]["lm_head"],
+            x.reshape(B * L, d), model.head_kernel(params),
             jnp.where(valid, target, 0), valid,
             int(model.loss_chunk) or B * L,
         )

@@ -1,7 +1,8 @@
 """What the decoders over :class:`~unicore_tpu.modules.hybrid_decoder.
 HybridDecoder` share (``nemotron_h``, ``evabyte``, ``mellum``, ``laguna``):
-a token embedding, the decoder over the model's ``pattern``, and an untied
-float32 output head; their arguments; how they are built from an argument
+a token embedding, the decoder over the model's ``pattern``, and a float32
+output head (untied, or the embedding itself where a decoder states
+``tie_word_embeddings`` and builds it: ``zaya``); their arguments; how they are built from an argument
 namespace; how their architectures are registered.
 
 A decoder is a subclass of :class:`HybridLM` that states what is its own
@@ -185,19 +186,40 @@ class HybridLM(BaseUnicoreModel):
             pattern=self.pattern, embed_dim=self.hidden_size,
             remat=self.remat, name="decoder", **self.layers(),
         )
-        self.lm_head = self.param(
-            "lm_head", _init, (self.hidden_size, self.head_columns),
-            jnp.float32,
-        )
+        if not self.tied:
+            self.lm_head = self.param(
+                "lm_head", _init, (self.hidden_size, self.head_columns),
+                jnp.float32,
+            )
 
     def __call__(self, src_tokens, train: bool = False,
                  features_only: bool = False, **kwargs):
         x, stats = self.decoder(self.embed_tokens(src_tokens))
         if features_only:
             return x, self.logged(stats, *src_tokens.shape)
+        kernel = (self.embed_tokens.embedding.T if self.tied
+                  else self.lm_head)
         with jax.named_scope("lm_head"):
-            return jnp.dot(x, self.lm_head.astype(x.dtype),
+            return jnp.dot(x, kernel.astype(x.dtype),
                            preferred_element_type=self.logits_dtype)
+
+    @property
+    def tied(self):
+        """Whether the head is the embedding (``tie_word_embeddings``, for
+        a decoder whose ``check()`` lets it through): the model then has no
+        ``lm_head`` and its logits are ``x E^T``."""
+        return bool(getattr(self, "tie_word_embeddings", False))
+
+    @nn.nowrap
+    def head_kernel(self, params):
+        """The head's ``(hidden, columns)`` kernel out of ``params``, for a
+        loss that runs the head itself (``lm_cross_entropy``'s chunks): the
+        ``lm_head`` leaf or, tied, the embedding transposed, whose gradient
+        is then the sum of the gather's and the head's."""
+        tree = params["params"]
+        if self.tied:
+            return tree["embed_tokens"]["embedding"].T
+        return tree["lm_head"]
 
     def band_heads(self):
         """``{"window": .., "full": ..}``, the query heads held on a layer

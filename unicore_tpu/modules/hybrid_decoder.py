@@ -6,7 +6,11 @@ rotary positions), ``F`` a gated feed-forward layer, ``S`` and ``G``
 grouped-KV attention with rotary positions under a band the kernels mask
 themselves (``S`` over a sliding window, ``G`` over the whole row; each
 with its own rotary table), ``R`` routed gated experts with a softmax
-router and no shared expert.  Every layer is
+router and no shared expert, ``C`` attention in a compressed,
+convolution-mixed latent (``modules/cca.py``), ``Z`` gated experts one a
+token under a router that is a network with a state carried from one ``Z``
+layer to the next and a skip expert (``modules/zaya_moe.py``).  Every layer
+is
 
     x = x + mixer(RMSNorm(x))
 
@@ -15,6 +19,22 @@ convolution's apart) and no dropout.  A transformer layer of the usual
 kind is two of these: ``AF``.  A kind is one row of :data:`TABLE`, and a
 model states the sizes of the kinds its pattern holds as one mapping,
 ``sizes={"A": dict(...), "F": dict(...)}``.
+
+Two things a kind or a model may state beside that.  A kind whose row has
+``side`` **carries a side stream**: its mixer is called ``mixer(h, side)``
+and returns ``(y, stats, side)``; the decoder starts the stream once
+(``Kind.side(x, sizes)``), hands it from layer to layer through the loop
+over the layers before the repeated unit and through the ``nn.scan`` carry
+of the unit, and every kind without ``side`` passes it on untouched.  A
+pattern none of whose kinds has one carries ``None``, an empty tree, and
+traces what it traced before the stream existed.  A model may state
+``scaled_merge``: the block then ends
+
+    x = s_x (x + b_x) + s_f (mixer(RMSNorm(x)) + b_f)
+
+with four learned per-channel vectors (:class:`ScaledMerge`).  A kind that
+returns stats beyond ``latent_moe.STATS`` names them (``more_stats``), and
+the decoder's stats are :func:`stat_names` of its pattern.
 
 A pattern whose tail repeats (``*EMEMEMEMEM`` = ``*`` + 5 x ``EM``) runs
 the repeated unit as ONE traced body under ``nn.scan``, its parameters
@@ -36,22 +56,24 @@ router product, ``top_k``, ``latent_down``, routed forward loop,
 and layout arrays, its routed sum and, with a shared expert, that expert's
 two products (``gated_moe.py``); ``M`` names ``in_proj``'s result
 (``mamba2.KEPT``: 38 MB a layer, no second ``in_proj``; convolution, scan
-and gated norm are made again from it); ``*``, ``A``, ``F``, ``S`` and
-``G`` name nothing and are made again whole.  What a name is worth is the
+and gated norm are made again from it); ``Z`` names what ``R`` names (its router's logits, the choice, the layout,
+the routed sum); ``*``, ``A``, ``F``, ``S``, ``G`` and ``C`` name nothing
+and are made again whole.  What a name is worth is the
 chip's to say: with ``in_proj``'s result kept the compiler lays the scanned
 backward loop out against the forward loop's and copies two saved arrays an
 iteration, its own cycle estimate ranks that form under ``shared_fc1``'s
 name alone, and the chip ranks it above (PERF.md, PR 43).
 """
 
-from typing import Callable, NamedTuple, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from unicore_tpu.ops import eva_attention, flash_attention
-from . import latent_moe, mamba2
+from . import latent_moe, mamba2, zaya_moe
+from .cca import CompressedConvAttention
 from .eva_attention import EvaAttention
 from .gated_mlp import GatedMLP
 from .gated_moe import GatedMoE
@@ -59,6 +81,7 @@ from .latent_moe import STATS, LatentMoE
 from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
 from .multihead_attention import GroupedQueryAttention
+from .zaya_moe import ZayaMoE
 
 
 class Kind(NamedTuple):
@@ -70,7 +93,11 @@ class Kind(NamedTuple):
     the stats a model logs of such layers say of them: ``logs`` to the
     training log (each a function of an update's logging outputs) and
     ``marks`` to a profiler capture (each from an update's summed logging
-    output to ``{mark: stats}``, empty where the sums hold none of its)."""
+    output to ``{mark: stats}``, empty where the sums hold none of its);
+    ``side``, for a kind that carries a side stream beside ``x``: the
+    stream that goes into the pattern's first layer, from ``x`` and the
+    kind's sizes (its mixer maps ``(h, side)`` to ``(y, stats, side)``);
+    ``more_stats``, the names of the stats it returns after ``STATS``."""
 
     module: type
     name: str
@@ -79,6 +106,8 @@ class Kind(NamedTuple):
     kept: Tuple[str, ...] = ()
     logs: Tuple[Callable, ...] = ()
     marks: Tuple[Callable, ...] = ()
+    side: Optional[Callable] = None
+    more_stats: Tuple[str, ...] = ()
 
 
 # E and R share one loop, S and G one band
@@ -98,6 +127,13 @@ TABLE = {
     "S": Kind(GroupedQueryAttention, "self_attn", **_BANDED),
     "G": Kind(GroupedQueryAttention, "self_attn", **_BANDED),
     "R": Kind(GatedMoE, "moe", **_EXPERTS),
+    "C": Kind(CompressedConvAttention, "self_attn",
+              marks=(flash_attention.band_mark,
+                     flash_attention.band_call_mark)),
+    "Z": Kind(ZayaMoE, "moe", stats=True, kept=latent_moe.KEPT,
+              logs=(latent_moe.route_scalars, zaya_moe.skip_scalars),
+              marks=(latent_moe.route_mark, zaya_moe.skip_mark),
+              side=zaya_moe.side_start, more_stats=zaya_moe.MORE_STATS),
 }
 
 
@@ -142,15 +178,69 @@ def split_pattern(pattern: str) -> Tuple[str, str, int]:
     return best
 
 
+def stat_names(pattern):
+    """The stats a decoder over ``pattern`` returns, summed over its
+    layers: ``latent_moe.STATS``, then what its kinds return after them
+    (``Kind.more_stats``; none of the kinds but ``Z``)."""
+    return STATS + tuple(dict.fromkeys(
+        n for kind in pattern if kind in TABLE
+        for n in TABLE[kind].more_stats))
+
+
+def side_start(pattern, x, sizes):
+    """The side stream that goes into the first layer of ``pattern``
+    beside ``x``: that of the first of its kinds that carries one
+    (``Kind.side``), or None where no kind does."""
+    for kind in dict.fromkeys(pattern):
+        if kind in TABLE and TABLE[kind].side is not None:
+            return TABLE[kind].side(x, sizes[kind])
+    return None
+
+
+class _ScaleBias(nn.Module):
+    """``scale * (t + bias)`` in float32, both learned vectors (ones and
+    zeros at the start)."""
+
+    dim: int
+
+    @nn.compact
+    def __call__(self, t):
+        scale = self.param("scale", nn.initializers.ones, (self.dim,),
+                           jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (self.dim,),
+                          jnp.float32)
+        return scale * (t.astype(jnp.float32) + bias)
+
+
+class ScaledMerge(nn.Module):
+    """``x <- s_x (x + b_x) + s_f (f + b_f)``: the residual merge with four
+    learned per-channel vectors (``x/scale``, ``x/bias``, ``f/scale``,
+    ``f/bias``; ones and zeros at the start, where it is ``x + f``), in
+    float32, rounded once to the stream's dtype."""
+
+    embed_dim: int
+
+    @nn.compact
+    def __call__(self, x, f):
+        with jax.named_scope("merge"):
+            y = (_ScaleBias(self.embed_dim, name="x")(x)
+                 + _ScaleBias(self.embed_dim, name="f")(f))
+            return y.astype(x.dtype)
+
+
 class HybridBlock(nn.Module):
     kind: str
     embed_dim: int
     norm_eps: float
     sizes: dict  # the pattern's kinds -> what each one's mixer is built with
     norm_unit_offset: bool = False
+    scaled_merge: bool = False
+    stats: Tuple[str, ...] = STATS  # :func:`stat_names` of the pattern
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, side=None):
+        """``(x, side) -> (x, stats, side)``: a kind that carries the side
+        stream reads and replaces it, every other hands it on."""
         row = TABLE.get(self.kind)
         if row is None:
             raise ValueError(
@@ -158,12 +248,22 @@ class HybridBlock(nn.Module):
             )
         h = RMSNorm(self.embed_dim, eps=self.norm_eps, name="norm",
                     unit_offset=self.norm_unit_offset)(x)
-        stats = jnp.zeros((len(STATS),), jnp.float32)
-        y = row.module(self.embed_dim, name=row.name, **row.always,
-                       **self.sizes[self.kind])(h)
+        stats = jnp.zeros((len(self.stats),), jnp.float32)
+        mixer = row.module(self.embed_dim, name=row.name, **row.always,
+                           **self.sizes[self.kind])
+        if row.side is not None:
+            y, own, side = mixer(h, side)
+        else:
+            y = mixer(h)
+            if row.stats:
+                y, own = y
         if row.stats:
-            y, stats = y
-        return x + y, stats
+            names = STATS + row.more_stats
+            stats = own if names == self.stats else stats.at[
+                jnp.asarray([self.stats.index(n) for n in names])].set(own)
+        if self.scaled_merge:
+            return ScaledMerge(self.embed_dim, name="merge")(x, y), stats, side
+        return x + y, stats, side
 
 
 class _Unit(nn.Module):
@@ -174,11 +274,12 @@ class _Unit(nn.Module):
 
     @nn.compact
     def __call__(self, carry, _):
-        x, stats = carry
+        x, stats, side = carry
         for j, kind in enumerate(self.pattern):
-            x, s = HybridBlock(kind=kind, name=f"layer_{j}", **self.block)(x)
+            x, s, side = HybridBlock(
+                kind=kind, name=f"layer_{j}", **self.block)(x, side)
             stats = stats + s
-        return (x, stats), None
+        return (x, stats, side), None
 
 
 class HybridDecoder(nn.Module):
@@ -190,25 +291,31 @@ class HybridDecoder(nn.Module):
     sizes: dict
     remat: bool = True
     norm_unit_offset: bool = False  # every norm's gain is 1 + its parameter
+    scaled_merge: bool = False      # the merge is :class:`ScaledMerge`
 
     @nn.compact
     def __call__(self, x):
         """``x`` (B, L, embed_dim) -> ``(x, stats)``: the final-normed
-        stream and the expert layers' routing stats summed over layers
-        (``latent_moe.STATS``; all zero where no layer returns any)."""
+        stream and the layers' stats summed over layers
+        (:func:`stat_names` of the pattern: ``latent_moe.STATS`` for every
+        pattern without ``Z``; all zero where no layer returns any)."""
+        names = stat_names(self.pattern)
         block = dict(embed_dim=self.embed_dim, norm_eps=self.norm_eps,
-                     sizes=self.sizes, norm_unit_offset=self.norm_unit_offset)
+                     sizes=self.sizes, norm_unit_offset=self.norm_unit_offset,
+                     scaled_merge=self.scaled_merge, stats=names)
         wrap = _remat if self.remat else (lambda cls: cls)
         head, unit, repeats = split_pattern(self.pattern)
-        stats = jnp.zeros((len(STATS),), jnp.float32)
+        stats = jnp.zeros((len(names),), jnp.float32)
+        side = side_start(self.pattern, x, self.sizes)
         for i, kind in enumerate(head):
-            x, s = wrap(HybridBlock)(kind=kind, name=f"layers_{i}", **block)(x)
+            x, s, side = wrap(HybridBlock)(
+                kind=kind, name=f"layers_{i}", **block)(x, side)
             stats = stats + s
         if repeats:
-            (x, stats), _ = nn.scan(
+            (x, stats, side), _ = nn.scan(
                 wrap(_Unit), variable_axes={"params": 0},
                 split_rngs={"params": True}, length=repeats,
-            )(pattern=unit, block=block, name="units")((x, stats), None)
+            )(pattern=unit, block=block, name="units")((x, stats, side), None)
         x = RMSNorm(self.embed_dim, eps=self.norm_eps, name="final_norm",
                     unit_offset=self.norm_unit_offset)(x)
         return x, stats

@@ -1150,6 +1150,24 @@ def band_mark(sums):
     }}
 
 
+def band_call_mark(sums):
+    """For a model whose banded layers ALL see the whole row (no
+    sliding-window layer, so one map for every call): one
+    ``unicore:attn_band_call`` mark whose ``keys_computed`` is what a
+    reader of the kernels' trace events takes it for, the pairs ONE mapped
+    call scores a head (the batch's rows, one layer), with ``keys_visible``
+    beside it.  Nothing where window layers were logged (:func:`band_mark`
+    says why its stats carry other names) or no row was."""
+    rows = sums.get("band_rows", 0)
+    if (not rows or sums.get("band_window_layers", 0)
+            or not sums.get("band_full_layers", 0)):
+        return {}
+    layers = sums["band_full_layers"] / rows
+    return {"attn_band_call": {
+        f"keys_{stat}": int(sums[f"band_full_keys_{stat}"] / layers)
+        for stat in ("computed", "visible")}}
+
+
 def mha_reference(q, k, v, bias=None, kv_padding_mask=None, sm_scale=1.0):
     """Pure-jnp reference for numerics tests."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
