@@ -62,6 +62,22 @@ def global_mesh_as_found():
     mesh_mod._global_mesh = was
 
 
+@pytest.fixture(autouse=True, scope="module")
+def global_mesh_as_the_module_found_it():
+    """The same for a test FILE: a module-scoped fixture that builds a
+    ``Trainer`` runs before any test's own fixtures, so the test-scoped
+    fixture above finds its mesh published already and hands it on to
+    every file the worker runs afterwards (``tests/benchmark/
+    test_trace_scopes.py`` then ``tests/test_tpu_compile.py``: a
+    ``shard_map`` over eight CPU devices inside a compile for one described
+    chip).  Autouse, so it is set up before the module's other fixtures."""
+    from unicore_tpu.parallel import mesh as mesh_mod
+
+    was = mesh_mod._global_mesh
+    yield
+    mesh_mod._global_mesh = was
+
+
 # ---------------------------------------------------------------------------
 # `-m fast` smoke subset: finishes in ~1 minute on one CPU core, touching
 # data pipeline, logging, optim/schedulers, checkpointing, kernels (jnp

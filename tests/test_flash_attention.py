@@ -248,6 +248,7 @@ def _seen_mask(case, G, Lq, Lk):
 MAPPED_CASES = {
     # name: (B, H, Lq, Lk, D, G, (block_q, block_k))
     "causal-256x512": (2, 2, 1024, 1024, 32, 1, (256, 512)),
+    "causal-256x512-d128": (1, 2, 1024, 1024, 128, 1, (256, 512)),
     "causal-128x128": (1, 2, 1024, 1024, 32, 1, (128, 128)),
     "grouped": (4, 1, 512, 512, 32, 2, (128, 128)),
     "unseen-key-block": (2, 1, 512, 512, 32, 1, (128, 128)),
@@ -471,3 +472,111 @@ def test_band_takes_bfloat16_a_padding_mask_and_no_second_map():
         fa.flash_attention(q, k, v, band=fa.Band(), block_q=128, block_k=128,
                            block_map=fa.block_map(np.ones((1, 2, 2), bool)))
     assert fa.Band().width(512) == 512 and fa.Band(64).width(512) == 64
+
+
+# -- the forward's running statistics at the decoders' shape -------------------
+
+#: name -> (L, what the call is handed): D = 128 at the default blocks of
+#: (256, 512), two to four key blocks a row, so a row's maximum is rescaled
+#: and its partial sums (one a lane, summed when the row ends) carry over
+#: from block to block.  ``window-300``: rows 812 .. 1023 see no key of the
+#: first block their query block visits.
+WIDE_CASES = {
+    "causal": (2048, "band", None),
+    "window-1024": (2048, "band", 1024),
+    "window-512": (2048, "band", 512),
+    "window-300": (1024, "band", 300),
+    "biased-mapped": (1024, "map", None),
+    "biased-unmapped": (1024, "bias", None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_forward_statistics_at_the_decoders_shape(case, dtype):
+    """Output, and dq / dk / dv through the saved ``lse``, against
+    ``mha_reference`` on a pre-scaled ``q`` (``sm_scale`` 1, as the decoders
+    call) and, for the biased calls, on EVA's ``sm_scale != 1``."""
+    L, kind, window = WIDE_CASES[case]
+    B, H, D = 1, 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(47), 4)
+    q, k, v = (jax.random.normal(key, (B, H, L, D), dtype) for key in keys[:3])
+    w = jnp.cos(jnp.arange(B * H * L * D, dtype=jnp.float32)).reshape(q.shape)
+    seen = _band_seen(L, window)
+    mask_bias = jnp.where(seen, 0.0, fa.NEG_INF)[None, None]
+    if kind == "band":
+        q, sm_scale = (q * D ** -0.5).astype(dtype), 1.0
+        kwargs, ref_bias = {"band": fa.Band(window)}, mask_bias
+        if case == "window-300":
+            first = seen.reshape(L // 256, 256, L // 512, 512).any(-1)
+            # a row whose query block visits a block the row sees nothing of
+            assert (first.any(1, keepdims=True) & ~first)[:, :, 0].any()
+    else:
+        sm_scale = D ** -0.5
+        learned = jax.random.normal(keys[3], (1, H, L, L), jnp.float32)
+        ref_bias = learned + (mask_bias if kind == "map" else 0.0)
+        kwargs = {"bias": ref_bias}
+        if kind == "map":
+            kwargs["block_map"] = fa.block_map(
+                seen.reshape(L // 256, 256, L // 512, 512).any((1, 3))[None])
+
+    def loss(fn):
+        def f(q, k, v):
+            out = fn(q, k, v).astype(jnp.float32)
+            return jnp.sum(out * w), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    flash = lambda q, k, v: fa.flash_attention(
+        q, k, v, sm_scale=sm_scale, **kwargs)
+    ref = lambda q, k, v: fa.mha_reference(
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        bias=ref_bias, sm_scale=sm_scale)
+    (_, out), grads = jax.jit(loss(flash))(q, k, v)
+    (_, want), want_grads = loss(ref)(q, k, v)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.abs(out - want).max()) < tol
+    for name, a, b in zip(("dq", "dk", "dv"), grads, want_grads):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        err = float(jnp.abs(a - b).max()) / max(1.0, float(jnp.abs(b).max()))
+        assert err < tol, f"{name}: {err}"
+
+
+def test_a_row_of_padding_writes_zeros_at_the_decoders_shape():
+    """Every key of batch row 1 is padding: its output is exact zeros and
+    its gradients are finite, at two key blocks a row; batch row 0 (no
+    padding) matches the reference beside it."""
+    B, H, L, D = 2, 1, 1024, 128
+    q, k, v, _, _ = make_inputs(B, H, L, D, jnp.float32)
+    pad = jnp.asarray(np.array([[0], [1]]) * np.ones((1, L)), jnp.int32)
+
+    def f(q, k, v):
+        out = fa.flash_attention(q, k, v, kv_padding_mask=pad,
+                                 sm_scale=D ** -0.5)
+        return jnp.sum(out ** 2), out
+
+    (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v)
+    assert not np.asarray(out[1]).any()
+    want = fa.mha_reference(q[:1], k[:1], v[:1], sm_scale=D ** -0.5)
+    assert float(jnp.abs(out[:1] - want).max()) < 2e-5
+    for g in grads:
+        assert bool(jnp.all(jnp.isfinite(g)))
+        assert not np.asarray(g[1]).any() and np.asarray(g[0]).any()
+
+
+@pytest.mark.parametrize("L,D", [(192, 64), (64, 32), (256, 256), (128, 192)])
+def test_forward_statistics_at_any_width(L, D):
+    """The statistics are as wide as a lane tile, or as a key block that is
+    no multiple of one (a short row taken whole: 192, 64); the accumulator's
+    rescale reads them at the head's width, narrower (64, 32), a multiple
+    (256) or neither (192)."""
+    assert fa._stat_lanes(L) == (128 if L % 128 == 0 else L)
+    q, k, v, bias, mask = make_inputs(
+        2, 2, L, D, jnp.float32, bias_shape=(1, 2, L, L), with_mask=True)
+    out = fa.flash_attention(q, k, v, bias=bias, kv_padding_mask=mask,
+                             sm_scale=D ** -0.5)
+    ref = fa.mha_reference(q, k, v, bias=bias, kv_padding_mask=mask,
+                           sm_scale=D ** -0.5)
+    assert float(jnp.abs(out - ref).max()) < 5e-3

@@ -45,7 +45,15 @@ Capabilities (superset of the reference kernel's semantics):
   positions.  No ``(Lq, Lk)`` array exists, forward or backward
 
 Softmax statistics are fp32 regardless of input dtype; the p @ v matmul runs
-in the input dtype on the MXU with fp32 accumulation.
+in the input dtype on the MXU with fp32 accumulation.  The forward kernel
+keeps a query block's running statistics WHOLE LANES WIDE: the maximum as
+the ``(BQ, 128)`` array a reduction along lanes leaves, every lane of a row
+alike, and the sum as 128 partial sums a row (lane ``j`` over the keys ``j,
+j + 128, ...``), which plain vector adds keep up and one cross-lane
+reduction a ROW, when it ends, turns into ``l``.  A ``(BQ, 1)`` column cut
+out of such an array takes a lane permute for every use of it, on the unit
+of the chip that a visit waits on (docs/performance.md, "The block map":
+what a visit costs in each kernel; ``scripts/flash_visit.py`` measures it).
 """
 
 import functools
@@ -344,6 +352,25 @@ def _step(walk, t, pre, body, band=None, iq=None, ik=None, shape=None):
 # forward
 # ---------------------------------------------------------------------------
 
+def _stat_lanes(block_k):
+    """How many lanes wide the forward kernel keeps a query block's running
+    statistics: one lane tile, or the whole key block where that is no
+    multiple of the tile (a short row taken whole)."""
+    return 128 if block_k % 128 == 0 else block_k
+
+
+def _row_lanes(x, n):
+    """``x`` ``(rows, W)`` whose lanes all hold their row's one value, as
+    ``(rows, n)``: slices and copies of whole lanes, no broadcast from a
+    column."""
+    w = x.shape[1]
+    if n <= w:
+        return x if n == w else x[:, :n]
+    if n % w == 0:
+        return jnp.concatenate([x] * (n // w), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(
     pre,
     q_ref, k_ref, v_ref, bias_ref, mask_ref,
@@ -353,6 +380,10 @@ def _fwd_kernel(
 ):
     b, h, iq, t = (pl.program_id(i) for i in range(4))
     b, iq, ik = walk.at(b, iq, t, pre)
+    # the statistics stay whole lanes wide (module docstring): ``m_s`` a
+    # row's maximum in every one of its W lanes, ``l_s`` W partial sums a
+    # row, lane ``j`` over the keys ``j, j + W, ...`` of the blocks so far
+    W = m_s.shape[1]
 
     @pl.when(walk.first(t, pre))
     def _init():
@@ -368,7 +399,8 @@ def _fwd_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        s = s * sm_scale
+        if sm_scale != 1.0:  # the decoders hand over a pre-scaled q
+            s = s * sm_scale
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         if visible is not None:
@@ -380,15 +412,17 @@ def _fwd_kernel(
             kv_mask = mask_ref[0] != 0  # (1, BK) True = masked out
             s = jnp.where(kv_mask, NEG_INF, s)
 
-        m_prev = m_s[:, :1]  # (BQ, 1)
-        l_prev = l_s[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_next)
+        m_prev = m_s[...]  # (BQ, W)
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _row_lanes(m_next, s.shape[1]))
         if has_mask:
             p = jnp.where(kv_mask, 0.0, p)  # exact zero for fully-masked rows
         corr = jnp.exp(m_prev - m_next)
-        l_next = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        part = p[:, :W]
+        for j in range(1, p.shape[1] // W):
+            part = part + p[:, j * W:(j + 1) * W]
+        l_s[...] = corr * l_s[...] + part
+        m_s[...] = m_next
 
         if dropout_rate > 0.0:
             _seed_block(pre[0], b, h, iq, ik)
@@ -401,9 +435,7 @@ def _fwd_kernel(
             p_use.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_s[...] = acc_s[...] * corr + pv
-        m_s[...] = jnp.broadcast_to(m_next, m_s.shape)
-        l_s[...] = jnp.broadcast_to(l_next, l_s.shape)
+        acc_s[...] = acc_s[...] * _row_lanes(corr, acc_s.shape[1]) + pv
 
     _step(walk, t, pre, _visit, band, iq, ik,
           (q_ref.shape[2], k_ref.shape[2]))
@@ -412,10 +444,10 @@ def _fwd_kernel(
     # are all padding does
     @pl.when(walk.last(t, pre))
     def _finish():
-        l = l_s[:, :1]
+        l = jnp.sum(l_s[...], axis=-1, keepdims=True)  # (BQ, 1)
         inv_l = jnp.where(l > 0.0, 1.0 / l, 0.0)
         o_ref[0, 0] = (acc_s[...] * inv_l).astype(o_ref.dtype)
-        lse = m_s[:, :1] + jnp.log(jnp.maximum(l_s[:, :1], 1e-37))
+        lse = m_s[:, :1] + jnp.log(jnp.maximum(l, 1e-37))
         lse_ref[0, 0] = lse.astype(jnp.float32)  # (BQ, 1)
 
 
@@ -444,11 +476,10 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
         io_blocks.append(((1, 1, BQ, BK), bias.dtype))
     if has_mask:
         io_blocks.append(((1, 1, BK), kv_mask.dtype))
-    check_vmem_budget(
-        "flash_attention fwd", io_blocks,
-        [((BQ, 128), jnp.float32), ((BQ, 128), jnp.float32),
-         ((BQ, D), jnp.float32)],
-    )
+    # the running maximum, the partial sums and the accumulator
+    scratch = [((BQ, _stat_lanes(BK)), jnp.float32)] * 2 + [
+        ((BQ, D), jnp.float32)]
+    check_vmem_budget("flash_attention fwd", io_blocks, scratch)
 
     qi, ki, maski, biasi = _index_maps(B, walk.at, kv_major=False)
     in_specs = [
@@ -501,11 +532,7 @@ def _fwd(q, k, v, bias, kv_mask, seed, sm_scale, dropout_rate, block_q,
                 pl.BlockSpec((1, 1, BQ, D), qi),
                 pl.BlockSpec((1, 1, BQ, 1), qi),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((BQ, 128), jnp.float32),
-                pltpu.VMEM((BQ, 128), jnp.float32),
-                pltpu.VMEM((BQ, D), jnp.float32),
-            ],
+            scratch_shapes=[pltpu.VMEM(*block) for block in scratch],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
