@@ -9,7 +9,11 @@ A model that states ``loss_chunk`` (models/hybrid_lm.py) is asked for its
 final hidden states instead of its logits, and the output head and the
 loss run over ``loss_chunk`` tokens at a time (:func:`chunked_lm_nll`): at
 8,192 x 16,384 the float32 logits alone would be 512 MB, and as much again
-for their gradient.  What such a model returns beside the hidden states
+for their gradient.  No chunk's logits are kept for the backward pass and
+none are made twice: the loss is a sum, so a chunk's ``softmax - onehot``
+is formed while its logits are alive and multiplied into the hidden
+states' gradient and the head's there, and the backward pass scales those
+two by the loss's cotangent.  What such a model returns beside the hidden states
 (an expert layer's routing stats, an attention layer's key counts) goes
 into the logging output.
 
@@ -21,6 +25,8 @@ count, and every (position, head) pair that counts weighs the same: the
 loss is their sum and the sample size their number.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -30,15 +36,10 @@ from . import register_loss
 from .unicore_loss import UnicoreLoss
 
 
-def chunked_lm_nll(x, kernel, target, valid, chunk):
-    """Summed next-token negative log-likelihood with the logits of only
-    ``chunk`` tokens alive at a time.  ``x`` (T, d) hidden states, ``kernel``
-    (d, V), ``target`` (T,) and ``valid`` (T,) already shifted; or
-    ``kernel`` (d, M * V) with ``target`` and ``valid`` (T, M), column
-    ``m`` the head's ``m``-th block of ``V`` logits' own target.  Each
-    chunk's logits are float32 (the product's accumulator, not a rounded
-    copy) and are computed again in the backward pass.  The kernel's
-    cotangent is summed over the chunks in float32."""
+def _chunks(x, target, valid, chunk):
+    """``x`` (T, d), ``target`` and ``valid`` (T[, M]) as ``T / chunk``
+    chunks of ``chunk`` tokens, a tail padded with tokens that do not
+    count."""
     T, d = x.shape
     pad = (-T) % chunk
     if pad:
@@ -46,27 +47,106 @@ def chunked_lm_nll(x, kernel, target, valid, chunk):
         x = jnp.pad(x, ((0, pad), (0, 0)))
         target = jnp.pad(target, rows)
         valid = jnp.pad(valid, rows)
-    kernel32 = kernel.astype(jnp.float32)
-
-    @jax.checkpoint
-    def one(args):
-        xc, tc, vc = args
-        with jax.named_scope("lm_head"):
-            logits = jnp.dot(xc, kernel32.astype(xc.dtype),
-                             preferred_element_type=jnp.float32)
-        with jax.named_scope("loss"):
-            logits = logits.reshape(tc.shape + (-1,))
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(
-                logits, tc[..., None], axis=-1
-            )[..., 0]
-            return jnp.sum(jnp.where(vc, lse - picked, 0.0))
-
     n = (T + pad) // chunk
-    return jnp.sum(jax.lax.map(one, (
+    return (
         x.reshape(n, chunk, d), target.reshape((n, chunk) + target.shape[1:]),
         valid.reshape((n, chunk) + valid.shape[1:]),
-    )))
+    )
+
+
+def _chunk_nll(kernel, xc, tc, vc):
+    """One chunk's summed loss, its float32 logits ``(chunk[, M], V)`` (the
+    product's accumulator, not a rounded copy) and their log-sum-exp."""
+    with jax.named_scope("lm_head"):
+        logits = jnp.dot(xc, kernel, preferred_element_type=jnp.float32)
+    with jax.named_scope("loss"):
+        logits = logits.reshape(tc.shape + (-1,))
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, tc[..., None], axis=-1
+        )[..., 0]
+        return jnp.sum(jnp.where(vc, lse - picked, 0.0)), logits, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def chunked_lm_nll(x, kernel, target, valid, chunk):
+    """Summed next-token negative log-likelihood with the logits of only
+    ``chunk`` tokens alive at a time.  ``x`` (T, d) hidden states, ``kernel``
+    (d, V), ``target`` (T,) and ``valid`` (T,) already shifted; or
+    ``kernel`` (d, M * V) with ``target`` and ``valid`` (T, M), column
+    ``m`` the head's ``m``-th block of ``V`` logits' own target.  Each
+    chunk's logits are float32 and are made once, differentiated or not.
+
+    Differentiated, the one walk over the chunks forms each chunk's
+    ``softmax - onehot`` beside its logits and multiplies it into ``x``'s
+    gradient and the kernel's there (three vocabulary-sized products a
+    chunk, the kernel's gradient summed over the chunks in float32); what
+    is kept for the backward pass is those two gradients at a cotangent of
+    1, and the backward pass multiplies them by the loss's cotangent.  The
+    loss is a sum, so that cotangent is one scalar; it scales the products'
+    float32 results, where ``jax.grad`` of the plain form scales
+    ``softmax - onehot`` before the products round it: at a cotangent of 1
+    nothing differs, at another the two agree to a rounding of ``x``'s
+    dtype.  ``x``'s gradient is kept in ``x``'s dtype, or in float32 for a
+    float16 ``x``, whose small gradients only the scale still to come lifts
+    into float16's range.  There is no forward-mode rule."""
+    kernel = kernel.astype(x.dtype)
+
+    def one(loss, args):
+        return loss + _chunk_nll(kernel, *args)[0], None
+
+    return jax.lax.scan(
+        one, jnp.zeros((), jnp.float32), _chunks(x, target, valid, chunk)
+    )[0]
+
+
+def _chunked_lm_nll_fwd(x, kernel, target, valid, chunk):
+    held = kernel.astype(x.dtype)
+    keep = jnp.float32 if x.dtype == jnp.float16 else x.dtype
+
+    def one(carry, args):
+        loss, dkernel = carry
+        xc, tc, vc = args
+        nll, logits, lse = _chunk_nll(held, xc, tc, vc)
+        with jax.named_scope("loss"):
+            softmax = jnp.exp(logits - lse[..., None])
+            onehot = jax.nn.one_hot(tc, logits.shape[-1], dtype=logits.dtype)
+            dlogits = jnp.where(
+                vc[..., None], softmax - onehot, 0.0
+            ).reshape(xc.shape[0], -1)
+        with jax.named_scope("lm_head"):
+            # float32 ``dlogits`` against operands of ``x``'s dtype at the
+            # default precision: what the transposes of the logits'
+            # product are handed
+            dxc = jax.lax.dot_general(
+                dlogits, held, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dkernel += jax.lax.dot_general(
+                xc, dlogits, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        return (loss + nll, dkernel), dxc.astype(keep)
+
+    (loss, dkernel), dx = jax.lax.scan(
+        one,
+        (jnp.zeros((), jnp.float32), jnp.zeros(kernel.shape, jnp.float32)),
+        _chunks(x, target, valid, chunk),
+    )
+    dx = dx.reshape(-1, x.shape[1])[:x.shape[0]]
+    # zero rows of each input: their dtypes, which the cotangents take
+    return loss, (dx, dkernel, x[:0], kernel[:0])
+
+
+def _chunked_lm_nll_bwd(chunk, kept, g):
+    dx, dkernel, x, kernel = kept
+    return (
+        (dx * g).astype(x.dtype), (dkernel * g).astype(kernel.dtype),
+        None, None,
+    )
+
+
+chunked_lm_nll.defvjp(_chunked_lm_nll_fwd, _chunked_lm_nll_bwd)
 
 
 def shifted_targets(target, heads, pad_idx):
