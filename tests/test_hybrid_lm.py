@@ -1158,10 +1158,12 @@ _KIND_SIZES = {
     "R": dict(expert_dim=24, n_routed=8, top_k=2),
     "C": dict(num_heads=4, num_kv_heads=2, head_dim=8, rope=dict(rope_theta=1e4)),
     "Z": dict(expert_dim=24, n_routed=8, router_dim=12),
+    "L": dict(num_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+              qk_rope_head_dim=8, v_head_dim=8, rope=dict(rope_theta=1e4)),
 }
 
 
-@pytest.mark.parametrize("kind", list("M*EAFSGRCZ") + ["X"])
+@pytest.mark.parametrize("kind", list("M*EAFSGRCZL") + ["X"])
 def test_every_kind_of_the_table_builds_alone(kind):
     """One layer of each kind under ``HybridDecoder``, with that kind's
     sizes and no other's: the mixer under the name its row states, stats
@@ -1169,13 +1171,13 @@ def test_every_kind_of_the_table_builds_alone(kind):
     from unicore_tpu.modules import hybrid_decoder
     from unicore_tpu.modules.hybrid_decoder import KINDS, TABLE, HybridDecoder
 
-    assert KINDS == "M*EAFSGRCZ" == "".join(_KIND_SIZES)
+    assert KINDS == "M*EAFSGRCZL" == "".join(_KIND_SIZES)
     x = jax.random.normal(jax.random.key(0), (2, 24, 32))
     decoder = HybridDecoder(pattern=kind, embed_dim=32, norm_eps=1e-5,
                             sizes={kind: _KIND_SIZES.get(kind, {})})
     if kind not in TABLE:
         with pytest.raises(ValueError, match=re.escape(
-                "layer kind 'X' is not one of 'M*EAFSGRCZ'")):
+                "layer kind 'X' is not one of 'M*EAFSGRCZL'")):
             decoder.init(jax.random.key(1), x)
         return
     row = TABLE[kind]
@@ -1200,10 +1202,11 @@ def test_the_loss_names_no_stat_and_passes_over_one_no_owner_knows():
 
     with open(lm_cross_entropy.__file__) as f:
         source = f.read()
-    for prefix in ("moe_", "eva_", "band_"):
+    for prefix in ("moe_", "eva_", "band_", "mla_", "mtp_"):
         assert prefix not in source, prefix
     assert [f.__name__ for f in hybrid_decoder.MARKS] == [
-        "route_mark", "keys_mark", "band_mark", "band_call_mark", "skip_mark"]
+        "route_mark", "keys_mark", "band_mark", "band_call_mark", "skip_mark",
+        "mla_mark"]
     sums = {"loss": 9.0, "_n": 1.0, "moe_layers": 2.0, "moe_pairs_here": 600.0,
             "moe_load_max": 400.0, "moe_load_mean": 150.0, "moe_tiles_used": 9.0,
             "moe_rows_wide": 0.0, "eva_rows": 2.0, "eva_keys_computed": 4096.0,

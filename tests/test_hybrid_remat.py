@@ -39,6 +39,9 @@ SIZES = dict(embed_dim=D, norm_eps=1e-5, sizes={
               rope=dict(rope_theta=1e4)),
     "R": dict(expert_dim=20, n_routed=10, top_k=3, n_held=5,
               first_held=5, routed_scale=2.5, shared_dim=44),
+    "L": dict(num_heads=4, q_lora_rank=24, kv_lora_rank=16,
+              qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+              rope=dict(rope_theta=1e4)),
 })
 #: the right-hand shapes of an ``E`` layer's forward products over its
 #: tokens, and of the Mamba layer's ``in_proj`` (2 x 32 + 2 x 40 + 4 wide)
@@ -223,10 +226,11 @@ def names_in(jaxpr):
 def test_no_name_is_dead_on_either_side():
     """Every name the decoder's policy lists is given to an array by some
     layer kind, and every array a layer names is on the policy's list."""
-    # ``R`` with its shared expert beside ``E``: the gated form's names too
-    params, x = inputs("*EMAFR", jnp.float32)
+    # ``R`` with its shared expert beside ``E``: the gated form's names too;
+    # ``L``: its two normed latents and its rotary key
+    params, x = inputs("*EMAFRL", jnp.float32)
     produced = names_in(
-        jax.make_jaxpr(decoder("*EMAFR").apply)(params, x).jaxpr)
+        jax.make_jaxpr(decoder("*EMAFRL").apply)(params, x).jaxpr)
     assert produced == set(hybrid_decoder.KEPT)
     assert len(set(hybrid_decoder.KEPT)) == len(hybrid_decoder.KEPT)
 

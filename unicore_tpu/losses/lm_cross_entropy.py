@@ -23,6 +23,19 @@ the vocabulary, block ``m`` at position ``t`` is scored against token
 ``t + m`` (m = 1 .. M), a target past the row's end (or a pad) does not
 count, and every (position, head) pair that counts weighs the same: the
 loss is their sum and the sample size their number.
+
+A model that trains a prediction module (``modules/mtp.py``) returns more
+than one stream of hidden states and states ``ahead = ((name, weight),)``:
+the stream after the decoder's is scored by the SAME head against the token
+one further ahead (``t + 2``; the last two positions of a row have none),
+under the scope ``name``, and
+
+    loss = nll_main + weight (n_main / n_name) nll_name,  sample size n_main
+
+so that the module's MEAN loss is added at ``weight``.  The head's kernel
+receives both passes' gradients.  The logging output then holds
+``nll_loss`` (the decoder's own sum) and ``<name>_loss`` (the stream's,
+scaled to the main pass's sample size) beside ``loss``.
 """
 
 import functools
@@ -31,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from unicore_tpu.logging import metrics
+from unicore_tpu.modules import mtp
 from unicore_tpu.modules.hybrid_decoder import LOGS, MARKS
 from . import register_loss
 from .unicore_loss import UnicoreLoss
@@ -202,21 +216,39 @@ class LMCrossEntropyLoss(UnicoreLoss):
             params, **sample["net_input"], train=train, rngs=rngs,
             features_only=True,
         )
+        further = ()
+        if isinstance(x, tuple):  # the decoder's stream, then those ahead
+            x, further = x[0], x[1:]
         B, L, d = x.shape
+
+        def nll_of(x, target):
+            """``x`` against ``target``, position for position: the summed
+            NLL and the targets that count."""
+            target = target.reshape((B * L,) + target.shape[2:])
+            valid = target != self.padding_idx
+            return chunked_lm_nll(
+                x.reshape(B * L, d), model.head_kernel(params),
+                jnp.where(valid, target, 0), valid,
+                int(model.loss_chunk) or B * L,
+            ), jnp.sum(valid).astype(jnp.float32)
+
         # every position predicts its successor(s); the last has none
         target = shifted_targets(sample["target"], heads, self.padding_idx)
-        target = target.reshape((B * L,) + target.shape[2:])
-        valid = target != self.padding_idx
-        loss = chunked_lm_nll(
-            x.reshape(B * L, d), model.head_kernel(params),
-            jnp.where(valid, target, 0), valid,
-            int(model.loss_chunk) or B * L,
-        )
-        sample_size = jnp.sum(valid).astype(jnp.float32)
+        loss, sample_size = nll_of(x, target)
+        parts = {"nll_loss": loss} if further else {}
+        for z, (name, weight) in zip(further, model.ahead):
+            # each further stream predicts one token further ahead
+            target = shifted_targets(target, 1, self.padding_idx)
+            with jax.named_scope(name):
+                nll, size = nll_of(z, target)
+            # the stream's mean NLL, over the main pass's sample size
+            parts[f"{name}_loss"] = nll * (sample_size / jnp.maximum(size, 1.0))
+            loss = loss + weight * parts[f"{name}_loss"]
         logging_output = {
             "loss": loss,
             "sample_size": sample_size,
             "bsz": jnp.asarray(B, dtype=jnp.float32),
+            **parts,
             **extra,
         }
         return loss, sample_size, logging_output
@@ -228,7 +260,7 @@ class LMCrossEntropyLoss(UnicoreLoss):
         metrics.log_scalar(
             "loss", loss_sum / sample_size / jnp.log(2), sample_size, round=3
         )
-        for log_stats in LOGS:
+        for log_stats in LOGS + mtp.LOGS:
             log_stats(logging_outputs)
 
     @staticmethod
@@ -241,7 +273,7 @@ class LMCrossEntropyLoss(UnicoreLoss):
         layer kinds' table lists them (``modules/hybrid_decoder.MARKS``),
         and a stat none of them knows is in no mark."""
         marks = {}
-        for mark_of in MARKS:
+        for mark_of in MARKS + mtp.MARKS:
             marks.update(mark_of(sums))
         return marks
 

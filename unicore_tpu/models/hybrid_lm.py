@@ -1,9 +1,12 @@
 """What the decoders over :class:`~unicore_tpu.modules.hybrid_decoder.
-HybridDecoder` share (``nemotron_h``, ``evabyte``, ``mellum``, ``laguna``):
-a token embedding, the decoder over the model's ``pattern``, and a float32
-output head (untied, or the embedding itself where a decoder states
-``tie_word_embeddings`` and builds it: ``zaya``); their arguments; how they are built from an argument
-namespace; how their architectures are registered.
+HybridDecoder` share (``nemotron_h``, ``evabyte``, ``mellum``, ``laguna``,
+``zaya``, ``joyai``): a token embedding, the decoder over the model's
+``pattern``, and a float32 output head (untied, or the embedding itself
+where a decoder states ``tie_word_embeddings`` and builds it: ``zaya``),
+and, where a decoder states ``mtp_pattern``, a prediction module beside the
+decoder that reads the same embedding and is scored by the same head
+(``modules/mtp.py``: ``joyai``); their arguments; how they are built from an
+argument namespace; how their architectures are registered.
 
 A decoder is a subclass of :class:`HybridLM` that states what is its own
 (docs/hybrid_lm.md, "Adding a decoder"):
@@ -22,8 +25,9 @@ A decoder is a subclass of :class:`HybridLM` that states what is its own
 - ``logged()``: what it logs of an update beside the loss.
 
 The loss does not need all logits at once: ``features_only=True`` returns
-the final hidden states and what the model logs, and ``lm_cross_entropy``
-runs head and loss over ``--loss-chunk`` tokens at a time.
+the final hidden states (with a prediction module a tuple: the decoder's,
+then the module's) and what the model logs, and ``lm_cross_entropy`` runs
+head and loss over ``--loss-chunk`` tokens at a time.
 """
 
 import json
@@ -39,7 +43,8 @@ from unicore_tpu.models.unicore_model import (
     strip_diagnostic_collections,
 )
 from unicore_tpu.modules.gated_moe import BALANCINGS
-from unicore_tpu.modules.hybrid_decoder import HybridDecoder
+from unicore_tpu.modules.hybrid_decoder import HybridDecoder, stat_names
+from unicore_tpu.modules.mtp import MultiTokenPrediction
 from unicore_tpu.ops.flash_attention import band_log
 
 _init = nn.initializers.normal(0.02)
@@ -118,6 +123,13 @@ class HybridLM(BaseUnicoreModel):
     #: ``preferred_element_type`` of the full-logits product (None: the
     #: stream's dtype)
     logits_dtype = None
+    #: the layer kinds of a prediction module's block (``modules/mtp.py``),
+    #: for a decoder that trains one (``""``: none), and what the loss is
+    #: told of the stream it returns: ``((name, weight),)``, the stream
+    #: after the decoder's scored against the token one further ahead, its
+    #: mean NLL added to the loss times ``weight``
+    mtp_pattern = ""
+    ahead = ()
 
     @classmethod
     def arguments(cls):
@@ -186,6 +198,15 @@ class HybridLM(BaseUnicoreModel):
             pattern=self.pattern, embed_dim=self.hidden_size,
             remat=self.remat, name="decoder", **self.layers(),
         )
+        if self.mtp_pattern:
+            if stat_names(self.mtp_pattern) != stat_names(self.pattern):
+                raise ValueError(
+                    f"a prediction block of kinds {self.mtp_pattern!r} "
+                    f"returns other stats than the pattern {self.pattern!r}")
+            self.mtp = MultiTokenPrediction(
+                pattern=self.mtp_pattern, embed_dim=self.hidden_size,
+                remat=self.remat, name="mtp", **self.layers(),
+            )
         if not self.tied:
             self.lm_head = self.param(
                 "lm_head", _init, (self.hidden_size, self.head_columns),
@@ -194,9 +215,20 @@ class HybridLM(BaseUnicoreModel):
 
     def __call__(self, src_tokens, train: bool = False,
                  features_only: bool = False, **kwargs):
-        x, stats = self.decoder(self.embed_tokens(src_tokens))
+        emb = self.embed_tokens(src_tokens)
+        x, stats = self.decoder(emb)
+        streams = x
+        # the full logits are the decoder's alone: the module runs for the
+        # loss (and once to make its parameters)
+        if self.mtp_pattern and (features_only or self.is_initializing()):
+            # Emb(t_{i+1}): the row's embeddings one position early (the
+            # last position has no next token, and no target either)
+            early = jnp.concatenate(
+                [emb[:, 1:], jnp.zeros_like(emb[:, :1])], axis=1)
+            z, more = self.mtp(x, early)
+            streams, stats = (x, z), stats + more
         if features_only:
-            return x, self.logged(stats, *src_tokens.shape)
+            return streams, self.logged(stats, *src_tokens.shape)
         kernel = (self.embed_tokens.embedding.T if self.tied
                   else self.lm_head)
         with jax.named_scope("lm_head"):

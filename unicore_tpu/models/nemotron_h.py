@@ -14,6 +14,12 @@ says how a share maps to one): ``--pattern-held`` (the layers),
 
 Embedding, head, building and the memory arguments are ``models/
 hybrid_lm.py``'s; the model logs its expert layers' routing stats.
+
+The published model's multi-token-prediction module
+(``num_nextn_predict_layers``, ``mtp_hybrid_override_pattern``) is left out:
+neither key is a field here.  ``modules/mtp.py`` is such a module (``joyai``
+trains one), and the skeleton builds it for a decoder that states
+``mtp_pattern`` and ``ahead``.
 """
 
 import flax.linen as nn

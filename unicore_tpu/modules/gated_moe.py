@@ -1,13 +1,28 @@
 """Routed gated experts, as a layer that is TOLD which experts it holds:
-a softmax router over all ``n_routed`` experts, ``top_k`` a token with the
-chosen scores renormalised, each expert a gated three-matrix feed-forward
-layer at the model's width; no latent; a shared expert where
-``shared_dim`` states one.
+a router over all ``n_routed`` experts, ``top_k`` a token with the chosen
+scores renormalised, each expert a gated three-matrix feed-forward layer at
+the model's width; no latent; a shared expert where ``shared_dim`` states
+one.
 
     p = softmax(h W_r)                      float32, over ALL n_routed
     chosen = the top_k largest of p;   w_e = routed_scale p_e / sum_{chosen} p
     y = sum_{e chosen and held} w_e W_down,e (silu(W_gate,e h) * (W_up,e h))
         + W_down,s (silu(W_gate,s h) * (W_up,s h))       where shared_dim > 0
+
+``scoring="sigmoid"`` is DeepSeek-V3's router (arXiv:2412.19437 section
+2.1.2, ``scoring_func: sigmoid`` with ``topk_method: noaux_tc``): each
+expert's score is its own sigmoid, and a bias an expert is added to the
+scores that CHOOSE and to nothing else,
+
+    s = sigmoid(h W_r);   chosen = the top_k largest of s + b
+    w_e = routed_scale s_e / (sum_{chosen} s + 1e-20)
+
+``b`` is the leaf ``router_bias`` (n_routed,), which no gradient reaches:
+the published recipe's trainer moves it between updates by the experts'
+loads (arXiv:2408.15664), this one's carries no such state (ROADMAP R8), so
+the leaf stays what it was loaded as.  It is read where ``balancing`` is
+``none``; under ``batch_bias`` the chooser is :func:`balanced_scores` and
+the leaf, still in the tree, is read by nothing.
 
 It is ``modules/latent_moe.py``'s machinery with another body: the chosen
 scores are read where they lie (:func:`~.latent_moe.top_k_set`, no gather),
@@ -74,6 +89,7 @@ from .latent_moe import (
 _init = nn.initializers.normal(0.02)
 
 BALANCINGS = ("none", "batch_bias")
+SCORINGS = ("softmax", "sigmoid")
 
 #: :func:`balanced_scores`: the noise's scale in spreads of an expert's
 #: logits, the count-and-correct rounds, and the step of each (in the
@@ -142,6 +158,7 @@ class GatedMoE(nn.Module):
     balancing: str = "none"   # of BALANCINGS
     routed_scale: float = 1.0  # on the weights, after they are renormalised
     shared_dim: int = 0       # 0: no shared expert
+    scoring: str = "softmax"  # of SCORINGS
 
     @nn.compact
     def __call__(self, h):
@@ -157,6 +174,10 @@ class GatedMoE(nn.Module):
         if self.balancing not in BALANCINGS:
             raise ValueError(
                 f"balancing {self.balancing!r} is not one of {BALANCINGS}")
+        if self.scoring not in SCORINGS:
+            raise ValueError(
+                f"scoring {self.scoring!r} is not one of {SCORINGS}")
+        sigmoid = self.scoring == "sigmoid"
         B, S, d = h.shape
         n = B * S
         dtype = h.dtype
@@ -172,10 +193,14 @@ class GatedMoE(nn.Module):
                 precision=None if dtype == jnp.bfloat16
                 else jax.lax.Precision.HIGHEST,
             ), "moe_logits")
-            p = jax.nn.softmax(logits, axis=-1)
+            p = (jax.nn.sigmoid(logits) if sigmoid
+                 else jax.nn.softmax(logits, axis=-1))
             # the selection is not differentiated: it only decides WHICH
             # scores are summed
             chooser = jax.lax.stop_gradient(p)
+            if sigmoid:
+                chooser = chooser + jax.lax.stop_gradient(self.param(
+                    "router_bias", nn.initializers.zeros, (E,), f32))
             if self.balancing == "batch_bias":
                 chooser = balanced_scores(
                     jax.lax.stop_gradient(logits), self.top_k)
@@ -186,8 +211,9 @@ class GatedMoE(nn.Module):
             w_held = jnp.where(
                 pair, p[:, self.first_held:self.first_held + Eh], 0.0)
             if self.norm_topk_prob:
-                w_held = w_held / jnp.sum(
+                chosen = jnp.sum(
                     jnp.where(sel, p, 0.0), axis=-1, keepdims=True)
+                w_held = w_held / (chosen + 1e-20 if sigmoid else chosen)
             if self.routed_scale != 1.0:
                 w_held = w_held * self.routed_scale
             load = pair.sum(axis=0)                                 # (Eh,)

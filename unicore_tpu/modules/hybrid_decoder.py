@@ -9,8 +9,12 @@ with its own rotary table), ``R`` routed gated experts with a softmax
 router and no shared expert, ``C`` attention in a compressed,
 convolution-mixed latent (``modules/cca.py``), ``Z`` gated experts one a
 token under a router that is a network with a state carried from one ``Z``
-layer to the next and a skip expert (``modules/zaya_moe.py``).  Every layer
-is
+layer to the next and a skip expert (``modules/zaya_moe.py``), ``L``
+multi-head latent attention: queries, keys and values expanded from two
+normed latents beside one rotary key every head shares, keys wider than
+values (``modules/mla.py``).  ``R`` with ``scoring="sigmoid"`` scores each
+expert by its own sigmoid and chooses on the score plus a bias leaf no
+gradient reaches (``modules/gated_moe.py``).  Every layer is
 
     x = x + mixer(RMSNorm(x))
 
@@ -57,8 +61,10 @@ and layout arrays, its routed sum and, with a shared expert, that expert's
 two products (``gated_moe.py``); ``M`` names ``in_proj``'s result
 (``mamba2.KEPT``: 38 MB a layer, no second ``in_proj``; convolution, scan
 and gated norm are made again from it); ``Z`` names what ``R`` names (its router's logits, the choice, the layout,
-the routed sum); ``*``, ``A``, ``F``, ``S``, ``G`` and ``C`` name nothing
-and are made again whole.  What a name is worth is the
+the routed sum); ``L`` names its two normed latents and its rotary key
+(``mla.KEPT``: 2,112 channels a token, for which the backward pass runs no
+second down-projection of queries or of keys and values); ``*``, ``A``,
+``F``, ``S``, ``G`` and ``C`` name nothing and are made again whole.  What a name is worth is the
 chip's to say: with ``in_proj``'s result kept the compiler lays the scanned
 backward loop out against the forward loop's and copies two saved arrays an
 iteration, its own cycle estimate ranks that form under ``shared_fc1``'s
@@ -72,7 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from unicore_tpu.ops import eva_attention, flash_attention
-from . import latent_moe, mamba2, zaya_moe
+from . import latent_moe, mamba2, mla, zaya_moe
 from .cca import CompressedConvAttention
 from .eva_attention import EvaAttention
 from .gated_mlp import GatedMLP
@@ -80,6 +86,7 @@ from .gated_moe import GatedMoE
 from .latent_moe import STATS, LatentMoE
 from .layer_norm import RMSNorm
 from .mamba2 import Mamba2Mixer
+from .mla import LatentAttention
 from .multihead_attention import GroupedQueryAttention
 from .zaya_moe import ZayaMoE
 
@@ -134,6 +141,9 @@ TABLE = {
               logs=(latent_moe.route_scalars, zaya_moe.skip_scalars),
               marks=(latent_moe.route_mark, zaya_moe.skip_mark),
               side=zaya_moe.side_start, more_stats=zaya_moe.MORE_STATS),
+    "L": Kind(LatentAttention, "self_attn", kept=mla.KEPT,
+              marks=(flash_attention.band_mark,
+                     flash_attention.band_call_mark, mla.mla_mark)),
 }
 
 
